@@ -10,9 +10,10 @@ in the committed trajectory (unified is exactly 1.0 by construction —
 asserted), ``recompiles`` pins the bounded shape-bucketing, and the two
 modes must emit identical tokens (asserted).
 
-Wall-clock rows are CPU interpret-mode numbers (relative, not TPU
-latencies); the HBM bytes/token rows are derived analytically from the two
-cache layouts and the *observed* request lengths:
+The committed rows come from ``JAX_PLATFORMS=cpu`` runs: their wall
+clocks are interpret-mode numbers (relative, not TPU latencies); the HBM
+bytes/token rows are derived analytically from the two cache layouts and
+the *observed* request lengths:
 
 * contiguous bf16 — every decode step streams each slot's full ``max_seq``
   reservation: ``layers · 2(K,V) · max_seq · kv · hd · 2B``;
@@ -45,8 +46,8 @@ chunk computes) and the allocator must be leak-free at exit; both are
 asserted, alongside the deterministic signal (fewer prefill chunks, hit
 rate) that makes the row meaningful even where wall clocks are noisy.
 
-    PYTHONPATH=src:. python benchmarks/serving_bench.py --smoke \
-        --out BENCH_serving.json
+    JAX_PLATFORMS=cpu PYTHONPATH=src:. python benchmarks/serving_bench.py \
+        --smoke --out BENCH_serving.json
 """
 
 from __future__ import annotations
@@ -58,13 +59,11 @@ import time
 import jax
 import numpy as np
 
-jax.config.update("jax_platform_name", "cpu")
-
-from benchmarks.common import hist_percentiles                 # noqa: E402
-from repro.models import lm                                    # noqa: E402
-from repro.models.config import ModelConfig                    # noqa: E402
-from repro.serving import kvcache as KV                        # noqa: E402
-from repro.serving.engine import (BucketedEngine, EngineConfig,  # noqa: E402
+from benchmarks.common import hist_percentiles
+from repro.models import lm
+from repro.models.config import ModelConfig
+from repro.serving import kvcache as KV
+from repro.serving.engine import (BucketedEngine, EngineConfig,
                                   PagedEngineConfig, PagedServingEngine)
 
 

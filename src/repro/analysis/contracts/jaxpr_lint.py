@@ -36,7 +36,7 @@ REPRESENTATIVE_CONFIG = "llama3_8b"
 
 
 def _iter_subjaxprs(params: dict):
-    from jax.core import Jaxpr, ClosedJaxpr
+    from jax.extend.core import Jaxpr, ClosedJaxpr
     for v in params.values():
         vs = v if isinstance(v, (list, tuple)) else (v,)
         for item in vs:

@@ -15,7 +15,8 @@ kernel example this pass
   the kernel math sees garbage rows);
 * **sums the VMEM footprint** (``KC002``): one block per operand and
   output (×2 for Mosaic's double buffering) plus every scratch allocation
-  must fit the budget (default 64 MiB);
+  must fit the budget (default: the kernels' requested
+  ``VMEM_LIMIT_BYTES``);
 * **checks accumulator dtypes** (``KC004``/``KC005``): the example is
   re-traced with ``jax.make_jaxpr`` (tracing only — no kernel executes on
   device) and every ``dot_general`` in the program, including the kernel
@@ -32,8 +33,10 @@ import jax
 import numpy as np
 
 from repro.analysis.contracts.findings import Finding
+from repro.kernels.stamp_matmul import VMEM_LIMIT_BYTES
 
-DEFAULT_VMEM_BUDGET = 64 * 2**20      # bytes; v5e carries 128 MiB/core
+# the scoped-VMEM limit the kernels request from the compiler
+DEFAULT_VMEM_BUDGET = VMEM_LIMIT_BYTES
 _MAX_GRID_CELLS = 200_000             # exhaustive-enumeration backstop
 
 
@@ -122,7 +125,7 @@ def _check_capture(cap, vmem_budget: int, out: list) -> None:
 
 
 def _iter_subjaxprs(params: dict):
-    from jax.core import Jaxpr, ClosedJaxpr
+    from jax.extend.core import Jaxpr, ClosedJaxpr
     for v in params.values():
         vs = v if isinstance(v, (list, tuple)) else (v,)
         for item in vs:

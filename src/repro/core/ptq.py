@@ -54,7 +54,7 @@ def capture_block_inputs(params, batch: dict, cfg: ModelConfig,
     return taps
 
 
-def calibrate_and_quantize(
+def calibrate(
     params,
     calib_batches: list,
     cfg: ModelConfig,
@@ -65,7 +65,13 @@ def calibrate_and_quantize(
     transform: str = "dwt",
     levels: int = 3,
     weight_bits: Optional[int] = 4,
-) -> tuple[dict, lm.ServeConfig, PTQReport]:
+) -> tuple[lm.ServeConfig, PTQReport]:
+    """Steps 1–3 and 5: the serving config and report, weights untouched.
+
+    ``params`` may be the float tree or the packed serving tree
+    (`lm._linear` dequantizes packed weights in the forward), so a model
+    initialised packed — ``lm.init_params(..., weight_bits=4)`` — is
+    calibrated through its served weights, with no float copy."""
     stats: Optional[SiteStats] = None
     for batch in calib_batches:
         b = {k: jnp.asarray(v) for k, v in batch.items()}
@@ -73,7 +79,8 @@ def calibrate_and_quantize(
             if stats is None:
                 stats = SiteStats.empty(tap.shape[-2], tap.shape[-1])
             stats.update(tap)
-    assert stats is not None, "no calibration data"
+    if stats is None:
+        raise ValueError("no calibration data")
 
     tf = toeplitz_fraction(stats.autocorr)
     energies = stats.energy_profile(transform, levels=levels)
@@ -91,12 +98,6 @@ def calibrate_and_quantize(
         kv=KVCacheConfig(quantized=True, num_hi=num_hi,
                          hi_bits=hi_bits, lo_bits=lo_bits),
         weight_bits=weight_bits)
-    sparams = params
-    if weight_bits:
-        sparams = lm.quantize_weights_for_serving(
-            jax.tree.map(lambda a: jnp.asarray(a, jnp.bfloat16)
-                         if a.dtype == jnp.float32 else a, params),
-            weight_bits)
     seq = stats.autocorr.shape[0]
     report = PTQReport(
         num_hi=num_hi,
@@ -104,4 +105,25 @@ def calibrate_and_quantize(
         toeplitz_fraction=tf,
         energy_head_fraction=head_frac,
         sites=2)
+    return serve, report
+
+
+def calibrate_and_quantize(
+    params,
+    calib_batches: list,
+    cfg: ModelConfig,
+    *,
+    weight_bits: Optional[int] = 4,
+    **kw,
+) -> tuple[dict, lm.ServeConfig, PTQReport]:
+    """The whole pipeline on a float tree: :func:`calibrate` (``kw`` are
+    its options), then step 4 — bf16 cast and ``weight_bits`` packing."""
+    serve, report = calibrate(params, calib_batches, cfg,
+                              weight_bits=weight_bits, **kw)
+    sparams = params
+    if weight_bits:
+        sparams = lm.quantize_weights_for_serving(
+            jax.tree.map(lambda a: jnp.asarray(a, jnp.bfloat16)
+                         if a.dtype == jnp.float32 else a, params),
+            weight_bits)
     return sparams, serve, report

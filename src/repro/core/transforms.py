@@ -300,6 +300,63 @@ def iwht(y: Array, axis: int = -2, skip_first: bool = False) -> Array:
 
 
 # ---------------------------------------------------------------------------
+# dense (s, s) forms of the fast transforms
+# ---------------------------------------------------------------------------
+
+
+def _haar_matrix(n: int, levels: int) -> np.ndarray:
+    out = np.eye(n)
+    lo = n
+    for _ in range(levels):
+        if lo < 2:
+            break
+        pairs = lo // 2
+        i = np.arange(pairs)
+        step = np.eye(n)
+        step[:lo, :lo] = 0.0
+        step[i, 2 * i] = step[i, 2 * i + 1] = 1.0 / _SQRT2     # approx rows
+        step[pairs + i, 2 * i] = 1.0 / _SQRT2                  # detail rows
+        step[pairs + i, 2 * i + 1] = -1.0 / _SQRT2
+        if lo % 2:
+            step[lo - 1, lo - 1] = 1.0                         # odd tail
+        out = step @ out
+        lo = (lo + 1) // 2 if lo % 2 else lo // 2
+    return out
+
+
+def _wht_matrix(n: int) -> np.ndarray:
+    p = _largest_pow2(n)
+    h = np.ones((1, 1))
+    while h.shape[0] < p:
+        h = np.block([[h, h], [h, -h]])
+    out = np.eye(n)
+    out[:p, :p] = h / np.sqrt(p)
+    return out
+
+
+@functools.lru_cache(maxsize=64)
+def sequence_matrix(kind: str, n: int, levels: int = 3,
+                    skip_first: bool = False) -> np.ndarray:
+    """The orthonormal ``(n, n)`` matrix ``L`` of the ``dwt`` or ``wht``
+    transform: ``sequence_transform(x, kind) == L @ x`` along the sequence
+    axis, and the inverse is ``L.T``.  Kernels apply it as a matmul, which
+    the TPU compiler lowers where the strided butterflies do not."""
+    body = n - 1 if skip_first else n
+    if kind == "dwt":
+        m = _haar_matrix(body, levels)
+    elif kind == "wht":
+        m = _wht_matrix(body)
+    else:
+        raise ValueError(f"no dense matrix form for transform {kind!r}")
+    if skip_first:
+        m = np.block([[np.ones((1, 1)), np.zeros((1, body))],
+                      [np.zeros((body, 1)), m]])
+    m = m.astype(np.float32)
+    m.setflags(write=False)          # cached: every caller gets this array
+    return m
+
+
+# ---------------------------------------------------------------------------
 # KLT (calibrated eigenbasis)
 # ---------------------------------------------------------------------------
 

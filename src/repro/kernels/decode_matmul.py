@@ -39,6 +39,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
+from repro.kernels.stamp_matmul import COMPILER_PARAMS
+
 
 def _kernel(x_ref, qw_ref, sw_ref, zw_ref, b_ref, o_ref,
             qx_ref, sx_ref, zx_ref, *, k_total: int):
@@ -109,5 +111,6 @@ def stamp_decode_matmul_pallas(
             pltpu.VMEM((b, 1), jnp.float32),   # per-token scale
             pltpu.VMEM((b, 1), jnp.float32),   # per-token (shifted) zp
         ],
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
     )(x, qw, sw, zw, bias)

@@ -1,9 +1,11 @@
 """jit'd public wrappers around the Pallas kernels.
 
-On CPU (this container) the kernels execute via ``interpret=True`` — the
-kernel body runs in Python, which validates BlockSpec indexing and kernel
-math against the `ref.py` oracles.  On TPU the same call sites compile to
-Mosaic.  ``force_interpret`` exists so tests pin the mode explicitly.
+On a TPU backend the kernels compile to Mosaic.  On any other backend
+(tests and CPU runs select it with ``JAX_PLATFORMS=cpu``) they execute via
+``interpret=True``: the kernel body runs in Python, which validates
+BlockSpec indexing and kernel math against the `ref.py` oracles.
+`default_interpret` is the one switch between the two; every wrapper also
+takes an explicit ``interpret=`` so tests can pin the mode.
 """
 
 from __future__ import annotations
@@ -29,9 +31,6 @@ def default_interpret() -> bool:
     points accept ``interpret=None`` and resolve it through this one switch,
     so tests can still pin the mode explicitly."""
     return jax.default_backend() != "tpu"
-
-
-_interpret_default = default_interpret  # back-compat alias
 
 
 @functools.partial(jax.jit, static_argnames=("levels", "inverse", "block_d",

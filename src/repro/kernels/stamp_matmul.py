@@ -6,8 +6,9 @@ linear: the sequence-transformed activation ``T = L·X``, the fake-quantized
 ``Tq``, the matmul output ``Tq·W`` and the inverse-transformed ``L⁻¹(Tq·W)``.
 The kernels here run the whole chain in one VMEM residency:
 
-    1. ``T = L · X``          — multi-level Haar DWT / WHT butterflies on the
-                                in-VMEM tile (sequence axis fully resident);
+    1. ``T = L · X``          — multi-level Haar DWT / WHT as an (s, s)
+                                matrix on the in-VMEM tile (sequence axis
+                                fully resident), on the MXU;
     2. ``Q(T)``               — per-token asymmetric min-max quantize, first
                                 ``num_hi`` rows at ``hi_bits`` and the rest at
                                 ``lo_bits`` (the paper's mixed precision,
@@ -33,11 +34,11 @@ into VMEM scratch; subsequent output blocks reuse the int8 codes and
 per-token scales, so widening N (e.g. a concatenated QKV weight) adds only
 GEMM + epilogue work.  The activation block index is constant across the N
 grid axis, so the pipeline fetches X from HBM once per row (Mosaic skips
-re-copying revisited blocks).  The transform butterflies reuse the pure-jnp
-orthonormal helpers from `repro.core.transforms` — static shapes, so they
-trace into sublane shuffles the same way `haar_dwt.py` / `wht.py` do,
-including the identity-tail handling for non-power-of-two sequence lengths
-and the first-token (attention sink) exception.
+re-copying revisited blocks).  The transform matrices come from
+`repro.core.transforms.sequence_matrix`, checked there against the
+butterfly oracle, including the identity-tail handling for
+non-power-of-two sequence lengths and the first-token (attention sink)
+exception; the inverse is ``Lᵀ`` per output block.
 
 Three call-site variants share that structure:
 
@@ -69,32 +70,43 @@ import jax.experimental.pallas.tpu as pltpu
 from repro.core import transforms as T
 
 # transforms the fused kernel can run in-VMEM; dct/klt/dwt2d fall back to
-# the reference path (dense O(s²) bases / latent-grid reads don't tile).
+# the reference path (calibrated bases / latent-grid reads don't tile).
 FUSABLE_TRANSFORMS = ("none", "dwt", "wht")
 
+# Scoped VMEM the kernels request from the compiler.  The compiler's default
+# limit is smaller than the down-proj working set at K = 14336 (a (s, K)
+# activation tile, its f32 quantize temporaries and double-buffered (K, bn)
+# int8 weight blocks); v5e has 128 MiB of VMEM per core.  The contract
+# checker audits kernel footprints against this same number.
+VMEM_LIMIT_BYTES = 64 * 2**20
+COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
-def _seq_fwd(x, kind: str, levels: int, skip_first: bool):
+
+def _seq_matrices(kind: str, s: int, levels: int, skip_first: bool):
+    """``(L, Lᵀ)`` as (s, s) f32 kernel operands, or ``()`` for ``none``.
+    The transform runs as ``L @ x`` on the MXU (s/N of the GEMM's
+    multiply-adds, but each at f32 ``HIGHEST`` precision, several bf16
+    passes, against the int8 GEMM's double rate): the strided butterflies
+    of `repro.core.transforms` do not lower to Mosaic, and that module
+    stays the oracle the matrices are read from."""
     if kind == "none":
+        return ()
+    if kind not in FUSABLE_TRANSFORMS:
+        raise ValueError(f"transform {kind!r} not fusable")
+    m = T.sequence_matrix(kind, s, levels, skip_first)
+    return jnp.asarray(m), jnp.asarray(m.T)
+
+
+def _apply(m_ref, x):
+    """``M @ x`` for an (s, s) f32 transform matrix and an (s, n) f32 tile;
+    a missing matrix (transform ``none``) is the identity."""
+    if m_ref is None:
         return x
-    if kind == "dwt":
-        return T.haar_dwt(x, levels=levels, axis=-2, skip_first=skip_first)
-    if kind == "wht":
-        return T.wht(x, axis=-2, skip_first=skip_first)
-    raise ValueError(f"transform {kind!r} not fusable")
+    return jnp.dot(m_ref[...], x, preferred_element_type=jnp.float32,
+                   precision=jax.lax.Precision.HIGHEST)
 
 
-def _seq_inv(y, kind: str, levels: int, skip_first: bool):
-    if kind == "none":
-        return y
-    if kind == "dwt":
-        return T.haar_idwt(y, levels=levels, axis=-2, skip_first=skip_first)
-    if kind == "wht":
-        return T.iwht(y, axis=-2, skip_first=skip_first)
-    raise ValueError(f"transform {kind!r} not fusable")
-
-
-def _transform_quantize(x_ref, qx_ref, sx_ref, zx_ref, *,
-                        transform: str, levels: int, skip_first: bool,
+def _transform_quantize(x_ref, l_ref, qx_ref, sx_ref, zx_ref, *,
                         num_hi: int, hi_bits: int, lo_bits: int):
     """Transform + mixed-precision quantize the in-VMEM activation tile into
     scratch.  Runs on the first output-block grid step of each batch row;
@@ -103,7 +115,7 @@ def _transform_quantize(x_ref, qx_ref, sx_ref, zx_ref, *,
     head-merge reshape is fused with the quantize, entirely in VMEM."""
     x = x_ref[0].astype(jnp.float32)
     x = x.reshape(x.shape[0], -1)                      # (s, K) head merge
-    tx = _seq_fwd(x, transform, levels, skip_first)
+    tx = _apply(l_ref, x)
     s = tx.shape[0]
     # mixed-precision per-token min-max quantize (Eq. 1 with b_ij = b_i)
     row = jax.lax.broadcasted_iota(jnp.int32, (s, 1), 0)
@@ -117,6 +129,13 @@ def _transform_quantize(x_ref, qx_ref, sx_ref, zx_ref, *,
     qx_ref[...] = (q - 128.0).astype(jnp.int8)      # unsigned → signed codes
     sx_ref[...] = sx
     zx_ref[...] = zx - 128.0               # shift zp identically (exact)
+
+
+def _split_mats(refs, has_mats: bool):
+    """Peel the optional leading ``(L, Lᵀ)`` refs off a kernel's refs."""
+    if has_mats:
+        return refs[0], refs[1], refs[2:]
+    return None, None, refs
 
 
 def _int_gemm(qx, sx, zxs, qw, sw, zw, *, k_total: int):
@@ -136,46 +155,44 @@ def _int_gemm(qx, sx, zxs, qw, sw, zw, *, k_total: int):
     return corr * sx * sw                              # (s, bn) f32
 
 
-def _stamp_kernel(x_ref, qw_ref, sw_ref, zw_ref, b_ref, o_ref,
-                  qx_ref, sx_ref, zx_ref, *,
-                  transform: str, levels: int, skip_first: bool,
-                  num_hi: int, hi_bits: int, lo_bits: int, k_total: int):
+def _stamp_kernel(*refs, has_mats: bool, num_hi: int, hi_bits: int,
+                  lo_bits: int, k_total: int):
+    l_ref, lt_ref, refs = _split_mats(refs, has_mats)
+    (x_ref, qw_ref, sw_ref, zw_ref, b_ref, o_ref,
+     qx_ref, sx_ref, zx_ref) = refs
+
     @pl.when(pl.program_id(1) == 0)
     def _tq():
-        _transform_quantize(x_ref, qx_ref, sx_ref, zx_ref,
-                            transform=transform, levels=levels,
-                            skip_first=skip_first, num_hi=num_hi,
-                            hi_bits=hi_bits, lo_bits=lo_bits)
+        _transform_quantize(x_ref, l_ref, qx_ref, sx_ref, zx_ref,
+                            num_hi=num_hi, hi_bits=hi_bits, lo_bits=lo_bits)
 
     y = _int_gemm(qx_ref[...], sx_ref[...], zx_ref[...],
                   qw_ref[...], sw_ref[...], zw_ref[...], k_total=k_total)
     # inverse transform commutes with the right-multiplication by W, so it
     # applies per output block; bias afterwards is exact (Eq. 7).
-    y = _seq_inv(y, transform, levels, skip_first)
+    y = _apply(lt_ref, y)
     o_ref[0] = (y + b_ref[...].astype(jnp.float32)).astype(o_ref.dtype)
 
 
-def _stamp_dual_kernel(x_ref, qwg_ref, swg_ref, zwg_ref, bg_ref,
-                       qwu_ref, swu_ref, zwu_ref, bu_ref, *refs,
-                       transform: str, levels: int, skip_first: bool,
-                       num_hi: int, hi_bits: int, lo_bits: int, k_total: int,
-                       epilogue: str):
+def _stamp_dual_kernel(*refs, has_mats: bool, num_hi: int, hi_bits: int,
+                       lo_bits: int, k_total: int, epilogue: str):
     """Two GEMMs (gate/up) off ONE scratch-resident quantized activation.
 
     With ``epilogue="silu_mul"`` the inverse-transformed pair combines to
     ``silu(g)·u`` in-VMEM and a single output block is written; with
     ``epilogue="none"`` both projections are written separately."""
+    l_ref, lt_ref, refs = _split_mats(refs, has_mats)
+    (x_ref, qwg_ref, swg_ref, zwg_ref, bg_ref,
+     qwu_ref, swu_ref, zwu_ref, bu_ref) = refs[:9]
     if epilogue == "silu_mul":
-        o_ref, qx_ref, sx_ref, zx_ref = refs
+        o_ref, qx_ref, sx_ref, zx_ref = refs[9:]
     else:
-        og_ref, ou_ref, qx_ref, sx_ref, zx_ref = refs
+        og_ref, ou_ref, qx_ref, sx_ref, zx_ref = refs[9:]
 
     @pl.when(pl.program_id(1) == 0)
     def _tq():
-        _transform_quantize(x_ref, qx_ref, sx_ref, zx_ref,
-                            transform=transform, levels=levels,
-                            skip_first=skip_first, num_hi=num_hi,
-                            hi_bits=hi_bits, lo_bits=lo_bits)
+        _transform_quantize(x_ref, l_ref, qx_ref, sx_ref, zx_ref,
+                            num_hi=num_hi, hi_bits=hi_bits, lo_bits=lo_bits)
 
     qx, sx, zxs = qx_ref[...], sx_ref[...], zx_ref[...]
     yg = _int_gemm(qx, sx, zxs, qwg_ref[...], swg_ref[...], zwg_ref[...],
@@ -185,10 +202,8 @@ def _stamp_dual_kernel(x_ref, qwg_ref, swg_ref, zwg_ref, bg_ref,
     # both outputs return to the original domain before the gating
     # nonlinearity — silu does NOT commute with L⁻¹, the element-wise
     # product must happen on tokens, not wavelet coefficients.
-    yg = _seq_inv(yg, transform, levels, skip_first) \
-        + bg_ref[...].astype(jnp.float32)
-    yu = _seq_inv(yu, transform, levels, skip_first) \
-        + bu_ref[...].astype(jnp.float32)
+    yg = _apply(lt_ref, yg) + bg_ref[...].astype(jnp.float32)
+    yu = _apply(lt_ref, yu) + bu_ref[...].astype(jnp.float32)
     if epilogue == "silu_mul":
         o_ref[0] = (jax.nn.silu(yg) * yu).astype(o_ref.dtype)
     else:
@@ -217,6 +232,11 @@ def _x_spec(x: jax.Array) -> tuple[pl.BlockSpec, int, int, int]:
             b, s, nh * hd
     b, s, k = x.shape
     return pl.BlockSpec((1, s, k), lambda i, j: (i, 0, 0)), b, s, k
+
+
+def _m_spec(s: int) -> pl.BlockSpec:
+    # constant block index: each transform matrix is fetched once per call
+    return pl.BlockSpec((s, s), lambda i, j: (0, 0))
 
 
 def stamp_quant_matmul_pallas(
@@ -248,14 +268,14 @@ def stamp_quant_matmul_pallas(
     if k != k2:
         raise ValueError(f"activation K={k} does not match weight K={k2}")
     bn = _pick_block_n(block_n, n)
+    mats = _seq_matrices(transform, s, levels, skip_first)
     kernel = functools.partial(
-        _stamp_kernel, transform=transform, levels=levels,
-        skip_first=skip_first, num_hi=num_hi, hi_bits=hi_bits,
+        _stamp_kernel, has_mats=bool(mats), num_hi=num_hi, hi_bits=hi_bits,
         lo_bits=lo_bits, k_total=k)
     return pl.pallas_call(
         kernel,
         grid=(b, n // bn),
-        in_specs=[
+        in_specs=[_m_spec(s)] * len(mats) + [
             x_spec,
             pl.BlockSpec((k, bn), lambda i, j: (0, j)),
             pl.BlockSpec((1, bn), lambda i, j: (0, j)),
@@ -269,8 +289,9 @@ def stamp_quant_matmul_pallas(
             pltpu.VMEM((s, 1), jnp.float32),   # per-token scale
             pltpu.VMEM((s, 1), jnp.float32),   # per-token (shifted) zp
         ],
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
-    )(x, qw, sw, zw, bias)
+    )(*mats, x, qw, sw, zw, bias)
 
 
 def stamp_quant_dual_matmul_pallas(
@@ -315,10 +336,10 @@ def stamp_quant_dual_matmul_pallas(
         raise ValueError(f"gate/up weight shapes differ: "
                          f"{qw_g.shape} vs {qw_u.shape}")
     bn = _pick_block_n(block_n, n)
+    mats = _seq_matrices(transform, s, levels, skip_first)
     kernel = functools.partial(
-        _stamp_dual_kernel, transform=transform, levels=levels,
-        skip_first=skip_first, num_hi=num_hi, hi_bits=hi_bits,
-        lo_bits=lo_bits, k_total=k, epilogue=epilogue)
+        _stamp_dual_kernel, has_mats=bool(mats), num_hi=num_hi,
+        hi_bits=hi_bits, lo_bits=lo_bits, k_total=k, epilogue=epilogue)
     w_spec = pl.BlockSpec((k, bn), lambda i, j: (0, j))
     c_spec = pl.BlockSpec((1, bn), lambda i, j: (0, j))
     o_spec = pl.BlockSpec((1, s, bn), lambda i, j: (i, 0, j))
@@ -327,9 +348,10 @@ def stamp_quant_dual_matmul_pallas(
     out = pl.pallas_call(
         kernel,
         grid=(b, n // bn),
-        in_specs=[x_spec,
-                  w_spec, c_spec, c_spec, c_spec,
-                  w_spec, c_spec, c_spec, c_spec],
+        in_specs=[_m_spec(s)] * len(mats) + [
+            x_spec,
+            w_spec, c_spec, c_spec, c_spec,
+            w_spec, c_spec, c_spec, c_spec],
         out_specs=o_spec if single else (o_spec, o_spec),
         out_shape=o_shape if single else (o_shape, o_shape),
         scratch_shapes=[
@@ -337,8 +359,9 @@ def stamp_quant_dual_matmul_pallas(
             pltpu.VMEM((s, 1), jnp.float32),   # per-token scale
             pltpu.VMEM((s, 1), jnp.float32),   # per-token (shifted) zp
         ],
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
-    )(x, qw_g, sw_g, zw_g, bias_g, qw_u, sw_u, zw_u, bias_u)
+    )(*mats, x, qw_g, sw_g, zw_g, bias_g, qw_u, sw_u, zw_u, bias_u)
     return out
 
 
@@ -528,6 +551,7 @@ def stamp_quant_grouped_matmul_pallas(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, e, cap + pad_c, d), out_dtype),
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
     )(counts, qx, sx, zx,
       qw_gate, sw_gate, zw_gate,

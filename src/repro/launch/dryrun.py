@@ -1,7 +1,9 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any other import (JAX locks the device
-# count at first initialization).  Everything below is ordinary code.
+os.environ["JAX_PLATFORMS"] = "cpu"
+# The lines above MUST run before any other import (JAX locks the platform
+# and device count at first initialization; on a TPU host the CPU pin keeps
+# the dry run off the chip).  Everything below is ordinary code.
 
 """Multi-pod dry run: lower + compile every (arch × shape × mesh) cell.
 

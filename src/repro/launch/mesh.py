@@ -16,22 +16,13 @@ paths.
 from __future__ import annotations
 
 import jax
-
-
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """``jax.sharding.AxisType`` landed after 0.4.x; older releases neither
-    expose it nor accept ``axis_types`` in ``jax.make_mesh`` — fall back to a
-    plain mesh (Auto is the implicit behavior there anyway)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_local_mesh(model_parallel: int = 1):
@@ -40,4 +31,4 @@ def make_local_mesh(model_parallel: int = 1):
     assert n % model_parallel == 0
     return jax.make_mesh((n // model_parallel, model_parallel),
                          ("data", "model"),
-                         **_axis_type_kwargs(2))
+                         axis_types=(AxisType.Auto,) * 2)

@@ -1,5 +1,7 @@
-"""Serving driver: PTQ a (small, trained or random-init) model and serve
-batched requests through a STaMP-quantized engine.
+"""Serving driver: random-init a model straight into its packed serving
+form, calibrate STaMP through it, and serve batched requests through a
+STaMP-quantized engine.  The set-up functions here are also what
+``chip_smoke.py`` drives.
 
     PYTHONPATH=src python -m repro.launch.serve --arch llama3-8b --reduced \
         --requests 16 --prompt-len 96 --max-new 16 \
@@ -18,17 +20,125 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import os
+import pathlib
 import time
+from typing import Optional
 
 import jax
 import numpy as np
 
 from repro.configs import get_config, get_reduced
-from repro.core.ptq import calibrate_and_quantize
+from repro.core.ptq import PTQReport, calibrate
 from repro.data.pipeline import DataConfig, calibration_batches
 from repro.models import lm
+from repro.models.config import ModelConfig
 from repro.serving.engine import (BucketedEngine, EngineConfig,
                                   PagedEngineConfig, PagedServingEngine)
+
+REPO = pathlib.Path(__file__).resolve().parents[3]
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it itself and
+    nothing is set here; otherwise the cache lives at the fixed
+    ``<repo>/.jax_cache``, so every run of this checkout finds the
+    programs the last one compiled."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(REPO / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def build_model(cfg: ModelConfig, seed: int
+                ) -> tuple[dict, lm.ServeConfig, PTQReport]:
+    """Random-init ``cfg`` from ``seed`` straight into its served form
+    (bf16, int4-packed linears, one period at a time) and run STaMP
+    calibration through those packed weights."""
+    weight_bits = 4                    # W4, the paper's served weights
+    params = lm.init_params(jax.random.PRNGKey(seed), cfg,
+                            weight_bits=weight_bits)
+    dcfg = DataConfig(vocab_size=cfg.vocab_size, seq_len=128, global_batch=4,
+                      seed=seed)
+    serve, report = calibrate(params, calibration_batches(dcfg, num_batches=2),
+                              cfg, weight_bits=weight_bits)
+    print(f"[ptq] num_hi={report.num_hi} avg_bits={report.avg_bits:.3f} "
+          f"toeplitz={report.toeplitz_fraction:.3f} "
+          f"head_energy={report.energy_head_fraction:.3f}")
+    return params, serve, report
+
+
+def with_execution(serve: lm.ServeConfig, execution: str) -> lm.ServeConfig:
+    """``serve`` with its STaMP linears on the ``reference`` (pure jnp) or
+    ``fused`` (Pallas integer kernel) path."""
+    if serve.stamp is None:
+        return serve
+    return dataclasses.replace(
+        serve, stamp=dataclasses.replace(serve.stamp, execution=execution))
+
+
+def paged_engine(params, cfg: ModelConfig, serve: lm.ServeConfig, *,
+                 block_size: int = 16, fault=None, **ecfg
+                 ) -> PagedServingEngine:
+    """The continuous-batching engine over 8 decode slots; ``ecfg`` are
+    further `PagedEngineConfig` fields.  A page holds one precision, so
+    ``block_size`` becomes ``num_hi`` where it does not divide it."""
+    num_hi = serve.kv.num_hi if serve.kv.quantized else 0
+    if num_hi % block_size:
+        block_size = num_hi
+        print(f"[serve] block_size adjusted to {block_size} "
+              f"(num_hi={num_hi})")
+    return PagedServingEngine(
+        params, cfg, serve,
+        PagedEngineConfig(max_slots=8, block_size=block_size, **ecfg),
+        fault=fault)
+
+
+def print_eligibility(engine) -> None:
+    """Per-site fused/reference matrix: which linears run integer kernels
+    and, for every reference site, the structured reason why."""
+    for site, cell in engine.eligibility.items():
+        why = f" ({','.join(cell['reasons'])})" if cell["reasons"] else ""
+        print(f"[serve:eligibility] {site:<12} {cell['status']:<9} "
+              f"kernel={cell['kernel'] or '-'} "
+              f"layers={cell['layers']}{why}")
+    n_ref = engine.stats["reference_fallback_sites"]
+    print(f"[serve:eligibility] reference_fallback_sites={n_ref}")
+    if n_ref == 0 and "moe" in engine.eligibility:
+        # the MoE expert einsums were the last structurally-ineligible
+        # site — call out full coverage explicitly on expert configs
+        print("[serve:eligibility] full fused coverage: every STaMP site "
+              "incl. grouped MoE runs the integer kernels")
+
+
+def print_paged_stats(engine: PagedServingEngine) -> None:
+    st = engine.stats
+    print(f"[serve:paged:{engine.ecfg.step_mode}] steps={st['steps']} "
+          f"prefill_chunks={st['prefill_chunks']} "
+          f"preemptions={st['preemptions']} "
+          f"dispatches/step="
+          f"{st['device_dispatches'] / max(st['steps'], 1):.2f} "
+          f"recompiles={st['recompiles']} "
+          f"prefix_hit_rate={st['prefix_cache_hit_rate']:.2f} "
+          f"prefix_tokens_reused={st['prefix_tokens_reused']}")
+    print(f"[serve:lifecycle] finished={st['finished']} "
+          f"failed={st['failed']} cancelled={st['cancelled']} "
+          f"rejected={st['rejected']} shed={st['shed']} "
+          f"deadline_misses={st['deadline_misses']} "
+          f"nan_quarantines={st['nan_quarantines']} "
+          f"demotions={st['demotions']} "
+          f"watchdog_trips={st['watchdog_trips']}")
+
+
+def peak_device_bytes() -> Optional[int]:
+    """``peak_bytes_in_use`` of the first device, where its backend
+    reports memory statistics (TPU does; CPU does not)."""
+    stats = jax.devices()[0].memory_stats()
+    return None if stats is None else stats.get("peak_bytes_in_use")
 
 
 def main():
@@ -48,7 +158,8 @@ def main():
     ap.add_argument("--execution", choices=("reference", "fused"),
                     default="reference",
                     help="STaMP linear path: pure-jnp reference or the "
-                         "fused Pallas integer kernel (interpret on CPU)")
+                         "fused Pallas integer kernel (interpret mode off "
+                         "the TPU)")
     ap.add_argument("--fused-cache-attention", action="store_true",
                     help="decode attention through the Pallas packed-cache "
                          "kernel (paged or contiguous layout)")
@@ -117,22 +228,12 @@ def main():
         ap.error(f"--engine paged does not support encoder-decoder stacks "
                  f"({cfg.name}: encoder_layers={cfg.encoder_layers}); "
                  f"run with --engine bucketed")
-    params = lm.init_params(jax.random.PRNGKey(args.seed), cfg)
-
-    dcfg = DataConfig(vocab_size=cfg.vocab_size, seq_len=128, global_batch=4,
-                      seed=args.seed)
-    calib = calibration_batches(dcfg, num_batches=2)
-    sparams, serve, report = calibrate_and_quantize(params, calib, cfg)
-    print(f"[ptq] num_hi={report.num_hi} avg_bits={report.avg_bits:.3f} "
-          f"toeplitz={report.toeplitz_fraction:.3f} "
-          f"head_energy={report.energy_head_fraction:.3f}")
+    print(f"[serve] compile cache: {enable_compile_cache()}")
+    sparams, serve, _ = build_model(cfg, args.seed)
     if args.no_stamp:
         serve = lm.ServeConfig(stamp=None, kv=serve.kv,
                                weight_bits=serve.weight_bits)
-    elif serve.stamp is not None:
-        serve = dataclasses.replace(
-            serve, stamp=dataclasses.replace(serve.stamp,
-                                             execution=args.execution))
+    serve = with_execution(serve, args.execution)
     if args.fused_cache_attention:
         serve = dataclasses.replace(serve, fused_cache_attention=True)
     if args.numerics_guard:
@@ -142,45 +243,23 @@ def main():
 
     max_seq = 128 + args.max_new
     if args.engine == "paged":
-        num_hi = serve.kv.num_hi if serve.kv.quantized else 0
-        bs = args.block_size
-        if num_hi % bs:
-            bs = num_hi      # pages must be single-precision (num_hi % bs == 0)
-            print(f"[serve] block_size adjusted to {bs} (num_hi={num_hi})")
         fault = None
         if args.chaos is not None:
             from repro.serving.faults import FaultPlan
             fault = FaultPlan(seed=args.chaos, exhaust_rate=0.2,
                               corrupt_rate=0.3, nan_rate=0.005)
-        engine = PagedServingEngine(
-            sparams, cfg, serve,
-            PagedEngineConfig(max_slots=8, prefill_chunk=args.prefill_chunk,
-                              max_seq=max_seq, block_size=bs,
-                              step_mode=args.step_mode,
-                              max_prefills=args.max_prefills,
-                              max_waiting=args.max_waiting,
-                              shed_policy=args.shed_policy,
-                              preempt_watermark=args.watermark,
-                              prefix_caching=args.prefix_cache),
-            fault=fault)
+        engine = paged_engine(
+            sparams, cfg, serve, block_size=args.block_size, fault=fault,
+            prefill_chunk=args.prefill_chunk, max_seq=max_seq,
+            step_mode=args.step_mode, max_prefills=args.max_prefills,
+            max_waiting=args.max_waiting, shed_policy=args.shed_policy,
+            preempt_watermark=args.watermark,
+            prefix_caching=args.prefix_cache)
     else:
         engine = BucketedEngine(sparams, cfg, serve,
                                 EngineConfig(max_batch=8, bucket=128,
                                              max_seq=max_seq))
-    # per-site fused/reference matrix: which linears run integer kernels
-    # and, for every reference site, the structured reason why
-    for site, cell in engine.eligibility.items():
-        why = f" ({','.join(cell['reasons'])})" if cell["reasons"] else ""
-        print(f"[serve:eligibility] {site:<12} {cell['status']:<9} "
-              f"kernel={cell['kernel'] or '-'} "
-              f"layers={cell['layers']}{why}")
-    n_ref = engine.stats["reference_fallback_sites"]
-    print(f"[serve:eligibility] reference_fallback_sites={n_ref}")
-    if n_ref == 0 and "moe" in engine.eligibility:
-        # the MoE expert einsums were the last structurally-ineligible
-        # site — call out full coverage explicitly on expert configs
-        print("[serve:eligibility] full fused coverage: every STaMP site "
-              "incl. grouped MoE runs the integer kernels")
+    print_eligibility(engine)
     rng = np.random.default_rng(args.seed)
     for _ in range(args.requests):
         engine.submit(rng.integers(0, cfg.vocab_size, args.prompt_len),
@@ -193,25 +272,12 @@ def main():
     total_new = sum(len(r.out_tokens) for r in done)
     ttfts = sorted(r.ttft_s for r in done)
     print(f"[serve:{args.engine}] {len(done)} requests, {total_new} tokens "
-          f"in {dt:.2f}s ({total_new / dt:.1f} tok/s on CPU), "
+          f"in {dt:.2f}s ({total_new / dt:.1f} tok/s on "
+          f"{jax.devices()[0].device_kind}, compiles included), "
           f"ttft p50={ttfts[len(ttfts) // 2]:.2f}s")
+    print(f"[serve] peak_bytes_in_use={peak_device_bytes()}")
     if args.engine == "paged":
-        st = engine.stats
-        print(f"[serve:paged:{args.step_mode}] steps={st['steps']} "
-              f"prefill_chunks={st['prefill_chunks']} "
-              f"preemptions={st['preemptions']} "
-              f"dispatches/step="
-              f"{st['device_dispatches'] / max(st['steps'], 1):.2f} "
-              f"recompiles={st['recompiles']} "
-              f"prefix_hit_rate={st['prefix_cache_hit_rate']:.2f} "
-              f"prefix_tokens_reused={st['prefix_tokens_reused']}")
-        print(f"[serve:lifecycle] finished={st['finished']} "
-              f"failed={st['failed']} cancelled={st['cancelled']} "
-              f"rejected={st['rejected']} shed={st['shed']} "
-              f"deadline_misses={st['deadline_misses']} "
-              f"nan_quarantines={st['nan_quarantines']} "
-              f"demotions={st['demotions']} "
-              f"watchdog_trips={st['watchdog_trips']}")
+        print_paged_stats(engine)
     for r in done[:3]:
         print(f"  req {r.uid}: {r.out_tokens[:10]}")
 

@@ -40,7 +40,9 @@ def run_cell(arch: str, shape: str, multi_pod: bool, extra=(),
     proc = subprocess.run(cmd, capture_output=True, text=True,
                           cwd=str(ROOT), timeout=timeout,
                           env={"PYTHONPATH": str(ROOT / "src"),
-                               "PATH": "/usr/bin:/bin:/usr/local/bin"})
+                               "PATH": "/usr/bin:/bin:/usr/local/bin",
+                               # children never take a TPU on a chip host
+                               "JAX_PLATFORMS": "cpu"})
     dt = time.time() - t0
     if proc.returncode != 0:
         err = proc.stderr.strip().splitlines()[-1] if proc.stderr else "?"

@@ -123,36 +123,56 @@ def init_layer_params(key, spec: LayerSpec, cfg: ModelConfig,
     return p
 
 
-def init_params(key, cfg: ModelConfig, dtype=jnp.float32) -> dict:
+def init_params(key, cfg: ModelConfig, dtype=jnp.float32,
+                weight_bits: Optional[int] = None) -> dict:
+    """Random-init parameters from ``key``.
+
+    With ``weight_bits`` the tree comes out as served: the float32 init
+    cast to bf16 and the large matmul weights packed by
+    :func:`quantize_weights_for_serving` — the values of packing a float
+    init afterwards (up to float rounding of the scales and ties in the
+    codes), but built one scan period at a time, so the float model is
+    never whole in memory (an 8B model is 32 GB in f32)."""
     pro, period, nper = cfg.layer_plan()
     k_embed, k_head, k_pro, k_per, k_enc = jax.random.split(key, 5)
+
+    def finish(tree):
+        if not weight_bits:
+            return tree
+        tree = jax.tree.map(lambda a: a.astype(jnp.bfloat16)
+                            if a.dtype == jnp.float32 else a, tree)
+        return quantize_weights_for_serving(tree, weight_bits)
+
     params: dict = {
-        "embed": (jax.random.normal(k_embed, (cfg.padded_vocab, cfg.d_model),
-                                    jnp.float32) * 0.02).astype(dtype),
-        "final_norm": jnp.ones((cfg.d_model,), dtype),
+        "embed": finish((jax.random.normal(
+            k_embed, (cfg.padded_vocab, cfg.d_model), jnp.float32)
+            * 0.02).astype(dtype)),
+        "final_norm": finish(jnp.ones((cfg.d_model,), dtype)),
     }
     if not cfg.tie_embeddings:
-        params["head"] = _dense_init(k_head, cfg.d_model, cfg.padded_vocab,
-                                     dtype)
+        params["head"] = finish(_dense_init(k_head, cfg.d_model,
+                                            cfg.padded_vocab, dtype))
     if pro:
         pro_keys = jax.random.split(k_pro, len(pro))
         params["prologue"] = tuple(
-            init_layer_params(k, s, cfg, dtype) for k, s in zip(pro_keys, pro))
+            finish(init_layer_params(k, s, cfg, dtype))
+            for k, s in zip(pro_keys, pro))
     per_keys = jax.random.split(k_per, nper)
-    stacked = jax.vmap(
-        lambda k: tuple(init_layer_params(kk, s, cfg, dtype)
-                        for kk, s in zip(jax.random.split(k, len(period)), period))
-    )(per_keys)
-    params["period"] = stacked
+    params["period"] = jax.lax.map(
+        lambda k: finish(tuple(
+            init_layer_params(kk, s, cfg, dtype)
+            for kk, s in zip(jax.random.split(k, len(period)), period))),
+        per_keys)
     if cfg.encoder_layers:
         enc_spec = LayerSpec("attn", "mlp")
         enc_cfg = dataclasses.replace(cfg, encoder_layers=0)  # no cross in enc
         enc_keys = jax.random.split(k_enc, cfg.encoder_layers)
         params["encoder"] = {
-            "period": jax.vmap(
-                lambda k: (init_layer_params(k, enc_spec, enc_cfg, dtype),)
-            )(enc_keys),
-            "final_norm": jnp.ones((cfg.d_model,), dtype),
+            "period": jax.lax.map(
+                lambda k: finish(
+                    (init_layer_params(k, enc_spec, enc_cfg, dtype),)),
+                enc_keys),
+            "final_norm": finish(jnp.ones((cfg.d_model,), dtype)),
         }
     return params
 
@@ -330,10 +350,12 @@ def prepare_fused_weights(params: Pytree, stamp: StampConfig) -> Pytree:
     each gate/up pair stacks into one `prepare_linear` call.
 
     Runs once at engine/benchmark setup; stacked ``(nper, din, dout)`` period
-    weights prepare in one shot and slice cleanly under `lax.scan`.  Packed
-    int4 dicts from :func:`quantize_weights_for_serving` are dequantized
-    first and re-coded at ``stamp.fused_weight_bits``.  No-op when the config
-    cannot run the fused kernel.
+    weights prepare one period at a time (`lax.map`) into stacks that slice
+    cleanly under `lax.scan` — per-output-channel scales make that identical
+    to one whole-stack pass, and only one period's f32 dequant is ever
+    live.  Packed int4 dicts from :func:`quantize_weights_for_serving` are
+    dequantized first and re-coded at ``stamp.fused_weight_bits``.  No-op
+    when the config cannot run the fused kernel.
     """
     from repro.core.stamp import fused_eligible, prepare_linear
     if not fused_eligible(stamp):
@@ -378,6 +400,8 @@ def prepare_fused_weights(params: Pytree, stamp: StampConfig) -> Pytree:
                     # the encoder never runs STaMP (stamp=None in
                     # _encoder_forward): quantizing it is pure precision loss
                     out[k] = v
+                elif k == "period":
+                    out[k] = jax.lax.map(visit, v)
                 elif k in FUSED_SITES and \
                         not (isinstance(v, dict) and "iq" in v):
                     out[k] = prep(v)

@@ -612,7 +612,8 @@ class PagedServingEngine(_EngineBase):
             buckets.add(b)
             b *= 2
         self._npf_buckets = sorted(buckets)
-        self._compiled_keys: set = set()
+        # chunk-row bucket -> abstract arguments of its compiled step
+        self._compiled_keys: dict = {}
         self._build_step_fns()
         self._refresh_prefix_gauges()
 
@@ -681,7 +682,7 @@ class PagedServingEngine(_EngineBase):
         reference demotion, which swaps the params/serve config underneath
         (old compiled variants are dropped; the recompile counter starts
         over for the new config)."""
-        self._compiled_keys = set()
+        self._compiled_keys = {}
         unified = self.ecfg.step_mode == "unified"
         cfgm, serve_p = self.cfg, self.serve
         # static: whether the step fns return an extra quant-telemetry
@@ -710,6 +711,15 @@ class PagedServingEngine(_EngineBase):
                 lambda p, pools, t, pos, ht, lt, pg, off, ih, act:
                 lm.paged_decode_step(p, pools, t, pos, ht, lt, pg, off, ih,
                                      cfgm, serve_p, active=act))
+
+    def step_program(self):
+        """The compiled unified step for the largest chunk-row bucket this
+        engine has run: ``as_text()`` shows which kernels it calls and
+        ``memory_analysis()`` its device footprint.  Lowered from the
+        abstract arguments (shapes, dtypes, shardings) of that bucket's
+        first call, so it is the program that call compiled."""
+        n_pf = max(self._compiled_keys)
+        return self._unified.lower(*self._compiled_keys[n_pf]).compile()
 
     def compile_count(self) -> int:
         """Compiled variants of the unified step this engine has built
@@ -1132,17 +1142,21 @@ class PagedServingEngine(_EngineBase):
             span_ht = np.concatenate([pf_ht, ht_np], axis=0)
             span_lt = np.concatenate([pf_lt, lt_np], axis=0)
 
+            args = (self.params, self.pools, jnp.asarray(pf_tokens),
+                    jnp.asarray(pf_start), jnp.asarray(pf_length),
+                    jnp.asarray(pf_first), jnp.asarray(pf_last),
+                    jnp.asarray(pf_slots), jnp.asarray(dec_tokens),
+                    jnp.asarray(dec_pos), jnp.asarray(dec_active),
+                    jnp.asarray(span_ht), jnp.asarray(span_lt),
+                    jnp.asarray(pages), jnp.asarray(offs), jnp.asarray(ishi))
             if n_pf not in self._compiled_keys:
-                self._compiled_keys.add(n_pf)
+                self._compiled_keys[n_pf] = jax.tree.map(
+                    lambda a: jax.ShapeDtypeStruct(
+                        a.shape, a.dtype,
+                        sharding=a.sharding if a.committed else None),
+                    args)
                 self._inc("recompiles")
-            out = self._unified(
-                self.params, self.pools, jnp.asarray(pf_tokens),
-                jnp.asarray(pf_start), jnp.asarray(pf_length),
-                jnp.asarray(pf_first), jnp.asarray(pf_last),
-                jnp.asarray(pf_slots), jnp.asarray(dec_tokens),
-                jnp.asarray(dec_pos), jnp.asarray(dec_active),
-                jnp.asarray(span_ht), jnp.asarray(span_lt),
-                jnp.asarray(pages), jnp.asarray(offs), jnp.asarray(ishi))
+            out = self._unified(*args)
             if self._collect:
                 pf_logits, dec_logits, self.pools, telem = out
             else:

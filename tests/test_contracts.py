@@ -189,7 +189,7 @@ class TestSeededJaxprViolations:
         assert "JX004" in _codes(jaxpr_lint.lint_jaxpr(closed, "fixture"))
 
     def test_f64_caught(self):
-        with jax.experimental.enable_x64():
+        with jax.enable_x64():
             closed = jax.make_jaxpr(lambda x: x.astype(jnp.float64))(
                 jnp.zeros((8,), jnp.float32))
         assert "JX001" in _codes(jaxpr_lint.lint_jaxpr(closed, "fixture"))

@@ -274,7 +274,7 @@ import jax
 import repro.launch.mesh as M
 def small(*, multi_pod=False):
     return jax.make_mesh((2, 4), ("data", "model"),
-                         **M._axis_type_kwargs(2))
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 M.make_production_mesh = small
 import repro.launch.dryrun as D
 import dataclasses, repro.configs as C

@@ -11,7 +11,7 @@ import pytest
 
 jax.config.update("jax_platform_name", "cpu")
 
-from repro.core.ptq import calibrate_and_quantize
+from repro.core.ptq import calibrate, calibrate_and_quantize
 from repro.core.stamp import StampConfig
 from repro.data.pipeline import DataConfig, calibration_batches
 from repro.launch.train import TrainConfig, train
@@ -73,6 +73,48 @@ class TestPTQPipeline:
             jax.tree.map(lambda a: a[0], packed), jnp.float32))
         rel = np.linalg.norm(deq - w_ref) / np.linalg.norm(w_ref)
         assert rel < 0.15
+
+    def test_packed_init_matches_packing_float_init(self):
+        """Initialising straight into the served form (one period at a
+        time) gives the tree of packing a float init: the same unpacked
+        leaves, scales within float rounding, and int4 codes that differ
+        only where a value sits at a rounding tie, which the compiled
+        per-period pass may break the other way (one code step, in under
+        1% of weights)."""
+        key = jax.random.PRNGKey(3)
+        packed = lm.init_params(key, CFG, weight_bits=4)
+        ref = lm.quantize_weights_for_serving(jax.tree.map(
+            lambda a: a.astype(jnp.bfloat16) if a.dtype == jnp.float32
+            else a, lm.init_params(key, CFG)), 4)
+        is_packed = lambda t: isinstance(t, dict) and "q" in t
+        a_leaves = jax.tree.leaves(packed, is_leaf=is_packed)
+        b_leaves = jax.tree.leaves(ref, is_leaf=is_packed)
+        assert jax.tree.structure(packed, is_leaf=is_packed) == \
+            jax.tree.structure(ref, is_leaf=is_packed)
+        n_packed = 0
+        for a, b in zip(a_leaves, b_leaves):
+            if not is_packed(a):
+                assert a.dtype == b.dtype
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+                continue
+            n_packed += 1
+            np.testing.assert_allclose(np.asarray(a["scale"]),
+                                       np.asarray(b["scale"]), rtol=1e-6)
+            wa = np.asarray(lm._dequant_packed(a, jnp.float32))
+            wb = np.asarray(lm._dequant_packed(b, jnp.float32))
+            step = np.asarray(a["scale"], np.float32)
+            assert (np.abs(wa - wb) <= step * 1.001 + 1e-6).all()
+            assert (np.abs(wa - wb) > 0.5 * step).mean() < 0.01
+        assert n_packed >= 4
+
+    def test_calibrate_through_packed_weights(self):
+        dcfg = DataConfig(vocab_size=CFG.vocab_size, seq_len=64,
+                          global_batch=4)
+        packed = lm.init_params(jax.random.PRNGKey(0), CFG, weight_bits=4)
+        serve, report = calibrate(packed, calibration_batches(dcfg, 2), CFG)
+        assert report.num_hi >= 1
+        assert serve.stamp.num_hi_tokens == serve.kv.num_hi == report.num_hi
+        assert serve.weight_bits == 4
 
 
 class TestServingEngine:

@@ -51,6 +51,23 @@ class TestOrthonormal:
         np.testing.assert_allclose(np.asarray(back), np.asarray(x),
                                    atol=1e-4)
 
+    @pytest.mark.parametrize("kind", ["dwt", "wht"])
+    @pytest.mark.parametrize("n", [8, 100, 127, 128])
+    @pytest.mark.parametrize("levels", [1, 3, 4])
+    @pytest.mark.parametrize("skip_first", [False, True])
+    def test_dense_matrix_matches_butterflies(self, kind, n, levels,
+                                              skip_first):
+        """The (n, n) matrix the fused kernels apply on the MXU is the
+        butterfly transform, and its transpose is the inverse."""
+        m = T.sequence_matrix(kind, n, levels, skip_first)
+        np.testing.assert_allclose(m @ m.T, np.eye(n), atol=1e-6)
+        x = correlated((1, n, 16), seed=n)
+        tx = T.sequence_transform(x, kind, levels=levels,
+                                  skip_first=skip_first)
+        np.testing.assert_allclose(np.einsum("st,btd->bsd", m, x),
+                                   np.asarray(tx), atol=1e-5)
+        assert not m.flags.writeable      # cached: shared by every caller
+
     def test_dwt2d_roundtrip(self):
         x = correlated((2, 16 * 16, 8), seed=2)
         tx = T.haar_dwt_2d(x, (16, 16), levels=3)
