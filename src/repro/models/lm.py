@@ -491,45 +491,52 @@ def _merge_heads(x: Array) -> Array:
 def _attn_qkv(p: dict, h: Array, cfg: ModelConfig,
               stamp: Optional[StampConfig]) -> tuple[Array, Array, Array]:
     """QKV projections off the normed input (shared by the prefill, decode
-    and unified paths so their dispatch rules cannot diverge)."""
-    if "wqkv" in p:
-        # merged prepared int8 QKV (prepare_fused_weights): the merged
-        # "bqkv" bias was concatenated there too — once at prepare time,
-        # not per layer call
-        bqkv = p.get("bqkv")
-        if bqkv is None and p.get("bq") is not None:
-            # legacy prepared tree (merged weight, per-site bias leaves):
-            # fall back to the per-call concat rather than dropping biases
-            bqkv = jnp.concatenate([p["bq"], p["bk"], p["bv"]], axis=-1)
-        if _use_fused(stamp, p["wqkv"]):
-            # ONE kernel call: the sequence transform + quantize of h runs
-            # once (kernel scratch), amortized over the full QKV width
-            qkv = L.stamp_fused_linear(h, p["wqkv"], bqkv, stamp,
-                                       site="qkv")
-        else:
-            # decode / reference execution against the same int8 buffers
-            qkv = _linear(_maybe_stamp(h, stamp, site="qkv"),
-                          p["wqkv"], bqkv)
-        q, k, v = jnp.split(
-            qkv, [cfg.q_dim, cfg.q_dim + cfg.kv_dim], axis=-1)
-        return q, k, v
-    h = _maybe_stamp(h, stamp, site="qkv")
-    return (_linear(h, p["wq"], p.get("bq")),
-            _linear(h, p["wk"], p.get("bk")),
-            _linear(h, p["wv"], p.get("bv")))
+    and unified paths so their dispatch rules cannot diverge), under the
+    named scope ``stamp.qkv``."""
+    with jax.named_scope("stamp.qkv"):
+        if "wqkv" in p:
+            # merged prepared int8 QKV (prepare_fused_weights): the merged
+            # "bqkv" bias was concatenated there too — once at prepare time,
+            # not per layer call
+            bqkv = p.get("bqkv")
+            if bqkv is None and p.get("bq") is not None:
+                # legacy prepared tree (merged weight, per-site bias leaves):
+                # fall back to the per-call concat rather than dropping biases
+                bqkv = jnp.concatenate([p["bq"], p["bk"], p["bv"]], axis=-1)
+            if _use_fused(stamp, p["wqkv"]):
+                # ONE kernel call: the sequence transform + quantize of h runs
+                # once (kernel scratch), amortized over the full QKV width
+                qkv = L.stamp_fused_linear(h, p["wqkv"], bqkv, stamp,
+                                           site="qkv")
+            else:
+                # decode / reference execution against the same int8 buffers
+                qkv = _linear(_maybe_stamp(h, stamp, site="qkv"),
+                              p["wqkv"], bqkv)
+            q, k, v = jnp.split(
+                qkv, [cfg.q_dim, cfg.q_dim + cfg.kv_dim], axis=-1)
+            return q, k, v
+        h = _maybe_stamp(h, stamp, site="qkv")
+        return (_linear(h, p["wq"], p.get("bq")),
+                _linear(h, p["wk"], p.get("bk")),
+                _linear(h, p["wv"], p.get("bv")))
 
 
 def _attn_out(p: dict, attn: Array, x: Array,
               stamp: Optional[StampConfig]) -> Array:
-    """Out-projection + residual (shared across paths)."""
-    if _use_fused(stamp, p["wo"]):
-        # fused out-proj: the raw head-split attention output goes straight
-        # into the kernel — its stamped quantize fuses with the head-merge
-        # reshape, so no merged (b, s, nh·hd) activation round-trips HBM
-        return x + L.stamp_fused_linear(attn, p["wo"], None, stamp,
-                                        merge_heads=True, site="wo")
-    out = _maybe_stamp(_merge_heads(attn), stamp, site="wo")
-    return x + _linear(out, p["wo"])
+    """Out-projection (named scope ``stamp.out``) + residual (shared
+    across paths)."""
+    with jax.named_scope("stamp.out"):
+        if _use_fused(stamp, p["wo"]):
+            # fused out-proj: the raw head-split attention output goes
+            # straight into the kernel — its stamped quantize fuses with the
+            # head-merge reshape, so no merged (b, s, nh·hd) activation
+            # round-trips HBM
+            y = L.stamp_fused_linear(attn, p["wo"], None, stamp,
+                                     merge_heads=True, site="wo")
+        else:
+            y = _linear(_maybe_stamp(_merge_heads(attn), stamp, site="wo"),
+                        p["wo"])
+    return x + y
 
 
 def attn_block(
@@ -553,19 +560,23 @@ def attn_block(
         # attention over the mapped pages only
         assert cache_entry is not None
         pcfg = paged["cfg"]
-        new_entry = PKV.write_tokens(cache_entry, k, v, paged["pages"],
-                                     paged["offsets"], paged["is_hi"], pcfg)
+        with jax.named_scope("attn.kv_write"):
+            new_entry = PKV.write_tokens(cache_entry, k, v, paged["pages"],
+                                         paged["offsets"], paged["is_hi"],
+                                         pcfg)
         length = paged["lengths"]
         if pcfg.quant.quantized and kw_fused(kv_cfg):
             from repro.kernels.paged_attention import paged_decode_attention
-            attn = paged_decode_attention(new_entry, q, length,
-                                          paged["hi_table"],
-                                          paged["lo_table"],
-                                          pcfg.block_size)
+            with jax.named_scope("attn.kernel"):
+                attn = paged_decode_attention(new_entry, q, length,
+                                              paged["hi_table"],
+                                              paged["lo_table"],
+                                              pcfg.block_size)
         else:
-            segs = PKV.gather_segments(new_entry, paged["hi_table"],
-                                       paged["lo_table"], pcfg, x.dtype)
-            attn = L.decode_attention_segments(q, segs, length=length)
+            with jax.named_scope("attn.fallback"):
+                segs = PKV.gather_segments(new_entry, paged["hi_table"],
+                                           paged["lo_table"], pcfg, x.dtype)
+                attn = L.decode_attention_segments(q, segs, length=length)
     elif mode == "decode":
         assert cache_entry is not None
         new_entry = KV.write_token(cache_entry, k, v, pos_scalar, kv_cfg)
@@ -692,32 +703,37 @@ def attn_block_unified(
                               k_dec.reshape(s_slots, kvh, hd)], axis=0)
     v_flat = jnp.concatenate([v_pf.reshape(n_pf * c_len, kvh, hd),
                               v_dec.reshape(s_slots, kvh, hd)], axis=0)
-    new_entry = PKV.write_ragged(cache_entry, k_flat, v_flat,
-                                 paged["pages"], paged["offsets"],
-                                 paged["is_hi"], pcfg)
+    with jax.named_scope("attn.kv_write"):
+        new_entry = PKV.write_ragged(cache_entry, k_flat, v_flat,
+                                     paged["pages"], paged["offsets"],
+                                     paged["is_hi"], pcfg)
 
     if pcfg.quant.quantized and kw_fused(kv_cfg):
         from repro.kernels.paged_attention import paged_ragged_attention
-        attn_pf, attn_dec = paged_ragged_attention(
-            new_entry, q_pf, q_dec, paged["span_starts"],
-            paged["span_lengths"], paged["span_ht"], paged["span_lt"],
-            pcfg.block_size)
+        with jax.named_scope("attn.kernel"):
+            attn_pf, attn_dec = paged_ragged_attention(
+                new_entry, q_pf, q_dec, paged["span_starts"],
+                paged["span_lengths"], paged["span_ht"], paged["span_lt"],
+                pcfg.block_size)
     else:
-        segs_dec = PKV.gather_segments(new_entry, paged["dec_ht"],
-                                       paged["dec_lt"], pcfg, x_dec.dtype)
-        attn_dec = L.decode_attention_segments(q_dec, segs_dec,
-                                               length=paged["dec_lengths"])
-        # chunk rows: ONE branch covers first and continuation chunks.  A
-        # first row's empty cached prefix (pf_start = 0) masks every
-        # segment and the online-softmax merge correction underflows to
-        # exactly zero, so the single chunked call IS the no-prefix result
-        # for those rows.  (The previous fallback evaluated BOTH variants
-        # and jnp.where-selected per row — paying the flash O(C²) scores on
-        # top of the segment attention for every chunk row, every step.)
-        segs_pf = PKV.gather_segments(new_entry, paged["pf_ht"],
-                                      paged["pf_lt"], pcfg, x_pf.dtype)
-        attn_pf = L.chunked_prefill_attention(q_pf, segs_pf, k_pf, v_pf,
-                                              paged["pf_start"])
+        with jax.named_scope("attn.fallback"):
+            segs_dec = PKV.gather_segments(new_entry, paged["dec_ht"],
+                                           paged["dec_lt"], pcfg,
+                                           x_dec.dtype)
+            attn_dec = L.decode_attention_segments(
+                q_dec, segs_dec, length=paged["dec_lengths"])
+            # chunk rows: ONE branch covers first and continuation chunks.
+            # A first row's empty cached prefix (pf_start = 0) masks every
+            # segment and the online-softmax merge correction underflows
+            # to exactly zero, so the single chunked call IS the no-prefix
+            # result for those rows.  (The previous fallback evaluated BOTH
+            # variants and jnp.where-selected per row — paying the flash
+            # O(C²) scores on top of the segment attention for every chunk
+            # row, every step.)
+            segs_pf = PKV.gather_segments(new_entry, paged["pf_ht"],
+                                          paged["pf_lt"], pcfg, x_pf.dtype)
+            attn_pf = L.chunked_prefill_attention(q_pf, segs_pf, k_pf,
+                                                  v_pf, paged["pf_start"])
 
     return (_attn_out(p, attn_pf, x_pf, stamp),
             _attn_out(p, attn_dec, x_dec, None)), new_entry
@@ -976,21 +992,25 @@ def ffn_block(p: dict, x: Array, spec: LayerSpec, cfg: ModelConfig, *,
     if spec.ffn in ("mlp", "moe_dense"):
         prefix = "d" if spec.ffn == "moe_dense" else ""
         wg, wu = p[f"{prefix}wi_gate"], p[f"{prefix}wi_up"]
-        if _use_fused(stamp, wg) and _use_fused(stamp, wu):
-            # ONE dual-output kernel call: the shared input's transform +
-            # quantize runs once (VMEM scratch) and drives both GEMMs,
-            # silu·mul epilogue included
-            g = L.stamp_fused_dual_linear(h, wg, wu, stamp, site="gate_up")
-        else:
-            hq = (_maybe_stamp(h, stamp, site="gate_up")
-                  if hq is None else hq)
-            g = jax.nn.silu(_linear(hq, wg)) * _linear(hq, wu)
-        if _use_fused(stamp, p[f"{prefix}wo_mlp"]):
-            out = out + L.stamp_fused_linear(g, p[f"{prefix}wo_mlp"], None,
-                                             stamp, site="wo_mlp")
-        else:
-            out = out + _linear(_maybe_stamp(g, stamp, site="wo_mlp"),
-                                p[f"{prefix}wo_mlp"])
+        with jax.named_scope("stamp.gate_up"):
+            if _use_fused(stamp, wg) and _use_fused(stamp, wu):
+                # ONE dual-output kernel call: the shared input's transform
+                # + quantize runs once (VMEM scratch) and drives both
+                # GEMMs, silu·mul epilogue included
+                g = L.stamp_fused_dual_linear(h, wg, wu, stamp,
+                                              site="gate_up")
+            else:
+                hq = (_maybe_stamp(h, stamp, site="gate_up")
+                      if hq is None else hq)
+                g = jax.nn.silu(_linear(hq, wg)) * _linear(hq, wu)
+        with jax.named_scope("stamp.down"):
+            if _use_fused(stamp, p[f"{prefix}wo_mlp"]):
+                y = L.stamp_fused_linear(g, p[f"{prefix}wo_mlp"], None,
+                                         stamp, site="wo_mlp")
+            else:
+                y = _linear(_maybe_stamp(g, stamp, site="wo_mlp"),
+                            p[f"{prefix}wo_mlp"])
+        out = out + y
     return x + out
 
 
@@ -1015,29 +1035,33 @@ def apply_block(spec: LayerSpec, p: dict, x: Array, cfg: ModelConfig, **kw
         # per region, inside one program.  Attention mixes through the
         # paged pools, Mamba through the slot-dense state pool.
         if spec.mixer == "attn":
-            x, entry = attn_block_unified(p, x, cfg, stamp=stamp,
-                                          kv_cfg=kw["kv_cfg"],
-                                          cache_entry=kw["cache_entry"],
-                                          paged=kw["paged"])
+            with jax.named_scope("attn"):
+                x, entry = attn_block_unified(p, x, cfg, stamp=stamp,
+                                              kv_cfg=kw["kv_cfg"],
+                                              cache_entry=kw["cache_entry"],
+                                              paged=kw["paged"])
         elif spec.mixer == "mamba":
             x, entry = mamba_block_unified(p, x, cfg, stamp=stamp,
                                            cache_entry=kw["cache_entry"],
                                            paged=kw["paged"])
         else:
             entry = None
-        x_pf = ffn_block(p, x[0], spec, cfg, stamp=stamp)
-        x_dec = ffn_block(p, x[1], spec, cfg, stamp=None)
+        with jax.named_scope("mlp"):
+            x_pf = ffn_block(p, x[0], spec, cfg, stamp=stamp)
+            x_dec = ffn_block(p, x[1], spec, cfg, stamp=None)
         return (x_pf, x_dec), entry
     if spec.mixer == "attn":
-        x, entry = attn_block(p, x, cfg, mode=kw["mode"],
-                              positions=kw["positions"], policy=kw.get("policy"),
-                              stamp=stamp, kv_cfg=kw["kv_cfg"],
-                              cache_entry=kw.get("cache_entry"),
-                              pos_scalar=kw.get("pos_scalar"),
-                              enc_out=kw.get("enc_out"),
-                              causal=kw.get("causal", True),
-                              cache_capacity=kw.get("cache_capacity"),
-                              paged=kw.get("paged"))
+        with jax.named_scope("attn"):
+            x, entry = attn_block(p, x, cfg, mode=kw["mode"],
+                                  positions=kw["positions"],
+                                  policy=kw.get("policy"),
+                                  stamp=stamp, kv_cfg=kw["kv_cfg"],
+                                  cache_entry=kw.get("cache_entry"),
+                                  pos_scalar=kw.get("pos_scalar"),
+                                  enc_out=kw.get("enc_out"),
+                                  causal=kw.get("causal", True),
+                                  cache_capacity=kw.get("cache_capacity"),
+                                  paged=kw.get("paged"))
     elif spec.mixer == "mamba":
         x, entry = mamba_block(p, x, cfg, mode=kw["mode"],
                                policy=kw.get("policy"), stamp=stamp,
@@ -1046,7 +1070,8 @@ def apply_block(spec: LayerSpec, p: dict, x: Array, cfg: ModelConfig, **kw
                                seq_lengths=kw.get("seq_lengths"))
     else:
         entry = None
-    x = ffn_block(p, x, spec, cfg, stamp=stamp)
+    with jax.named_scope("mlp"):
+        x = ffn_block(p, x, spec, cfg, stamp=stamp)
     return x, entry
 
 
@@ -1564,8 +1589,9 @@ def paged_unified_step(params, pools: dict, pf_tokens: Array,
     compute_dtype = jnp.bfloat16
     # span-major from the start: embedding is per-token, so the (n_pf, C,
     # d) per-span view of the flattened batch is built directly
-    x_pf = _embed(params, pf_tokens, compute_dtype)
-    x_dec = _embed(params, dec_tokens[:, None], compute_dtype)
+    with jax.named_scope("embed"):
+        x_pf = _embed(params, pf_tokens, compute_dtype)
+        x_dec = _embed(params, dec_tokens[:, None], compute_dtype)
     pos_pf = pf_start[:, None] + jnp.arange(c_len)[None, :]
     paged = {"cfg": serve.paged,
              "span_ht": hi_table, "span_lt": lo_table,
@@ -1593,18 +1619,18 @@ def paged_unified_step(params, pools: dict, pf_tokens: Array,
         telem = QS.end() if collect else None
     x_pf, x_dec = x
     head = _head_weight(params)
-    x_pf = L.rms_norm(x_pf, params["final_norm"].astype(x_pf.dtype),
-                      cfg.norm_eps)
-    x_last = jnp.take_along_axis(x_pf, pf_last_index[:, None, None], axis=1)
-    pf_logits = _linear(x_last, head)[:, 0]
-    x_dec = L.rms_norm(x_dec, params["final_norm"].astype(x_dec.dtype),
-                       cfg.norm_eps)
-    dec_logits = _linear(x_dec[:, 0], head)
+    with jax.named_scope("head"):
+        x_pf = L.rms_norm(x_pf, params["final_norm"].astype(x_pf.dtype),
+                          cfg.norm_eps)
+        x_last = jnp.take_along_axis(x_pf, pf_last_index[:, None, None],
+                                     axis=1)
+        pf_logits = _linear(x_last, head)[:, 0].astype(jnp.float32)
+        x_dec = L.rms_norm(x_dec, params["final_norm"].astype(x_dec.dtype),
+                           cfg.norm_eps)
+        dec_logits = _linear(x_dec[:, 0], head).astype(jnp.float32)
     if collect:
-        return (pf_logits.astype(jnp.float32),
-                dec_logits.astype(jnp.float32), new_pools, telem)
-    return (pf_logits.astype(jnp.float32), dec_logits.astype(jnp.float32),
-            new_pools)
+        return pf_logits, dec_logits, new_pools, telem
+    return pf_logits, dec_logits, new_pools
 
 
 def paged_decode_step(params, pools: dict, tokens: Array, positions: Array,
@@ -1634,7 +1660,8 @@ def paged_decode_step(params, pools: dict, tokens: Array, positions: Array,
     set_fused_cache_attention(serve.fused_cache_attention)
     set_fused_decode_matmul(serve.fused_decode_matmul)
     compute_dtype = jnp.bfloat16
-    x = _embed(params, tokens[:, None], compute_dtype)
+    with jax.named_scope("embed"):
+        x = _embed(params, tokens[:, None], compute_dtype)
     if active is None:
         active = jnp.ones(tokens.shape, bool)
     paged = {"cfg": serve.paged, "hi_table": hi_table, "lo_table": lo_table,
@@ -1644,6 +1671,7 @@ def paged_decode_step(params, pools: dict, tokens: Array, positions: Array,
                              positions=positions[:, None], policy=policy,
                              stamp=None, kv_cfg=serve.kv, cache=pools,
                              pos_scalar=positions, paged=paged)
-    x = L.rms_norm(x, params["final_norm"].astype(x.dtype), cfg.norm_eps)
-    logits = _linear(x[:, 0], _head_weight(params))
-    return logits.astype(jnp.float32), new_pools
+    with jax.named_scope("head"):
+        x = L.rms_norm(x, params["final_norm"].astype(x.dtype), cfg.norm_eps)
+        logits = _linear(x[:, 0], _head_weight(params)).astype(jnp.float32)
+    return logits, new_pools

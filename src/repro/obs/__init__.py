@@ -11,7 +11,8 @@ Three modules, layered by what they may import:
 * `trace.py` — pure stdlib.  The typed :class:`Event` record that replaced
   the engines' mixed-arity event tuples (tuple-unpacking stays compatible
   via ``__iter__``), the :class:`StepTimer` that times the engine step
-  phases (plan / dispatch / post), and `export_chrome_trace` rendering
+  phases (plan / dispatch / post, and nested parts of a phase), each also
+  an ``engine.<phase>`` profiler span, and `export_chrome_trace` rendering
   per-request span timelines + per-step phase slices as Chrome
   trace-event JSON (load in Perfetto / ``chrome://tracing``).
 * `quantstats.py` — imports jax.  Per-STaMP-site activation clip rate,

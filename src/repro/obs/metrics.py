@@ -24,6 +24,9 @@ Design notes
   counters like ``recompiles``.
 * The injectable ``clock`` only stamps snapshots (wall-clock metadata);
   engine phase timing uses its own observability clock (see trace.py).
+* ``on_read(fn)`` registers a collector that ``snapshot()`` and
+  ``to_prometheus()`` call first: gauges derived from live state are
+  computed when someone reads them, not on every engine step.
 """
 
 from __future__ import annotations
@@ -32,7 +35,8 @@ import bisect
 import json
 import re
 import time
-from typing import Dict, Iterable, List, Optional, Sequence, Tuple
+from typing import (Callable, Dict, Iterable, List, Optional, Sequence,
+                    Tuple)
 
 _NAME_RE = re.compile(r"^[a-zA-Z_:][a-zA-Z0-9_:]*$")
 
@@ -184,6 +188,16 @@ class MetricsRegistry:
     def __init__(self, clock=time.time):
         self._families: Dict[str, _Family] = {}
         self._clock = clock
+        self._collectors: List[Callable[[], None]] = []
+
+    def on_read(self, fn: Callable[[], None]) -> None:
+        """Call ``fn`` before every snapshot or exposition, so that the
+        gauges it sets reflect the state at the moment of the read."""
+        self._collectors.append(fn)
+
+    def _collect(self) -> None:
+        for fn in self._collectors:
+            fn()
 
     # -- get-or-create accessors ----------------------------------------
     def _family(self, name: str, typ: str, help: str,
@@ -229,6 +243,7 @@ class MetricsRegistry:
     # -- exposition ------------------------------------------------------
     def snapshot(self) -> dict:
         """Plain-data view: {"t", "counters", "gauges", "histograms"}."""
+        self._collect()
         out = {"t": float(self._clock()),
                "counters": {}, "gauges": {}, "histograms": {}}
         for fam in sorted(self._families.values(), key=lambda f: f.name):
@@ -253,6 +268,7 @@ class MetricsRegistry:
 
     def to_prometheus(self) -> str:
         """Prometheus text exposition format (v0.0.4)."""
+        self._collect()
         lines: List[str] = []
         for fam in sorted(self._families.values(), key=lambda f: f.name):
             if fam.help:

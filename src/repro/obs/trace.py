@@ -12,10 +12,15 @@ tuple for decode batches, ``(uid, error)`` for error terminals).
 :class:`StepTimer` wraps the three phases of an engine step — ``plan``
 (deadlines + scheduler), ``dispatch`` (host batch build + the device
 program + result materialization), ``post`` (token post-loops) — into
-histogram observations and per-step phase events.  It reads the
-*observability* clock exactly twice per phase (enter/exit), so a
-fake tick-clock test can pin exact durations; engine semantics
-(deadlines, TTFT) stay on the engine's own clock, untouched.
+histogram observations and per-step phase events.  Phases nest: the
+unified step splits ``dispatch`` into ``build_inputs``, ``upload``,
+``launch``, ``wait`` and ``fetch_logits``, each its own histogram label.
+It reads the *observability* clock exactly twice per phase (enter/exit),
+so a fake tick-clock test can pin exact durations; engine semantics
+(deadlines, TTFT) stay on the engine's own clock, untouched.  Given an
+``annotate`` factory (the engine passes ``jax.profiler.TraceAnnotation``;
+this module imports no jax), each phase is also a host span named
+``engine.<phase>`` on the profiler's clock, beside the device's ops.
 
 `export_chrome_trace` renders the event ring as Chrome trace-event JSON
 (the ``{"traceEvents": [...]}`` object form): one thread per request
@@ -80,34 +85,46 @@ class Event:
 
 class StepTimer:
     """Times named step phases into a histogram family and emits one
-    ``phase`` event per occurrence.
+    ``phase`` event per top-level occurrence.
 
     ``clock`` is called exactly twice per phase (enter + exit); pass the
     engine's observability tick so event timestamps advance with phase
     boundaries.  ``on_phase(name, t0, dur)`` lets the engine append the
-    phase slice to its event ring.
+    phase slice to its event ring; nested phases skip it, so the ring
+    keeps one slice per top-level phase.  ``annotate(span_name)``, if
+    given, returns a context manager opened around each phase as the
+    span ``engine.<name>``.
     """
 
     def __init__(self, metrics, clock: Callable[[], float],
                  on_phase: Optional[Callable[[str, float, float], None]] = None,
-                 buckets=None):
+                 buckets=None,
+                 annotate: Optional[Callable[[str], Any]] = None):
         self._metrics = metrics
         self._clock = clock
         self._on_phase = on_phase
         self._buckets = buckets
+        self._annotate = annotate
+        self._depth = 0
 
     @contextlib.contextmanager
     def phase(self, name: str):
-        t0 = self._clock()
-        try:
-            yield
-        finally:
-            dur = self._clock() - t0
-            self._metrics.histogram(
-                "step_phase_s", help="engine step phase wall time",
-                buckets=self._buckets, labels={"phase": name}).observe(dur)
-            if self._on_phase is not None:
-                self._on_phase(name, t0, dur)
+        span = self._annotate(f"engine.{name}") if self._annotate \
+            else contextlib.nullcontext()
+        top = self._depth == 0
+        self._depth += 1
+        with span:
+            t0 = self._clock()
+            try:
+                yield
+            finally:
+                dur = self._clock() - t0
+                self._depth -= 1
+                self._metrics.histogram(
+                    "step_phase_s", help="engine step phase wall time",
+                    buckets=self._buckets, labels={"phase": name}).observe(dur)
+                if top and self._on_phase is not None:
+                    self._on_phase(name, t0, dur)
 
 
 # ---------------------------------------------------------------------------

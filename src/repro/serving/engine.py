@@ -168,9 +168,14 @@ class _EngineBase:
         self.metrics = MetricsRegistry()
         for k in self.STAT_KEYS:
             self.metrics.counter(k, help=f"engine {k.replace('_', ' ')}")
+        # host spans on the profiler's clock (``engine.<phase>``): one
+        # TraceMe each, and nothing is recorded unless a trace is running
+        self._annotate = jax.profiler.TraceAnnotation
         self._timer = StepTimer(self.metrics, self._tick,
-                                on_phase=self._on_phase)
+                                on_phase=self._on_phase,
+                                annotate=self._annotate)
         self.events: collections.deque = collections.deque()
+        self.metrics.on_read(self._refresh_derived_gauges)
         # the pre-`prepare_fused_weights` weights: fused preparation merges
         # wq/wk/wv into one int8 wqkv (destructively, per site), so demoting
         # a misbehaving engine back to reference execution needs this copy
@@ -254,16 +259,16 @@ class _EngineBase:
         for BOTH engines."""
         self.metrics.reset(exclude=keep)
         self._refresh_eligibility()   # reset() zeroes gauges; re-publish
-        self._refresh_derived_gauges()
         if clear_events:
             self.events.clear()
 
     def _refresh_derived_gauges(self) -> None:
-        """Hook for gauges derived from live engine state (same recompute
-        rule as ``reference_fallback_sites``): re-published after any
-        ``metrics.reset`` so a warmup/measure boundary never zeroes what
-        the state still says.  The paged engine recomputes its
-        prefix-cache gauges here; the base has none."""
+        """Hook for gauges derived from live engine state, run whenever
+        the registry is read (``metrics.snapshot``/``to_prometheus``,
+        ``stats``) rather than on every step: a read always shows the
+        state as it is, and a ``metrics.reset`` cannot zero what the state
+        still says.  The paged engine publishes its scheduler occupancy
+        and prefix-cache gauges here; the base has none."""
 
     def _observe_latency(self, name: str, seconds: float) -> None:
         self.metrics.histogram(name, help=f"request {name}").observe(
@@ -615,7 +620,6 @@ class PagedServingEngine(_EngineBase):
         # chunk-row bucket -> abstract arguments of its compiled step
         self._compiled_keys: dict = {}
         self._build_step_fns()
-        self._refresh_prefix_gauges()
 
     # -- prefix caching -------------------------------------------------
     def _on_prefix_lookup(self, sreq: SchedRequest, match) -> None:
@@ -636,12 +640,17 @@ class PagedServingEngine(_EngineBase):
         self._inc("cow_copies")
         self._event("cow", uid=sreq.uid, pool=pool, src=src, dst=dst)
 
-    def _refresh_prefix_gauges(self) -> None:
-        """Publish the prefix-cache gauges from LIVE allocator state (and
-        the hit-rate from the counters).  Like ``reference_fallback_sites``
-        these are recomputed — never carried — so ``reset_stats`` and a
-        fused → reference demotion cannot zero what the allocator still
-        holds."""
+    def _refresh_derived_gauges(self) -> None:
+        """Publish the scheduler occupancy gauges (``sched_*``) and the
+        prefix-cache gauges from LIVE scheduler and allocator state (and
+        the hit-rate from the counters), at read time: ``cache_stats()``
+        walks every page's refs, which no step should pay for.  Like
+        ``reference_fallback_sites`` these are recomputed — never carried —
+        so ``reset_stats`` and a fused → reference demotion cannot zero
+        what the allocator still holds."""
+        for name, v in self.sched.load().items():
+            self.metrics.gauge(f"sched_{name}",
+                               help=f"scheduler {name}").set(v)
         cs = self.sched.alloc.cache_stats()
         q = self.metrics.counter("prefix_cache_queries").value
         h = self.metrics.counter("prefix_cache_hits").value
@@ -662,11 +671,9 @@ class PagedServingEngine(_EngineBase):
             help="pages registered in the prefix cache").set(
             cs["cached_pages"])
 
-    def _refresh_derived_gauges(self) -> None:
-        self._refresh_prefix_gauges()
-
     @property
     def stats(self) -> Dict[str, int]:
+        self._refresh_derived_gauges()
         out = _EngineBase.stats.fget(self)
         g = self.metrics.gauge
         out["prefix_cache_hit_rate"] = float(
@@ -871,11 +878,21 @@ class PagedServingEngine(_EngineBase):
         done: List[Request] = []
         self._drain_terminal(done)   # submit-time rejects / early cancels
         while self.sched.has_work():
-            self._step(done)
-            self._drain_terminal(done)
+            done += self.step()
         dt = self._clock() - t0
         for r in done:
             r.latency_s = r.latency_s or dt
+        return done
+
+    def step(self) -> List[Request]:
+        """Run one engine step (one device program in unified mode) and
+        return the requests that reached a terminal state in it, finished
+        or not.  Synchronous: the step's logits are on the host when it
+        returns.  ``run()`` is this in a loop until no work is left."""
+        done: List[Request] = []
+        with self._annotate("engine.step"):
+            self._step(done)
+            self._drain_terminal(done)
         return done
 
     def _drain_terminal(self, done: List[Request]) -> None:
@@ -995,7 +1012,6 @@ class PagedServingEngine(_EngineBase):
             fused_decode_matmul=False)
         self._build_step_fns()
         self._refresh_eligibility()
-        self._refresh_prefix_gauges()
         self._inc("demotions")
         self._event("demote", to="reference")
 
@@ -1069,14 +1085,6 @@ class PagedServingEngine(_EngineBase):
         elif progress:
             self._run_unified(plan, done)
         self._watchdog(progress)
-        self._publish_load()
-
-    def _publish_load(self) -> None:
-        """Per-step occupancy gauges from the scheduler/allocator."""
-        for name, v in self.sched.load().items():
-            self.metrics.gauge(f"sched_{name}",
-                               help=f"scheduler {name}").set(v)
-        self._refresh_prefix_gauges()
 
     def _run_unified(self, plan, done: List[Request]) -> None:
         """Build the flattened ragged batch the scheduler planned and run
@@ -1088,67 +1096,66 @@ class PagedServingEngine(_EngineBase):
         n_pf = self._bucket_npf(len(works))
         telem = None
         with self._timer.phase("dispatch"):
-            pf_tokens = np.zeros((n_pf, c_len), np.int32)
-            pf_start = np.zeros((n_pf,), np.int32)
-            pf_length = np.zeros((n_pf,), np.int32)
-            pf_first = np.zeros((n_pf,), bool)
-            pf_last = np.zeros((n_pf,), np.int32)
-            # dummy chunk rows park on the null slot (index max_slots):
-            # their SSM-state scatter lands there the way masked K/V
-            # writes land on the null page
-            pf_slots = np.full((n_pf,), s, np.int32)
-            pages = np.zeros((n_pf * c_len + s,), np.int32)
-            offs = np.zeros((n_pf * c_len + s,), np.int32)
-            ishi = np.zeros((n_pf * c_len + s,), bool)
-            for i, w in enumerate(works):
-                sreq, start, end = w.sreq, w.start, w.end
-                valid = end - start
-                pf_tokens[i, :valid] = sreq.prompt[start:end]
-                pf_start[i] = start
-                pf_length[i] = end
-                pf_first[i] = start == 0
-                pf_slots[i] = sreq.slot
-                # the chunk's last valid row — on a final chunk that is
-                # the prompt's last token, whose logits are the
-                # first-token distribution (pf_logits of non-final chunks
-                # are discarded)
-                pf_last[i] = valid - 1
-                base = i * c_len
-                if self._has_attn:
-                    for t in range(valid):
-                        pages[base + t], offs[base + t], ishi[base + t] = \
-                            self._write_target(sreq, start + t)
-            dec_tokens = np.zeros((s,), np.int32)
-            dec_pos = np.zeros((s,), np.int32)
-            dec_active = np.zeros((s,), bool)
-            base = n_pf * c_len
-            for sreq in plan.decode:
-                dec_tokens[sreq.slot] = sreq.generated[-1]
-                dec_pos[sreq.slot] = sreq.pos
-                dec_active[sreq.slot] = True
-                if self._has_attn:
-                    pages[base + sreq.slot], offs[base + sreq.slot], \
-                        ishi[base + sreq.slot] = \
-                        self._write_target(sreq, sreq.pos)
-            # span-ordered tables: one row per chunk span (that request's
-            # own table), then the whole slot array for the decode spans
-            ht_np, lt_np = self._tables_np([w.sreq for w in works]
-                                           + plan.decode)
-            pf_ht = np.zeros((n_pf, ht_np.shape[1]), np.int32)
-            pf_lt = np.zeros((n_pf, lt_np.shape[1]), np.int32)
-            for i, w in enumerate(works):
-                pf_ht[i] = ht_np[w.sreq.slot]
-                pf_lt[i] = lt_np[w.sreq.slot]
-            span_ht = np.concatenate([pf_ht, ht_np], axis=0)
-            span_lt = np.concatenate([pf_lt, lt_np], axis=0)
-
-            args = (self.params, self.pools, jnp.asarray(pf_tokens),
-                    jnp.asarray(pf_start), jnp.asarray(pf_length),
-                    jnp.asarray(pf_first), jnp.asarray(pf_last),
-                    jnp.asarray(pf_slots), jnp.asarray(dec_tokens),
-                    jnp.asarray(dec_pos), jnp.asarray(dec_active),
-                    jnp.asarray(span_ht), jnp.asarray(span_lt),
-                    jnp.asarray(pages), jnp.asarray(offs), jnp.asarray(ishi))
+            with self._timer.phase("build_inputs"):
+                pf_tokens = np.zeros((n_pf, c_len), np.int32)
+                pf_start = np.zeros((n_pf,), np.int32)
+                pf_length = np.zeros((n_pf,), np.int32)
+                pf_first = np.zeros((n_pf,), bool)
+                pf_last = np.zeros((n_pf,), np.int32)
+                # dummy chunk rows park on the null slot (index max_slots):
+                # their SSM-state scatter lands there the way masked K/V
+                # writes land on the null page
+                pf_slots = np.full((n_pf,), s, np.int32)
+                pages = np.zeros((n_pf * c_len + s,), np.int32)
+                offs = np.zeros((n_pf * c_len + s,), np.int32)
+                ishi = np.zeros((n_pf * c_len + s,), bool)
+                for i, w in enumerate(works):
+                    sreq, start, end = w.sreq, w.start, w.end
+                    valid = end - start
+                    pf_tokens[i, :valid] = sreq.prompt[start:end]
+                    pf_start[i] = start
+                    pf_length[i] = end
+                    pf_first[i] = start == 0
+                    pf_slots[i] = sreq.slot
+                    # the chunk's last valid row — on a final chunk that is
+                    # the prompt's last token, whose logits are the
+                    # first-token distribution (pf_logits of non-final chunks
+                    # are discarded)
+                    pf_last[i] = valid - 1
+                    base = i * c_len
+                    if self._has_attn:
+                        for t in range(valid):
+                            pages[base + t], offs[base + t], ishi[base + t] = \
+                                self._write_target(sreq, start + t)
+                dec_tokens = np.zeros((s,), np.int32)
+                dec_pos = np.zeros((s,), np.int32)
+                dec_active = np.zeros((s,), bool)
+                base = n_pf * c_len
+                for sreq in plan.decode:
+                    dec_tokens[sreq.slot] = sreq.generated[-1]
+                    dec_pos[sreq.slot] = sreq.pos
+                    dec_active[sreq.slot] = True
+                    if self._has_attn:
+                        pages[base + sreq.slot], offs[base + sreq.slot], \
+                            ishi[base + sreq.slot] = \
+                            self._write_target(sreq, sreq.pos)
+                # span-ordered tables: one row per chunk span (that request's
+                # own table), then the whole slot array for the decode spans
+                ht_np, lt_np = self._tables_np([w.sreq for w in works]
+                                               + plan.decode)
+                pf_ht = np.zeros((n_pf, ht_np.shape[1]), np.int32)
+                pf_lt = np.zeros((n_pf, lt_np.shape[1]), np.int32)
+                for i, w in enumerate(works):
+                    pf_ht[i] = ht_np[w.sreq.slot]
+                    pf_lt[i] = lt_np[w.sreq.slot]
+                span_ht = np.concatenate([pf_ht, ht_np], axis=0)
+                span_lt = np.concatenate([pf_lt, lt_np], axis=0)
+            with self._timer.phase("upload"):
+                args = (self.params, self.pools) + tuple(
+                    jnp.asarray(a) for a in (
+                        pf_tokens, pf_start, pf_length, pf_first, pf_last,
+                        pf_slots, dec_tokens, dec_pos, dec_active, span_ht,
+                        span_lt, pages, offs, ishi))
             if n_pf not in self._compiled_keys:
                 self._compiled_keys[n_pf] = jax.tree.map(
                     lambda a: jax.ShapeDtypeStruct(
@@ -1156,14 +1163,20 @@ class PagedServingEngine(_EngineBase):
                         sharding=a.sharding if a.committed else None),
                     args)
                 self._inc("recompiles")
-            out = self._unified(*args)
+            with self._timer.phase("launch"):
+                out = self._unified(*args)
             if self._collect:
                 pf_logits, dec_logits, self.pools, telem = out
             else:
                 pf_logits, dec_logits, self.pools = out
             self._inc("device_dispatches")
-            pf_logits = np.asarray(pf_logits)
-            dec_logits = np.asarray(dec_logits)
+            # the wait for the device, apart from the copy to the host
+            # (np.asarray would block here anyway: no added sync)
+            with self._timer.phase("wait"):
+                jax.block_until_ready((pf_logits, dec_logits))
+            with self._timer.phase("fetch_logits"):
+                pf_logits = np.asarray(pf_logits)
+                dec_logits = np.asarray(dec_logits)
         if telem is not None:
             self._absorb_telemetry(telem)
 

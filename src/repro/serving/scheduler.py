@@ -275,7 +275,7 @@ class Scheduler:
                 and self.alloc.all_free())
 
     def load(self) -> dict:
-        """Occupancy snapshot for the engine's per-step gauges: queue
+        """Occupancy snapshot for the engine's ``sched_*`` gauges: queue
         depths, free decode slots, and free pages per pool family."""
         free_hi, free_lo = self.alloc.free_counts()
         return {"waiting": len(self.waiting),

@@ -6,6 +6,7 @@ numpy oracle (including deliberately clipped injected scales), and the
 engine-level contract: one registry-backed ``stats`` surface on BOTH
 engines and ZERO extra device dispatches when telemetry is on."""
 
+import contextlib
 import json
 
 import jax
@@ -214,6 +215,52 @@ class TestStepTimer:
                 raise RuntimeError("boom")
         assert reg.histogram("step_phase_s",
                              labels={"phase": "post"}).count == 1
+
+    def test_nested_phases_time_into_their_own_labels(self):
+        """A phase inside a phase observes its own label, the outer sum
+        still covers it, each phase reads the clock twice inside its span
+        ``engine.<name>``, and only the top-level phase reaches the ring."""
+        clk = TickClock(tick=1.0)
+        reg = MetricsRegistry()
+        slices, spans = [], []
+
+        @contextlib.contextmanager
+        def annotate(name):
+            spans.append(("open", name, clk.reads))
+            yield
+            spans.append(("close", name, clk.reads))
+
+        timer = StepTimer(reg, clk, annotate=annotate,
+                          on_phase=lambda n, t0, d: slices.append((n, t0, d)))
+        with timer.phase("dispatch"):
+            with timer.phase("upload"):
+                pass
+            with timer.phase("launch"):
+                pass
+        assert clk.reads == 6                       # exactly 2 per phase
+        sums = {ph: reg.histogram("step_phase_s", labels={"phase": ph}).sum
+                for ph in ("dispatch", "upload", "launch")}
+        assert sums == {"dispatch": 5.0, "upload": 1.0, "launch": 1.0}
+        assert slices == [("dispatch", 1.0, 5.0)]
+        assert spans == [("open", "engine.dispatch", 0),
+                         ("open", "engine.upload", 1),
+                         ("close", "engine.upload", 3),
+                         ("open", "engine.launch", 3),
+                         ("close", "engine.launch", 5),
+                         ("close", "engine.dispatch", 6)]
+
+    def test_depth_recovers_after_an_exception(self):
+        """A nested phase that raises leaves the next phase top-level."""
+        slices = []
+        timer = StepTimer(MetricsRegistry(), TickClock(),
+                          on_phase=lambda n, t0, d: slices.append(n))
+        with pytest.raises(RuntimeError):
+            with timer.phase("dispatch"):
+                with timer.phase("launch"):
+                    raise RuntimeError("boom")
+        with timer.phase("post"):
+            pass
+        assert slices == ["dispatch", "post"]
 
 
 # ---------------------------------------------------------------------------
@@ -486,6 +533,36 @@ class TestEngineObservability:
         # drained engine: nothing waiting or active
         assert snap["gauges"]["sched_waiting"] == 0
         assert snap["gauges"]["sched_active"] == 0
+
+    def test_occupancy_gauges_computed_when_read(self, params, prompts,
+                                                 monkeypatch):
+        """The ``sched_*`` and prefix-cache gauges cost no step: nothing
+        walks the allocator while the engine runs, and every read of the
+        registry shows the state at that moment."""
+        eng = PagedServingEngine(params, CFG,
+                                 lm.ServeConfig(stamp=None, kv=QUANT),
+                                 _paged_cfg())
+        walks = []
+        cache_stats = eng.sched.alloc.cache_stats
+        monkeypatch.setattr(eng.sched.alloc, "cache_stats",
+                            lambda: walks.append(1) or cache_stats())
+        for p in prompts:
+            eng.submit(p, max_new_tokens=6)
+        assert eng.metrics.snapshot()["gauges"]["sched_waiting"] \
+            == len(prompts)
+        n = len(walks)
+        eng.step()
+        assert len(walks) == n, "a step walked the allocator"
+        snap = eng.metrics.snapshot()["gauges"]
+        assert len(walks) == n + 1
+        assert {k: snap[f"sched_{k}"] for k in eng.sched.load()} \
+            == eng.sched.load()
+        assert f"sched_active {eng.sched.load()['active']}" \
+            in eng.metrics.to_prometheus()
+        eng.run()
+        assert len(walks) == n + 2       # the exposition; run() reads none
+        assert eng.stats["prefix_cached_pages"] == \
+            eng.sched.alloc.cache_stats()["cached_pages"]
 
     def test_obs_clock_isolated_from_engine_clock(self, params, prompts):
         """Deadline semantics live on the engine clock; histograms and
