@@ -76,7 +76,8 @@ def _check_capture(cap, vmem_budget: int, out: list) -> None:
                     f"{role}[{i}] dim {d}: shape {dim} % block {blk} != 0"))
 
     # -- VMEM footprint --------------------------------------------------
-    resident = sum(_block_bytes(b) for _, _, b in buffers) * 2  # dbl-buffer
+    resident = sum(_block_bytes(b) for _, _, b in buffers
+                   if not b.hbm) * 2                         # dbl-buffer
     resident += sum(int(np.prod(shape)) * _itemsize(dt)
                     for shape, dt in cap.scratch)
     if resident > vmem_budget:

@@ -99,11 +99,10 @@ rules keep the kernels correct inside that program:
   `stamp_matmul.stamp_quant_segment_matmul_pallas`.  Decode spans are
   single tokens — their transform is the identity, which is why the
   decode region applies none.
-* **Ragged attention grid** — `paged_attention.paged_ragged_attention`
-  walks query spans: decode spans take the existing online-softmax path,
-  prefill spans add causal masking within the chunk against their own
-  block-table prefix (one mask rule, ``kv_pos <= q_pos AND kv_pos <
-  length``).  See the paged layout section below.
+* **Ragged attention** — `paged_attention.paged_ragged_attention`
+  walks query spans: decode spans over their pages, chunk rows over their
+  cached prefix, then causally over their raw chunk (the XLA fallback's
+  result).  See the paged layout section below.
 * **Decode-matmul dispatch by shape** — both regions share one trace, so
   the single-token integer matmul (below) keys on the token dim being 1:
   decode sub-tensors ``(S, 1, d)`` take it, chunk rows ``(n_pf, C>1, d)``
@@ -123,26 +122,29 @@ only the mixed-precision memory layout:
 
 Paged-attention block layout
 ----------------------------
-`paged_attention.paged_decode_attention` serves the continuous-batching
-engine (`serving/scheduler.py` + `serving/paged_kvcache.py`).  The cache is
-two shared page pools instead of per-slot dense buffers:
+`paged_attention` serves the continuous-batching engine
+(`serving/scheduler.py` + `serving/paged_kvcache.py`).  The cache is two
+shared page pools instead of per-slot dense buffers:
 
 * **hi pool** ``(NH, bs, kv, hd)`` int8 — pages holding the first
   ``num_hi`` logical tokens of each sequence (the attention-sink region)
   at 8 bits; ``num_hi % bs == 0`` so pages are single-precision.
-* **lo pool** ``(NL, bs, kv, hd/2)`` uint8 — int4 nibble pairs packed along
-  head_dim: one page holds ``bs`` tokens in half the bytes, and per-token
-  f16 scale/zp pages ride alongside so a page is self-describing (swap /
-  preemption moves one contiguous unit).
+* **lo pool** ``(NL, bs, kv·hd/2)`` uint8 — int4 nibble pairs packed along
+  head_dim, with the page's f16 scales and zero points in
+  ``lo_scale_zp``: a page is lane-dense and contiguous, so one async copy
+  moves it.
 
-Each slot maps logical block ``k`` to a physical page through a
-scalar-prefetched block table; the BlockSpec index map does the lookup, so
-Mosaic pipelines page fetches exactly like dense block fetches.  Grid is
-``(slots, kv_heads, NH_seq + NL_seq)`` with the online-softmax (m, l, acc)
-accumulated across the logical-block axis in the revisited output ref.
-Unmapped blocks clamp to page 0 (the null page) and mask out via the
-per-slot length; HBM traffic per step is proportional to *allocated* pages,
-not the engine-wide ``max_seq`` reservation.
+Each span maps logical block ``k`` to a physical page through its block
+table.  The kernel's grid is the spans; per span it walks only the span's
+own int4 pages, ~512 tokens a step, double-buffered (page copies for the
+next step start before this step's wait), and keeps the online-softmax
+``(m, l, acc)`` in the span's output block.  The sink pages (a fixed few
+per span) go through the XLA gather and merge with the kernel's
+statistics, as the fallback merges its segments.  On a TPU the unified
+step takes the kernel for every quantized pool it compiles for
+(`lm.paged_kernel`; the geometries are one rule,
+`paged_attention.unsupported`); elsewhere the fallback runs unless
+``fused_cache_attention`` forces the kernel in interpret mode.
 
 Hybrid dense + paged layout
 ---------------------------

@@ -75,93 +75,55 @@ def stamp_decode_matmul_ref(x, qw, sw, zw, bias=None,
 
 def paged_attention_ref(entry, q, lengths, hi_table, lo_table, block_size,
                         num_hi):
-    """Gather-based oracle for `paged_decode_attention`: densify the mapped
-    pages per slot and run the segment-merged decode attention."""
-    from repro.models.layers import decode_attention_segments
-    from repro.serving import kvcache as KV
+    """Gather-based oracle for `paged_decode_attention`, sharing no code
+    with the cache's own readers: densify each slot's mapped pages token by
+    token from the documented layout (`serving/paged_kvcache.py`), then a
+    direct masked softmax over positions ``< length``."""
+    hi_table = np.asarray(hi_table)
+    lo_table = np.asarray(lo_table)
+    s_slots, _, h, hd = q.shape
+    kv_heads = entry["k_hi"].shape[2]
+    bits = np.asarray(entry["lo_scale_zp"]).view(np.float16).astype(
+        np.float32)                                  # (NL, rows, lanes)
 
-    def dense(codes, table):
-        g = codes[table]
-        return g.reshape(g.shape[0], g.shape[1] * g.shape[2], *g.shape[3:])
+    def hi_tokens(name):
+        pages = hi_table.reshape(-1)
+        codes = np.asarray(entry[f"{name}_hi"])[pages].astype(np.float32)
+        sc = np.asarray(entry[f"{name}_hi_scale"])[pages].astype(np.float32)
+        zp = np.asarray(entry[f"{name}_hi_zp"])[pages].astype(np.float32)
+        vals = (codes - zp[..., None]) * sc[..., None]
+        return vals.reshape(s_slots, -1, kv_heads, hd)
 
-    segs = []
-    for region, table, offset in (("hi", hi_table, 0),
-                                  ("lo", lo_table, num_hi)):
-        pair = []
-        for name in ("k", "v"):
-            codes = dense(entry[f"{name}_{region}"], table)
-            sc = dense(entry[f"{name}_{region}_scale"], table)
-            zp = dense(entry[f"{name}_{region}_zp"], table)
-            vals = codes.astype(jnp.float32) if region == "hi" \
-                else KV.unpack_nibbles(codes)
-            pair.append(KV.dequant_tokens(vals, sc, zp, jnp.float32))
-        segs.append((pair[0], pair[1], offset))
-    return decode_attention_segments(q.astype(jnp.float32), segs,
-                                     length=lengths)
+    def lo_tokens(name, kv):
+        pages = np.repeat(lo_table.reshape(-1), block_size)
+        t = np.tile(np.arange(block_size), lo_table.size)
+        codes = np.asarray(entry[f"{name}_lo"])[pages, t].view(np.uint8)
+        codes = codes.reshape(-1, kv_heads, hd // 2).astype(np.float32)
+        # byte i of a head holds dims 2i (high nibble) and 2i + 1 (low)
+        vals = np.stack([codes // 16, codes % 16], axis=-1).reshape(
+            -1, kv_heads, hd)
+        # token 4r + b: scale in row 2r, zero point in row 2r + 1, at lane
+        # kv·4·kv_heads + b·kv_heads + head
+        lanes = kv * 4 * kv_heads + (t % 4)[:, None] * kv_heads \
+            + np.arange(kv_heads)[None, :]
+        sc = bits[pages[:, None], 2 * (t // 4)[:, None], lanes]
+        zp = bits[pages[:, None], 2 * (t // 4)[:, None] + 1, lanes]
+        vals = (vals - zp[..., None]) * sc[..., None]
+        return vals.reshape(s_slots, -1, kv_heads, hd)
 
-
-def paged_ragged_attention_ref(entry, q_pf, q_dec, q_starts, lengths,
-                               hi_table, lo_table):
-    """Dense oracle for `paged_ragged_attention`: densify each span's mapped
-    pages and compute a direct (non-online) masked softmax per query row
-    with the unified rule ``kv_pos <= q_pos AND kv_pos < length``."""
-    from repro.serving import kvcache as KV
-
-    n_pf, c_len, h, hd = q_pf.shape
-    s_slots = q_dec.shape[0]
-    g = entry["k_lo"].shape[2]
-    rep = h // g
-
-    def dense(codes, table):
-        gathered = codes[table]
-        return gathered.reshape(gathered.shape[0],
-                                gathered.shape[1] * gathered.shape[2],
-                                *gathered.shape[3:])
-
-    def span_kv(table_row_hi, table_row_lo):
-        pair = []
-        for name in ("k", "v"):
-            parts = []
-            for region, row in (("hi", table_row_hi), ("lo", table_row_lo)):
-                if row.shape[0] == 0:
-                    continue
-                codes = dense(entry[f"{name}_{region}"], row[None])
-                sc = dense(entry[f"{name}_{region}_scale"], row[None])
-                zp = dense(entry[f"{name}_{region}_zp"], row[None])
-                vals = codes.astype(jnp.float32) if region == "hi" \
-                    else KV.unpack_nibbles(codes)
-                parts.append(KV.dequant_tokens(vals, sc, zp, jnp.float32)[0])
-            pair.append(jnp.concatenate(parts, axis=0))    # (n_tok, g, hd)
-        return pair
-
-    def attend(q_rows, qpos, kd, vd, length):              # q_rows (r, g, hd)
-        kv_pos = jnp.arange(kd.shape[0])
-        scale = 1.0 / np.sqrt(hd)
-        qg = q_rows.reshape(-1, g, rep, hd).astype(jnp.float32) * scale
-        sc = jnp.einsum("rgpd,sgd->rgps", qg, kd.astype(jnp.float32))
-        mask = (kv_pos[None, :] <= qpos[:, None]) & \
-            (kv_pos[None, :] < length)
-        sc = jnp.where(mask[:, None, None], sc, -1e30)
-        m = jnp.max(sc, axis=-1, keepdims=True)
-        p = jnp.exp(sc - m)
-        o = jnp.einsum("rgps,sgd->rgpd", p, vd.astype(jnp.float32))
-        l = jnp.sum(p, axis=-1, keepdims=True)
-        return (o / jnp.maximum(l, 1e-30)).reshape(-1, h, hd)
-
-    outs_pf = []
-    for i in range(n_pf):
-        kd, vd = span_kv(hi_table[i], lo_table[i])
-        qpos = q_starts[i] + jnp.arange(c_len)
-        outs_pf.append(attend(q_pf[i], qpos, kd, vd, lengths[i]))
-    outs_dec = []
-    for j in range(s_slots):
-        i = n_pf + j
-        kd, vd = span_kv(hi_table[i], lo_table[i])
-        qpos = jnp.asarray([lengths[i] - 1])
-        outs_dec.append(attend(q_dec[j], qpos, kd, vd, lengths[i]))
-    out_pf = jnp.stack(outs_pf) if outs_pf else \
-        jnp.zeros((0, c_len, h, hd), jnp.float32)
-    return out_pf, jnp.stack(outs_dec)                     # (S, 1, h, hd)
+    k = np.concatenate([hi_tokens("k"), lo_tokens("k", 0)], axis=1)
+    v = np.concatenate([hi_tokens("v"), lo_tokens("v", 1)], axis=1)
+    # the lo region starts at position num_hi, whatever the hi table spans
+    pos = np.concatenate([np.arange(hi_table.shape[1] * block_size),
+                          num_hi + np.arange(lo_table.shape[1] * block_size)])
+    rep = h // kv_heads
+    qf = np.asarray(q, np.float32).reshape(s_slots, kv_heads, rep, hd)
+    sc = np.einsum("sgrd,stgd->sgrt", qf, k) / np.sqrt(hd)
+    mask = pos[None, :] < np.asarray(lengths)[:, None]          # (S, T)
+    sc = np.where(mask[:, None, None], sc, -np.inf)
+    p = np.exp(sc - sc.max(axis=-1, keepdims=True))
+    out = np.einsum("sgrt,stgd->sgrd", p / p.sum(axis=-1, keepdims=True), v)
+    return jnp.asarray(out.reshape(s_slots, 1, h, hd))
 
 
 def stamp_quant_matmul_ref(x, qw, sw, zw, bias=None, *, transform="dwt",

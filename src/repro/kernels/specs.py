@@ -43,6 +43,7 @@ class BufferSpec:
     dtype: Any
     block_shape: Optional[tuple]        # None => no BlockSpec (whole array)
     index_map: Optional[Callable]
+    hbm: bool = False                   # left in HBM (memory_space ANY)
 
 
 @dataclasses.dataclass
@@ -85,6 +86,15 @@ def _shape_dtype(x):
         else x.dtype
 
 
+def _is_memory(dtype) -> bool:
+    """A scratch allocation that takes memory (semaphores take none)."""
+    try:
+        np.dtype(dtype)
+    except TypeError:
+        return False
+    return True
+
+
 @contextlib.contextmanager
 def _capture_pallas(records: list, name: str):
     """Swap ``jax.experimental.pallas.pallas_call`` for a recorder.  Kernel
@@ -117,11 +127,13 @@ def _capture_pallas(records: list, name: str):
             data = operands[nsp:]
             inputs = []
             for spec, op in zip(ins, data):
+                blocked = spec is not None and spec.block_shape is not None
                 inputs.append(BufferSpec(
                     shape=tuple(op.shape), dtype=jnp.asarray(op).dtype
                     if not hasattr(op, "dtype") else op.dtype,
-                    block_shape=tuple(spec.block_shape) if spec else None,
-                    index_map=spec.index_map if spec else None))
+                    block_shape=tuple(spec.block_shape) if blocked else None,
+                    index_map=spec.index_map if blocked else None,
+                    hbm=spec is not None and spec.memory_space is pl_mod.ANY))
             outputs = []
             for spec, sd in zip(outs, out_leaves):
                 outputs.append(BufferSpec(
@@ -130,7 +142,8 @@ def _capture_pallas(records: list, name: str):
                     index_map=spec.index_map if spec else None))
             records.append(KernelCapture(
                 name=name, grid=g, inputs=inputs, outputs=outputs,
-                scratch=[(tuple(s.shape), s.dtype) for s in scratch],
+                scratch=[(tuple(s.shape), s.dtype) for s in scratch
+                         if _is_memory(s.dtype)],
                 num_scalar_prefetch=nsp, prefetch=prefetch,
                 interpret=bool(interpret)))
             return jax.tree_util.tree_map(
@@ -304,19 +317,23 @@ def _paged_pools(r, g, hd, bs, n_hi_pages, n_lo_pages):
                                 ).astype(np.float32),
         "v_hi_zp": r.integers(0, 8, size=(n_hi_pages, bs, g)
                               ).astype(np.float32),
-        "k_lo": r.integers(0, 256, size=(n_lo_pages, bs, g, hd // 2),
-                           dtype=np.uint8),
-        "v_lo": r.integers(0, 256, size=(n_lo_pages, bs, g, hd // 2),
-                           dtype=np.uint8),
-        "k_lo_scale": r.uniform(1e-3, 1e-2, size=(n_lo_pages, bs, g)
-                                ).astype(np.float32),
-        "k_lo_zp": r.integers(0, 8, size=(n_lo_pages, bs, g)
-                              ).astype(np.float32),
-        "v_lo_scale": r.uniform(1e-3, 1e-2, size=(n_lo_pages, bs, g)
-                                ).astype(np.float32),
-        "v_lo_zp": r.integers(0, 8, size=(n_lo_pages, bs, g)
-                              ).astype(np.float32),
+        "k_lo": r.integers(-128, 128, size=(n_lo_pages, bs, g * hd // 2),
+                           dtype=np.int8),
+        "v_lo": r.integers(-128, 128, size=(n_lo_pages, bs, g * hd // 2),
+                           dtype=np.int8),
+        "lo_scale_zp": _lo_params(r, g, bs, n_lo_pages),
     }
+
+
+def _lo_params(r, g, bs, n_pages):
+    """Random ``lo_scale_zp`` pages: f16 scale rows, then zero-point rows,
+    stored as their int16 bits."""
+    from repro.serving.paged_kvcache import lo_param_shape
+    rows, lanes = lo_param_shape(bs, g)
+    out = np.empty((n_pages, rows, lanes), np.float16)
+    out[:, 0::2] = r.uniform(1e-3, 1e-2, size=(n_pages, rows // 2, lanes))
+    out[:, 1::2] = r.integers(0, 8, size=(n_pages, rows // 2, lanes))
+    return out.view(np.int16)
 
 
 def _ex_paged_decode():
@@ -340,14 +357,18 @@ def _ex_paged_ragged():
     n_pf, s_slots = 2, 3
     entry = _paged_pools(r, g, hd, bs, n_hi_pages=4, n_lo_pages=6)
     q_pf = r.standard_normal((n_pf, c_len, h, hd)).astype(np.float32)
+    k_pf = r.standard_normal((n_pf, c_len, g, hd)).astype(np.float32)
+    v_pf = r.standard_normal((n_pf, c_len, g, hd)).astype(np.float32)
     q_dec = r.standard_normal((s_slots, 1, h, hd)).astype(np.float32)
-    q_starts = np.array([0, 16, 19, 39, 8], np.int32)
-    lengths = np.array([8, 24, 20, 40, 9], np.int32)
+    # positions read through pages: chunk rows their cached prefix (0: a
+    # first chunk), decode slots their length
+    lengths = np.array([0, 16, 20, 40, 9], np.int32)
     hi_table = np.array([[1], [3], [1], [2], [0]], np.int32)
     lo_table = np.array([[0, 0, 0], [1, 2, 0],
                          [1, 2, 0], [3, 4, 5], [0, 0, 0]], np.int32)
     return paged_ragged_attention, \
-        (entry, q_pf, q_dec, q_starts, lengths, hi_table, lo_table, bs), {}
+        (entry, q_pf, q_dec, k_pf, v_pf, lengths, hi_table, lo_table,
+         bs), {}
 
 
 KERNEL_EXAMPLES: dict = {

@@ -162,7 +162,8 @@ def main():
                          "the TPU)")
     ap.add_argument("--fused-cache-attention", action="store_true",
                     help="decode attention through the Pallas packed-cache "
-                         "kernel (paged or contiguous layout)")
+                         "kernel off the TPU too (interpret mode); the paged "
+                         "engine takes it on a TPU anyway")
     ap.add_argument("--block-size", type=int, default=16,
                     help="tokens per cache page (paged engine)")
     ap.add_argument("--prefill-chunk", type=int, default=128,

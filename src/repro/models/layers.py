@@ -211,24 +211,29 @@ def decode_attention_segments(
     q: Array,                      # (b, 1, h, hd)
     segments: list,                # [(k, v, position_offset), ...]
     length: Optional[Array] = None,
+    parts: tuple = (),             # [(m, l, o)] computed elsewhere
 ) -> Array:
     """Decode attention over disjoint cache segments with a score-level
     merge: the mixed-precision cache's hi (64-token int8) and lo (int4)
     regions are attended separately and their scores concatenated — K/V are
     never concatenated along the GSPMD-sharded sequence axis (that concat
     reshards the whole cache by a 64-token offset every layer; §Perf).
-    Matmuls keep bf16 operands with f32 accumulation (MXU-native)."""
+    Matmuls keep bf16 operands with f32 accumulation (MXU-native).
+    ``parts`` adds softmax statistics of further positions computed
+    elsewhere (the paged kernel's int4 pages): ``m, l`` (b, g, rep), ``o``
+    (b, g, rep, hd), unnormalised."""
     b, _, h, hd = q.shape
-    g = segments[0][0].shape[2]
+    g = segments[0][0].shape[2] if segments else parts[0][0].shape[1]
     rep = h // g
     scale = 1.0 / np.sqrt(hd)
-    qg = (q.reshape(b, g, rep, hd) * scale).astype(segments[0][0].dtype)
+    dtype = segments[0][0].dtype if segments else q.dtype
+    qg = (q.reshape(b, g, rep, hd) * scale).astype(dtype)
 
     # per-segment online-softmax statistics, merged at the end — NO
     # cross-segment concatenation (concatenating a replicated 64-token hi
     # segment with a 16-way-sharded lo segment makes GSPMD replicate the
     # whole thing, dragging the packed cache through an all-gather).
-    parts = []
+    parts = list(parts)
     for k_seg, v_seg, offset in segments:
         s_seg = k_seg.shape[1]
         sc = jnp.einsum("bgrd,bsgd->bgrs", qg, k_seg,
@@ -243,6 +248,15 @@ def decode_attention_segments(
         o = jnp.einsum("bgrs,bsgd->bgrd", p.astype(k_seg.dtype), v_seg,
                        preferred_element_type=jnp.float32)
         parts.append((m, l, o))
+    out = merge_softmax_parts(parts)
+    return out.reshape(b, 1, h, hd).astype(q.dtype)
+
+
+def merge_softmax_parts(parts: list) -> Array:
+    """Normalised attention output from the unnormalised softmax statistics
+    ``(m, l, o)`` of disjoint key sets (``o`` carries one more trailing
+    axis than ``m`` and ``l``).  A part with ``m = −1e30`` (every key
+    masked) weighs ``exp(−1e30 − m_tot) = 0``."""
     m_tot = parts[0][0]
     for m, _, _ in parts[1:]:
         m_tot = jnp.maximum(m_tot, m)
@@ -252,8 +266,7 @@ def decode_attention_segments(
         corr = jnp.exp(m - m_tot)
         l_tot = l_tot + l * corr
         o_tot = o_tot + o * corr[..., None]
-    out = o_tot / jnp.maximum(l_tot, 1e-30)[..., None]
-    return out.reshape(b, 1, h, hd).astype(q.dtype)
+    return o_tot / jnp.maximum(l_tot, 1e-30)[..., None]
 
 
 def chunked_prefill_attention(
@@ -262,6 +275,7 @@ def chunked_prefill_attention(
     k_self: Array,                 # (b, c, kv, hd) — this chunk's raw K
     v_self: Array,
     start: Array,                  # scalar or (b,) int32: tokens cached
+    parts: tuple = (),             # [(m, l, o)] computed elsewhere
 ) -> Array:
     """Attention for one continuous-batching prefill chunk: queries at
     global positions ``start + i`` attend to the **cached prefix** (the
@@ -274,7 +288,10 @@ def chunked_prefill_attention(
 
     ``start`` may be a scalar (one chunk, the two-call engine) or a ``(b,)``
     vector (the unified ragged step batches several requests' chunks as
-    rows, each with its own cached-prefix length)."""
+    rows, each with its own cached-prefix length).  ``parts`` adds softmax
+    statistics of further cached positions computed elsewhere (the paged
+    kernel's int4 pages): ``m, l`` (b, g, rep, c), ``o`` (b, g, rep, c,
+    hd), unnormalised."""
     b, c, h, hd = q.shape
     g = k_self.shape[2]
     rep = h // g
@@ -283,7 +300,7 @@ def chunked_prefill_attention(
     start = jnp.broadcast_to(jnp.asarray(start, jnp.int32).reshape(-1), (b,))
     qpos = start[:, None] + jnp.arange(c)[None, :]           # (b, c)
 
-    parts = []
+    parts = list(parts)
 
     def score_part(k_seg, v_seg, mask):          # mask: (b, c, s_seg) bool
         sc = jnp.einsum("bcgrd,bsgd->bgrcs", qg,
@@ -305,16 +322,7 @@ def chunked_prefill_attention(
     score_part(k_self, v_self,
                kpos_self[:, None, :] <= qpos[:, :, None])
 
-    m_tot = parts[0][0]
-    for m, _, _ in parts[1:]:
-        m_tot = jnp.maximum(m_tot, m)
-    l_tot = jnp.zeros_like(m_tot)
-    o_tot = jnp.zeros_like(parts[0][2])
-    for m, l, o in parts:
-        corr = jnp.exp(m - m_tot)
-        l_tot = l_tot + l * corr
-        o_tot = o_tot + o * corr[..., None]
-    out = o_tot / jnp.maximum(l_tot, 1e-30)[..., None]
+    out = merge_softmax_parts(parts)
     return out.transpose(0, 3, 1, 2, 4).reshape(b, c, h, hd).astype(q.dtype)
 
 

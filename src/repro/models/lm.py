@@ -442,12 +442,28 @@ def kw_fused(kv_cfg) -> bool:
     return _FUSED_CACHE_ATTENTION
 
 
+def paged_kernel(pcfg, cfg: ModelConfig, forced: bool) -> bool:
+    """Whether paged attention over this pool takes the Pallas kernel
+    (kernels/paged_attention.py), decided at trace time: on a TPU for
+    every quantized pool it compiles for; elsewhere only when ``forced``
+    (``ServeConfig.fused_cache_attention``: interpret mode, for tests)."""
+    from repro.kernels.ops import default_interpret
+    from repro.kernels.paged_attention import compiles_for
+    if not pcfg.quant.quantized:
+        return False
+    if forced:
+        return True
+    return not default_interpret() and compiles_for(
+        pcfg.block_size, cfg.num_kv_heads, cfg.resolved_head_dim)
+
+
 def set_fused_cache_attention(enabled: bool) -> None:
     """Route decode attention through the Pallas packed-cache kernel
-    (kernels/cache_attention.py for the contiguous layout,
-    kernels/paged_attention.py for the paged one).  Module-level switch so
-    the functional layer code stays signature-stable; the serving engine
-    sets it from ``ServeConfig.fused_cache_attention``."""
+    (kernels/cache_attention.py for the contiguous layout; the paged one
+    takes kernels/paged_attention.py on a TPU anyway, see `paged_kernel`).
+    Module-level switch so the functional layer code stays
+    signature-stable; the serving engine sets it from
+    ``ServeConfig.fused_cache_attention``."""
     global _FUSED_CACHE_ATTENTION
     _FUSED_CACHE_ATTENTION = enabled
 
@@ -565,7 +581,7 @@ def attn_block(
                                          paged["offsets"], paged["is_hi"],
                                          pcfg)
         length = paged["lengths"]
-        if pcfg.quant.quantized and kw_fused(kv_cfg):
+        if paged_kernel(pcfg, cfg, _FUSED_CACHE_ATTENTION):
             from repro.kernels.paged_attention import paged_decode_attention
             with jax.named_scope("attn.kernel"):
                 attn = paged_decode_attention(new_entry, q, length,
@@ -673,9 +689,9 @@ def attn_block_unified(
     contribution, so no separate flash variant (and no evaluate-both-and-
     ``jnp.where`` select) is needed — first/continuation chunks share one
     compiled program and each row's math is bit-identical to the two-call
-    engine's chunk call (the parity contract).  With the Pallas path
-    enabled both regions go through ONE `paged_ragged_attention` grid
-    instead.
+    engine's chunk call (the parity contract).  On a TPU (`paged_kernel`)
+    `paged_ragged_attention` computes the same result, its Pallas kernel
+    reading only each span's own int4 pages.
     """
     x_pf, x_dec = x
     hd, nh, kvh = cfg.resolved_head_dim, cfg.num_heads, cfg.num_kv_heads
@@ -708,13 +724,12 @@ def attn_block_unified(
                                      paged["pages"], paged["offsets"],
                                      paged["is_hi"], pcfg)
 
-    if pcfg.quant.quantized and kw_fused(kv_cfg):
+    if paged_kernel(pcfg, cfg, _FUSED_CACHE_ATTENTION):
         from repro.kernels.paged_attention import paged_ragged_attention
         with jax.named_scope("attn.kernel"):
             attn_pf, attn_dec = paged_ragged_attention(
-                new_entry, q_pf, q_dec, paged["span_starts"],
-                paged["span_lengths"], paged["span_ht"], paged["span_lt"],
-                pcfg.block_size)
+                new_entry, q_pf, q_dec, k_pf, v_pf, paged["span_cached"],
+                paged["span_ht"], paged["span_lt"], pcfg.block_size)
     else:
         with jax.named_scope("attn.fallback"):
             segs_dec = PKV.gather_segments(new_entry, paged["dec_ht"],
@@ -1595,9 +1610,9 @@ def paged_unified_step(params, pools: dict, pf_tokens: Array,
     pos_pf = pf_start[:, None] + jnp.arange(c_len)[None, :]
     paged = {"cfg": serve.paged,
              "span_ht": hi_table, "span_lt": lo_table,
-             "span_starts": jnp.concatenate([pf_start, dec_positions]),
-             "span_lengths": jnp.concatenate([pf_length,
-                                              dec_positions + 1]),
+             # positions each span reads through its pages: a chunk row
+             # its cached prefix, a decode slot its own token too
+             "span_cached": jnp.concatenate([pf_start, dec_positions + 1]),
              "pf_ht": hi_table[:n_pf], "pf_lt": lo_table[:n_pf],
              "dec_ht": hi_table[n_pf:], "dec_lt": lo_table[n_pf:],
              "pf_positions": pos_pf, "pf_start": pf_start,

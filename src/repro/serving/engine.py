@@ -155,7 +155,8 @@ class _EngineBase:
                  "deadline_misses", "nan_quarantines", "demotions",
                  "watchdog_trips", "stalled_steps", "swap_corruptions",
                  "prefix_cache_queries", "prefix_cache_hits",
-                 "prefix_tokens_reused", "cow_copies")
+                 "prefix_tokens_reused", "cow_copies",
+                 "attn_pages_walked", "attn_pages_reserved")
 
     def __init__(self, params, cfg: ModelConfig, serve: lm.ServeConfig,
                  clock: Optional[Callable[[], float]] = None,
@@ -696,6 +697,8 @@ class PagedServingEngine(_EngineBase):
         # element (recomputed here so demotion keeps arity consistent
         # with the rebuilt serve config)
         self._collect = lm._collect_telemetry(serve_p)
+        self._kernel_attn = lm.paged_kernel(self.pcfg, cfgm,
+                                            serve_p.fused_cache_attention)
         if unified:
             self._unified = jax.jit(
                 lambda p, pools, pt, ps, pln, pf, pli, psl, dt, dp, da, ht,
@@ -1030,6 +1033,24 @@ class PagedServingEngine(_EngineBase):
             ht = ht[:, :0]
         return ht, lt
 
+    def _count_attention_pages(self, cached: np.ndarray) -> None:
+        """Pages one attention layer reads this step, and the pages its
+        span tables reserve, from the positions each span reads through
+        its pages (``cached``, one per span).  Every span reads its sink
+        pages; the paged kernel reads only a span's own int4 pages, where
+        the XLA fallback gathers its whole table."""
+        if not self._has_attn:
+            return
+        pc = self.pcfg
+        nh, nl = pc.hi_blocks_per_seq, pc.max_blocks_per_seq
+        reserved = len(cached) * (nh + nl)
+        walked = reserved
+        if self._kernel_attn:
+            lo = -(-np.maximum(cached - pc.num_hi, 0) // pc.block_size)
+            walked = len(cached) * nh + int(lo.sum())
+        self._inc("attn_pages_walked", walked)
+        self._inc("attn_pages_reserved", reserved)
+
     def _tables(self, sreqs: List[SchedRequest]) -> tuple:
         ht, lt = self._tables_np(sreqs)
         return jnp.asarray(ht), jnp.asarray(lt)
@@ -1150,6 +1171,8 @@ class PagedServingEngine(_EngineBase):
                     pf_lt[i] = lt_np[w.sreq.slot]
                 span_ht = np.concatenate([pf_ht, ht_np], axis=0)
                 span_lt = np.concatenate([pf_lt, lt_np], axis=0)
+                self._count_attention_pages(
+                    np.concatenate([pf_start, dec_pos + 1]))
             with self._timer.phase("upload"):
                 args = (self.params, self.pools) + tuple(
                     jnp.asarray(a) for a in (

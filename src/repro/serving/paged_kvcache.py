@@ -20,11 +20,30 @@ reuses `kvcache.py`'s per-token quant + nibble packing bit-for-bit):
   ``num_hi`` (=64) logical tokens of every sequence live here at 8 bits (the
   attention-sink region, §B.2); ``num_hi % bs == 0`` so a page is entirely
   hi or entirely lo.
-* **lo pool** — ``k_lo / v_lo``: ``(P, NL, bs, kv, hd/2)`` uint8, two int4
-  nibbles packed along head_dim.
-* ``*_scale / *_zp`` — ``(P, N?, bs, kv)`` float16 per-token params,
-  paged alongside their codes (a page is self-describing, so eviction /
-  swap moves one contiguous unit).
+* ``k_hi_scale / k_hi_zp / v_hi_*`` — ``(P, NH, bs, kv)`` float16
+  per-token params, paged alongside their codes (a page is
+  self-describing, so eviction / swap moves one contiguous unit).
+* **lo pool** — ``k_lo / v_lo``: ``(P, NL, bs, kv·hd/2)`` int8 holding
+  the bytes of two packed int4 nibbles along head_dim, one row of every
+  kv head per token.
+* ``lo_scale_zp`` — ``(P, NL, 2·⌈bs/4⌉, M)`` int16 holding the float16
+  bits of the lo pages' per-token params (see :func:`lo_param_lanes`): for
+  token ``4r + b`` row ``2r`` holds scales and row ``2r + 1`` zero points,
+  at lane ``kv_index·4·kv + b·kv + head``; ``M`` is ``8·kv`` rounded up to
+  128.
+
+The lo pool is laid out for the paged-attention kernel
+(`kernels/paged_attention.py`), which copies pages HBM → VMEM one at a
+time: every lo array keeps a page contiguous with a minor dim that is a
+multiple of 128 lanes (at the served widths), so the device stores it
+page-major and unpadded, and a page copy is one aligned tile run.  (With a
+narrow minor dim — ``hd/2`` or ``kv`` — the device stores such a pool
+with pages on its minor axis, where no page can be copied alone.)  Paired
+as 32-bit words, the params rows give the scale and zero point of four
+tokens' worth of one head in the word row that holds those tokens' codes.
+The kernel takes neither uint8 nor float16 arrays, hence the int8 and
+int16 storage: the bytes are the uint8 nibbles and float16 values, and
+only the values written or gathered are reinterpreted.
 
 Page 0 of each pool is the **null page**: never handed out by the
 allocator, and never *read unmasked*.  Block tables hold 0 for unmapped
@@ -114,20 +133,38 @@ def init_pools(periods: int, kv_heads: int, head_dim: int,
     return {
         "k_hi": jnp.zeros((periods, nh, bs, kv_heads, head_dim), jnp.int8),
         "v_hi": jnp.zeros((periods, nh, bs, kv_heads, head_dim), jnp.int8),
-        "k_lo": jnp.zeros((periods, nl, bs, kv_heads, head_dim // 2),
-                          jnp.uint8),
-        "v_lo": jnp.zeros((periods, nl, bs, kv_heads, head_dim // 2),
-                          jnp.uint8),
+        "k_lo": jnp.zeros((periods, nl, bs, kv_heads * head_dim // 2),
+                          jnp.int8),
+        "v_lo": jnp.zeros((periods, nl, bs, kv_heads * head_dim // 2),
+                          jnp.int8),
         # f16 for the same exactness/traffic argument as the contiguous cache
         "k_hi_scale": jnp.zeros((periods, nh, bs, kv_heads), jnp.float16),
         "k_hi_zp": jnp.zeros((periods, nh, bs, kv_heads), jnp.float16),
         "v_hi_scale": jnp.zeros((periods, nh, bs, kv_heads), jnp.float16),
         "v_hi_zp": jnp.zeros((periods, nh, bs, kv_heads), jnp.float16),
-        "k_lo_scale": jnp.zeros((periods, nl, bs, kv_heads), jnp.float16),
-        "k_lo_zp": jnp.zeros((periods, nl, bs, kv_heads), jnp.float16),
-        "v_lo_scale": jnp.zeros((periods, nl, bs, kv_heads), jnp.float16),
-        "v_lo_zp": jnp.zeros((periods, nl, bs, kv_heads), jnp.float16),
+        "lo_scale_zp": jnp.zeros((periods, nl) + lo_param_shape(bs, kv_heads),
+                                 jnp.int16),
     }
+
+
+def lo_param_shape(block_size: int, kv_heads: int) -> tuple:
+    """Trailing shape of one lo page's ``lo_scale_zp``: two rows (scales,
+    zero points) per four tokens, ``8·kv`` lanes rounded up to 128."""
+    return 2 * (-(-block_size // 4)), -(-8 * kv_heads // 128) * 128
+
+
+def lo_param_lanes(offsets: Array, kv: int, kv_heads: int) -> tuple:
+    """Where the params of tokens at in-page ``offsets`` (n,) live in a lo
+    page's ``lo_scale_zp``, for K (``kv`` 0) or V (1): the scale row (n,)
+    (the zero point is the next row) and the lanes (n, kv_heads)."""
+    lanes = (kv * 4 * kv_heads + (offsets % 4)[:, None] * kv_heads
+             + jnp.arange(kv_heads)[None, :])
+    return 2 * (offsets // 4), lanes
+
+
+def _is_lo(name: str) -> bool:
+    """Whether a pool array lives in the lo (or unquantized) page pool."""
+    return name in ("k", "v") or "lo" in name.split("_")
 
 
 def pool_bytes(entry: dict) -> int:
@@ -651,18 +688,25 @@ def _scatter_tokens(entry: dict, kc: Array, vc: Array,
         return out
     pg_hi = jnp.where(is_hi, pages, 0)
     pg_lo = jnp.where(is_hi, 0, pages)
-    for name, t in (("k", kc), ("v", vc)):
+    kv_heads = kc.shape[1]
+    params = entry["lo_scale_zp"]
+    for i, (name, t) in enumerate((("k", kc), ("v", vc))):
         q8, sc8, zp8 = _quant_token(t, 8)
         q4, sc4, zp4 = _quant_token(t, cfg.quant.lo_bits)
         out[f"{name}_hi"] = entry[f"{name}_hi"].at[pg_hi, offsets].set(q8)
-        out[f"{name}_lo"] = entry[f"{name}_lo"].at[pg_lo, offsets].set(q4)
-        for suffix, hi_val, lo_val in (("scale", sc8, sc4), ("zp", zp8, zp4)):
+        out[f"{name}_lo"] = entry[f"{name}_lo"].at[pg_lo, offsets].set(
+            jax.lax.bitcast_convert_type(q4.reshape(q4.shape[0], -1),
+                                         jnp.int8))
+        for suffix, hi_val in (("scale", sc8), ("zp", zp8)):
             out[f"{name}_hi_{suffix}"] = \
                 entry[f"{name}_hi_{suffix}"].at[pg_hi, offsets].set(
                     hi_val.astype(jnp.float16))
-            out[f"{name}_lo_{suffix}"] = \
-                entry[f"{name}_lo_{suffix}"].at[pg_lo, offsets].set(
-                    lo_val.astype(jnp.float16))
+        row, lanes = lo_param_lanes(offsets, i, kv_heads)
+        for r, val in ((row, sc4), (row + 1, zp4)):
+            params = params.at[pg_lo[:, None], r[:, None], lanes].set(
+                jax.lax.bitcast_convert_type(val.astype(jnp.float16),
+                                             jnp.int16))
+    out["lo_scale_zp"] = params
     return out
 
 
@@ -698,6 +742,43 @@ def write_ragged(entry: dict, k: Array, v: Array,
     return _scatter_tokens(entry, k, v, pages, offsets, is_hi, cfg)
 
 
+def gather_region(entry: dict, region: str, table: Array, block_size: int,
+                  dtype=jnp.bfloat16) -> tuple:
+    """Dequantised ``(k, v)`` of one quantized region (``"hi"``: int8 codes,
+    ``"lo"``: int4 nibbles) through its block table ``(S, n)``: each
+    (S, n*bs, kv, hd)."""
+    s, n = table.shape
+    tokens = n * block_size
+
+    def dense(arr):
+        g = arr[table]                                # (S, n, bs, ...)
+        return g.reshape(s, tokens, *g.shape[3:])
+
+    out = []
+    if region == "hi":
+        for name in ("k", "v"):
+            out.append(KV.dequant_tokens(
+                dense(entry[f"{name}_hi"]).astype(jnp.float32),
+                dense(entry[f"{name}_hi_scale"]),
+                dense(entry[f"{name}_hi_zp"]), dtype))
+        return tuple(out)
+    kv_heads = entry["k_hi_scale"].shape[-1]
+    # (S, n, ⌈bs/4⌉, scale|zp, k|v, 4, kv) → per token, in page order
+    params = jax.lax.bitcast_convert_type(
+        entry["lo_scale_zp"][table][..., :8 * kv_heads], jnp.float16
+    ).reshape(s, n, -1, 2, 2, 4, kv_heads).transpose(0, 1, 4, 3, 2, 5, 6)
+    params = params.reshape(s, n, 2, 2, -1, kv_heads)[..., :block_size, :]
+    for i, name in enumerate(("k", "v")):
+        codes = jax.lax.bitcast_convert_type(
+            dense(entry[f"{name}_lo"]), jnp.uint8).reshape(s, tokens,
+                                                           kv_heads, -1)
+        sc, zp = (params[:, :, i, j].reshape(s, tokens, kv_heads)
+                  for j in (0, 1))
+        out.append(KV.dequant_tokens(KV.unpack_nibbles(codes), sc, zp,
+                                     dtype))
+    return tuple(out)
+
+
 def gather_segments(entry: dict, hi_table: Array, lo_table: Array,
                     cfg: PagedCacheConfig, dtype=jnp.bfloat16):
     """Block tables -> dense dequantized segments for the XLA attention path.
@@ -709,35 +790,18 @@ def gather_segments(entry: dict, hi_table: Array, lo_table: Array,
     `decode_attention_segments` consumes for the contiguous cache, so the
     two layouts share one attention implementation (and its exact numerics).
     """
-    s = hi_table.shape[0] if cfg.quant.quantized else lo_table.shape[0]
     bs = cfg.block_size
-
-    def dense(codes, lo: bool):
-        g = codes[lo_table if lo else hi_table]       # (S, n, bs, kv, ...)
-        n = g.shape[1]
-        return g.reshape(s, n * bs, *g.shape[3:])
-
     if not cfg.quant.quantized:
-        k = dense(entry["k"], True).astype(dtype)
-        v = dense(entry["v"], True).astype(dtype)
+        s = lo_table.shape[0]
+        k, v = (entry[name][lo_table].reshape(
+            s, lo_table.shape[1] * bs, *entry[name].shape[2:]).astype(dtype)
+            for name in ("k", "v"))
         return [(k, v, 0)]
-
     segs = []
-    regions = (("hi", False, 0), ("lo", True, cfg.num_hi))
-    if hi_table.shape[1] == 0:           # no sink region configured
-        regions = regions[1:]
-    for region, lo, offset in regions:
-        kv_pair = []
-        for name in ("k", "v"):
-            codes = dense(entry[f"{name}_{region}"], lo)
-            sc = dense(entry[f"{name}_{region}_scale"], lo)
-            zp = dense(entry[f"{name}_{region}_zp"], lo)
-            if region == "hi":
-                vals = codes.astype(jnp.float32)
-            else:
-                vals = KV.unpack_nibbles(codes)
-            kv_pair.append(KV.dequant_tokens(vals, sc, zp, dtype))
-        segs.append((kv_pair[0], kv_pair[1], offset))
+    if hi_table.shape[1]:                # a sink region is configured
+        segs.append((*gather_region(entry, "hi", hi_table, bs, dtype), 0))
+    segs.append((*gather_region(entry, "lo", lo_table, bs, dtype),
+                 cfg.num_hi))
     return segs
 
 
@@ -812,7 +876,7 @@ def extract_pages(pools: dict, hi_ids: list[int], lo_ids: list[int],
         periods = _has_periods_axis(entry)
         layer = {}
         for name, arr in entry.items():
-            ids = lo if (name in ("k", "v") or "_lo" in name) else hi
+            ids = lo if _is_lo(name) else hi
             layer[name] = np.asarray(arr[:, ids] if periods else arr[ids])
         swapped[layer_key] = layer
     swapped[CRC_KEY] = {
@@ -850,7 +914,7 @@ def insert_pages(pools: dict, swapped: dict, hi_ids: list[int],
         periods = _has_periods_axis(entry)
         layer = dict(entry)
         for name, arr in entry.items():
-            ids = lo if (name in ("k", "v") or "_lo" in name) else hi
+            ids = lo if _is_lo(name) else hi
             if ids.size:
                 saved = jnp.asarray(swapped[layer_key][name])
                 layer[name] = arr.at[:, ids].set(saved) if periods \
@@ -877,8 +941,7 @@ def copy_page(pools: dict, pool: str, src: int, dst: int) -> dict:
         periods = _has_periods_axis(entry)
         layer = dict(entry)
         for name, arr in entry.items():
-            in_lo = name in ("k", "v") or "_lo" in name
-            if in_lo != (pool == "lo"):
+            if _is_lo(name) != (pool == "lo"):
                 continue
             layer[name] = arr.at[:, dst].set(arr[:, src]) if periods \
                 else arr.at[dst].set(arr[src])

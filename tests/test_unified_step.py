@@ -15,9 +15,9 @@ jax.config.update("jax_platform_name", "cpu")
 
 from repro.core.stamp import (StampConfig, fold_segments, stamp_fake_quant,
                               stamp_linear, unfold_segments)
-from repro.kernels import ref
 from repro.kernels.paged_attention import paged_ragged_attention
 from repro.models import lm
+from repro.models import layers as L
 from repro.models.config import ModelConfig
 from repro.serving import kvcache as KV
 from repro.serving import paged_kvcache as PKV
@@ -169,9 +169,10 @@ class TestRaggedKernel:
         rng = np.random.default_rng(3)
         g, hd, h = 2, 16, 4
         entry = {k: a[0] for k, a in PKV.init_pools(1, g, hd, cfg).items()}
-        # span 0: continuation chunk with ODD valid length (start 16,
-        # materialized 27); span 1: first chunk, num_hi(16) ≥ its early
-        # positions; spans 2-3: decode slots, span 3 with num_hi >= seq
+        # span 0: continuation chunk (16 tokens cached, the chunk's 11
+        # valid tokens just written — ODD, ending mid-page); span 1: first
+        # chunk, num_hi(16) ≥ its early positions; spans 2-3: decode
+        # slots, span 3 with num_hi >= seq
         reqs = {0: ([1, 2], [1, 2], 27), 1: ([3, 4], [3], 21),
                 2: ([5, 6], [4, 5], 30), 3: ([7, 0], [0, 0], 9)}
         for uid, (hp, lp, ln) in reqs.items():
@@ -191,28 +192,51 @@ class TestRaggedKernel:
                                     jnp.asarray(ishi, bool), cfg)
         q_pf = jnp.asarray(rng.normal(size=(2, c_len, h, hd)
                                       ).astype(np.float32))
+        # the chunks' own raw K/V (rows past a chunk's valid length pad)
+        k_pf = jnp.asarray(rng.normal(size=(2, c_len, g, hd)
+                                      ).astype(np.float32))
+        v_pf = jnp.asarray(rng.normal(size=(2, c_len, g, hd)
+                                      ).astype(np.float32))
         q_dec = jnp.asarray(rng.normal(size=(2, 1, h, hd)
                                        ).astype(np.float32))
-        starts = jnp.asarray([16, 0, 29, 8], jnp.int32)
-        lengths = jnp.asarray([27, 21, 30, 9], jnp.int32)
+        # positions read through pages: the chunks' cached prefixes, the
+        # decode slots' lengths
+        cached = jnp.asarray([16, 0, 30, 9], jnp.int32)
         ht = jnp.asarray([reqs[i][0] for i in range(4)], jnp.int32)
         lt = jnp.asarray([reqs[i][1] + [0] * (4 - len(reqs[i][1]))
                           for i in range(4)], jnp.int32)
-        return cfg, entry, q_pf, q_dec, starts, lengths, ht, lt
+        return cfg, entry, q_pf, k_pf, v_pf, q_dec, cached, ht, lt
+
+    @staticmethod
+    def _fallback(cfg, entry, q_pf, k_pf, v_pf, q_dec, cached, ht, lt):
+        """The XLA fallback of `lm.attn_block_unified`: chunk rows attend
+        to their gathered cached prefix and causally to their raw chunk,
+        decode slots to their gathered pages."""
+        n_pf = q_pf.shape[0]
+        segs = PKV.gather_segments(entry, ht[n_pf:], lt[n_pf:], cfg,
+                                   jnp.float32)
+        out_dec = L.decode_attention_segments(q_dec, segs,
+                                              length=cached[n_pf:])
+        if n_pf == 0:
+            return q_pf, out_dec
+        segs = PKV.gather_segments(entry, ht[:n_pf], lt[:n_pf], cfg,
+                                   jnp.float32)
+        return L.chunked_prefill_attention(q_pf, segs, k_pf, v_pf,
+                                           cached[:n_pf]), out_dec
 
     def test_matches_oracle_mixed_spans(self):
-        """Prefill spans (odd valid length, a no-prefix first chunk) and
-        decode spans (one with num_hi ≥ seq) in one grid, vs the dense
-        masked-softmax oracle.  Only valid chunk rows compared — pad rows
+        """Prefill spans (a continuation chunk ending mid-page, a
+        no-prefix first chunk) and decode spans (one with num_hi ≥ seq) in
+        one call, vs the XLA fallback: chunk rows see their cached prefix
+        and their raw chunk.  Only valid chunk rows compared — pad rows
         are defined but discarded by the caller."""
-        cfg, entry, q_pf, q_dec, starts, lengths, ht, lt = self._setup()
+        cfg, entry, q_pf, k_pf, v_pf, q_dec, cached, ht, lt = self._setup()
         out_pf, out_dec = paged_ragged_attention(
-            entry, q_pf, q_dec, starts, lengths, ht, lt, cfg.block_size,
+            entry, q_pf, q_dec, k_pf, v_pf, cached, ht, lt, cfg.block_size,
             interpret=True)
-        ref_pf, ref_dec = ref.paged_ragged_attention_ref(
-            entry, q_pf, q_dec, starts, lengths, ht, lt)
-        valid = (int(lengths[0] - starts[0]), int(lengths[1] - starts[1]))
-        for i, n in enumerate(valid):
+        ref_pf, ref_dec = self._fallback(cfg, entry, q_pf, k_pf, v_pf,
+                                         q_dec, cached, ht, lt)
+        for i, n in enumerate((11, 21)):
             np.testing.assert_allclose(
                 np.asarray(out_pf[i, :n], np.float32),
                 np.asarray(ref_pf[i, :n]), atol=1e-5, rtol=1e-5)
@@ -222,14 +246,15 @@ class TestRaggedKernel:
 
     def test_all_decode_delegates_to_decode_kernel(self):
         """n_pf = 0 (the steady-state fast case) must route through the
-        existing decode kernel and agree with the oracle."""
-        cfg, entry, q_pf, q_dec, starts, lengths, ht, lt = self._setup()
+        decode kernel alone and agree with the fallback."""
+        cfg, entry, q_pf, k_pf, v_pf, q_dec, cached, ht, lt = self._setup()
         out_pf, out_dec = paged_ragged_attention(
-            entry, q_pf[:0], q_dec, starts[2:], lengths[2:], ht[2:],
+            entry, q_pf[:0], q_dec, k_pf[:0], v_pf[:0], cached[2:], ht[2:],
             lt[2:], cfg.block_size, interpret=True)
         assert out_pf.shape[0] == 0
-        _, ref_dec = ref.paged_ragged_attention_ref(
-            entry, q_pf[:0], q_dec, starts[2:], lengths[2:], ht[2:], lt[2:])
+        _, ref_dec = self._fallback(cfg, entry, q_pf[:0], k_pf[:0],
+                                    v_pf[:0], q_dec, cached[2:], ht[2:],
+                                    lt[2:])
         np.testing.assert_allclose(np.asarray(out_dec, np.float32),
                                    np.asarray(ref_dec), atol=1e-5,
                                    rtol=1e-5)
