@@ -190,58 +190,59 @@ def fused_site_rows() -> list[dict]:
     return rows
 
 
-def moe_site_bytes(s: int, d: int, f: int, e: int, k: int,
-                   cf: float) -> tuple[int, int]:
-    """Derived per-group HBM traffic of the MoE expert site, f32 activation
-    accounting (same convention as `stamp_site_bytes`); capacity
-    C = ceil(s·k/E·cf).
+def moe_site_bytes(s: int, d: int, f: int, e: int,
+                   k: int) -> tuple[int, int]:
+    """Derived HBM traffic of the dropless MoE expert site over ``s``
+    tokens, every expert held, f32 activation accounting (same convention
+    as `stamp_site_bytes`).
 
-    Reference: dispatch einsum (x read, f32 (E,C,d) buffer written), gate
-    and up each re-read the buffer and re-materialize a bf16 expert weight
-    from the int8 codes (dequant write + matmul read), the (E,C,f)
-    gate/up/silu·mul intermediates all round-trip, down re-materializes its
-    weight, expert outputs written + re-read by the combine.
+    Reference (`moe_ffn_dropless`, the dense loop): every expert runs on
+    every token — gate and up each re-materialize a bf16 expert weight
+    from the int8 codes (dequant write + matmul read), the (s,E,f)
+    gate/up/silu·mul intermediates all round-trip, down re-materializes
+    its weight, the (s,E,d) expert outputs are written and re-read by the
+    gate-weighted sum.
 
-    Fused: read the activation once (token quantize), move int8 codes
-    through the dispatch buffer (write + kernel read), stream the int8
-    expert codes, write the (E,C,d) expert outputs once, combine.  The
-    (E,C,f) intermediates never leave VMEM.
+    Fused (`moe_ffn_dropless_fused`): read the activation once (token
+    quantize), move the ``s·k`` chosen rows' int8 codes into their
+    expert groups (write + kernel read), stream the int8 expert codes,
+    write the rows' outputs once, combine.  The (rows, f) intermediates
+    never leave VMEM.
     """
-    cap = max(int(np.ceil(s * k / e * cf)), 1)
+    rows = s * k
     act = s * d * 4
-    buck_i8 = e * cap * d                # int8 dispatch codes
-    buck = e * cap * d * 4               # f32 (E, C, d) buffer
-    hid = e * cap * f * 4                # f32 (E, C, f) intermediate
+    hid = s * e * f * 4                  # f32 (s, E, f) intermediate
+    outs = s * e * d * 4                 # f32 (s, E, d) expert outputs
     w_gu = e * d * f                     # int8 codes, gate or up
     w_dn = e * f * d
     remat = lambda codes: codes + 2 * codes * 2   # read + bf16 write/read
-    ref = (act + buck                    # dispatch: x read, xin written
-           + 2 * buck                    # gate + up each read xin
+    ref = (act                           # x read
            + 2 * remat(w_gu)            # gate/up weight re-materialized
            + 2 * hid                    # g, u written
            + 3 * hid                    # silu·mul: g, u read, h written
            + hid + remat(w_dn)         # down: h read, weight re-materialized
-           + buck                       # expert outputs written
-           + buck + act)                # combine: outputs read, y written
+           + outs                       # expert outputs written
+           + outs + act)                # weighted sum: read, y written
     fused = (act                         # activation read once
-             + 2 * buck_i8              # int8 dispatch written + kernel read
+             + 2 * rows * d             # int8 row codes written + read
              + 2 * w_gu + w_dn          # int8 expert codes streamed
-             + buck                     # expert outputs written
-             + buck + act)              # combine: outputs read, y written
+             + rows * d * 4             # row outputs written
+             + rows * d * 4 + act)      # combine: outputs read, y written
     return ref, fused
 
 
 @functools.lru_cache(maxsize=1)
 def moe_site_rows() -> list[dict]:
-    """Fused grouped-kernel vs reference einsum MoE expert site (one
-    routing group).  Both rows deploy the same prepared int8 expert codes;
-    the reference path dequantizes them per call."""
+    """Fused grouped-kernel vs dense-loop MoE expert site (dropless, every
+    expert held).  Both rows deploy the same prepared int8 expert codes;
+    the dense loop dequantizes them per call."""
     from repro.core.stamp import prepare_linear
     from repro.models import layers as L
 
     rng = np.random.default_rng(11)
-    s, d, f, e, k, cf = 256, 128, 128, 8, 2, 1.25
-    x = jnp.asarray(rng.normal(size=(1, s, d)).astype(np.float32))
+    s, d, f, e, k = 256, 128, 128, 8, 2
+    x = jnp.asarray(rng.normal(size=(s, d)).astype(np.float32))
+    valid = jnp.ones((s,), bool)
     gate_w = jnp.asarray(rng.normal(size=(d, e)).astype(np.float32))
 
     def expert(k_dim, n_dim, seed):
@@ -255,13 +256,13 @@ def moe_site_rows() -> list[dict]:
     deq = {n: (w["iq"].astype(jnp.float32) - w["izw"]) * w["isw"]
            for n, w in prep.items()}
 
-    us_ref, _ = timed(lambda: L.moe_ffn(
-        x, gate_w, deq["g"], deq["u"], deq["d"], k, cf, group_size=s),
+    us_ref, _ = timed(lambda: L.moe_ffn_dropless(
+        x, gate_w, deq["g"], deq["u"], deq["d"], k, valid, offset=0),
         reps=2)
-    us_fused, _ = timed(lambda: L.moe_ffn_fused(
-        x, gate_w, prep["g"], prep["u"], prep["d"], k, cf, group_size=s),
+    us_fused, _ = timed(lambda: L.moe_ffn_dropless_fused(
+        x, gate_w, prep["g"], prep["u"], prep["d"], k, valid, offset=0),
         reps=2)
-    ref_b, fused_b = moe_site_bytes(s, d, f, e, k, cf)
+    ref_b, fused_b = moe_site_bytes(s, d, f, e, k)
     return [
         {"name": "kernels/site/moe.experts/reference", "us_per_call": us_ref,
          "derived": f"tpu_hbm_bytes={ref_b}"},

@@ -262,12 +262,13 @@ def run(smoke: bool = True, seed: int = 0, trace_out: str = None,
 def run_moe(seed: int = 0) -> dict:
     """Expert-scale row: the reduced Arctic config (8 experts, top-2,
     dense residual) served fused end to end — every STaMP site including
-    the MoE expert einsums runs the integer kernels (grouped dispatch), so
-    ``reference_fallback_sites`` must be 0 and the unified ragged step
+    the MoE experts runs the integer kernels (dropless, ragged dispatch),
+    so ``reference_fallback_sites`` must be 0 and the unified ragged step
     still dispatches exactly ONE device program per step (both asserted).
     Router health comes from the engine's own registry (the ``moe_router``
-    pseudo-site `moe_route` records inside the step program): per-expert
-    load, capacity occupancy, and the drop rate."""
+    pseudo-site the router records inside the step program): per-expert
+    load, the occupancy of the expert rows computed, and the drop rate
+    (0: nothing is dropped)."""
     from repro.configs import get_reduced
     from repro.core.stamp import StampConfig
     cfg = get_reduced("arctic-480b")

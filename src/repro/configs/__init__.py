@@ -19,6 +19,7 @@ ARCHS = (
     "kimi_k2_1t_a32b",
     "arctic_480b",
     "mamba2_1_3b",
+    "granite_4_0_h_small",
     # the paper's own evaluation models
     "llama3_8b",
     "pixart_sigma",
@@ -36,6 +37,7 @@ _ALIASES.update({
     "kimi-k2-1t-a32b": "kimi_k2_1t_a32b",
     "arctic-480b": "arctic_480b",
     "mamba2-1.3b": "mamba2_1_3b",
+    "granite-4.0-h-small": "granite_4_0_h_small",
     "llama3-8b": "llama3_8b",
     "pixart-sigma": "pixart_sigma",
 })

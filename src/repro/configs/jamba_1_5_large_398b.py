@@ -1,7 +1,9 @@
 """Jamba-1.5-Large 398B [arXiv:2403.19887; hf] — hybrid Mamba+attention
 1:7 interleave (1 attention layer per period of 8), MoE 16e top-2 every
-other layer.  The Mamba branch is implemented as Mamba2/SSD (state 128,
-headdim 64) — see DESIGN.md §Arch-applicability for the substitution note.
+other layer.  The Mamba layers run the repo's Mamba-2 mixer (SSD, state
+128, head dim 64) as a stand-in: Jamba publishes Mamba-1 (a selective
+scan with state 16 and a dt rank), which the repo does not implement, so
+this configuration is not yet the published model (ROADMAP §2 item 2).
 
 Serves first-class under `PagedServingEngine`: paged mixed-precision K/V
 for the attention layers + the slot-dense per-slot SSM state pool for the

@@ -43,8 +43,8 @@ def capture_block_inputs(params, batch: dict, cfg: ModelConfig,
     ``max_blocks`` scan periods (the quantization sites' common input)."""
     taps = []
 
-    x, _, _ = lm.model_hidden(params, batch, cfg, mode="train", policy=None,
-                              remat=False)
+    x, _, _ = lm.model_hidden(params, batch, cfg, mode="forward",
+                              policy=None, remat=False)
     # cheap proxy: tap the embedding output and final hidden — the
     # autocorrelation structure is driven by the data's locality and is
     # stable across depth (paper Fig. 3 shows layer 15/20 look alike).

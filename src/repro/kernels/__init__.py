@@ -51,27 +51,28 @@ Every prefill-path model linear is wired through the fused family
 Grouped MoE execution
 ---------------------
 The MoE expert einsums don't fit the per-sequence tiling above: after
-capacity routing the activation is ``(b, E, C, d)`` — expert buckets, not
-sequence spans — and the sequence transform ``L`` does not commute with
-the dispatch gather, so a per-bucket transform would change numerics.
-`stamp_matmul.stamp_quant_grouped_matmul_pallas` (wrapper
-`ops.stamp_quant_grouped_matmul`) instead splits the work at the token
-boundary — the **dispatch-once-quantize-once invariant**:
+routing, the activation is grouped by expert, not by sequence span, and
+the sequence transform ``L`` does not commute with the dispatch gather,
+so a per-group transform would change numerics.  Serving routes
+dropless (`repro.models.layers.moe_route_dropless`) and
+`stamp_matmul.stamp_quant_ragged_matmul_pallas` (wrapper
+`ops.stamp_quant_ragged_matmul`) splits the work at the token boundary —
+the **dispatch-once-quantize-once invariant**:
 
 * the stamped round trip (transform → mixed-precision fake-quant →
   inverse) runs ONCE per token in XLA, shared verbatim with the router
   input, so fused and reference paths route bit-identically by
   construction;
 * `repro.core.stamp.token_quantize` then produces one int8 code + scale
-  + zero point per token, and the *codes* are gathered into the capacity
-  buckets — the dispatch buffer moves int8, not bf16;
-* ONE kernel walks grid ``(b, E, C/block_c, f/block_f)`` with the
-  per-``(b, E)`` occupancy counts as a scalar-prefetch table: index maps
-  clamp the empty capacity tail of underfull buckets (routing keeps each
-  bucket a contiguous prefix, so the count is exact), rows past the
-  count are zeroed in-kernel, and gate + up GEMMs consume the same
-  gathered codes with the silu·mul epilogue and the grouped down-proj in
-  VMEM scratch — the ``(E, C, f)`` intermediates never reach HBM.
+  + zero point per token, and the *codes* are gathered into each held
+  expert's row group (``block_m``-aligned, so every row tile belongs to
+  one expert) — the dispatch buffer moves int8, not bf16;
+* ONE kernel walks grid ``(n_tiles, f/block_f)`` with a tile table
+  (fetch tile | expert | rows) as its scalar-prefetch argument: empty
+  tiles copy and write nothing, rows past a tile's count are zeroed
+  in-kernel, and gate + up GEMMs consume the same gathered codes with
+  the silu·mul epilogue and the grouped down-proj in VMEM scratch — the
+  ``(rows, f)`` intermediates never reach HBM.
 
 Expert weights prepare like every other site
 (`prepare_fused_weights` stacks the scanned period as
@@ -206,8 +207,8 @@ from repro.kernels.ops import (  # noqa: F401
     quantize_pack,
     stamp_decode_matmul,
     stamp_quant_dual_matmul,
-    stamp_quant_grouped_matmul,
     stamp_quant_matmul,
+    stamp_quant_ragged_matmul,
     walsh_hadamard,
 )
 from repro.kernels.cache_attention import cache_decode_attention  # noqa: F401

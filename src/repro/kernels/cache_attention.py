@@ -90,8 +90,10 @@ def _kernel(q_ref, khi_ref, klo_ref, kshi_ref, kzhi_ref, kslo_ref, kzlo_ref,
 
 def cache_decode_attention(entry: dict, q: jax.Array, length: jax.Array,
                            block_s: int = 2048,
-                           interpret: bool | None = None) -> jax.Array:
-    """Fused attention over one layer's quantized cache.
+                           interpret: bool | None = None,
+                           scale: float | None = None) -> jax.Array:
+    """Fused attention over one layer's quantized cache (``scale``: the
+    score scale, None for ``1/sqrt(hd)``).
 
     ``entry``: kvcache layer dict (no periods axis) — k_hi (b, hi, g, hd)
     int8, k_lo (b, S−hi, g, hd/2) uint8, *_scale/zp (b, S, g) f32;
@@ -111,7 +113,7 @@ def cache_decode_attention(entry: dict, q: jax.Array, length: jax.Array,
         bs //= 2
     bs = max(bs, 1)
     n_blocks = s_lo // bs
-    scale = float(1.0 / np.sqrt(hd))
+    scale = float(1.0 / np.sqrt(hd) if scale is None else scale)
     qg = q.reshape(b, h, hd).reshape(b, g, rep, hd)
 
     def split(name):

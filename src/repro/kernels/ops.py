@@ -20,8 +20,8 @@ from repro.kernels.haar_dwt import haar_dwt_pallas
 from repro.kernels.int8_matmul import int8_matmul_pallas
 from repro.kernels.quant_pack import quant_pack_pallas
 from repro.kernels.stamp_matmul import (stamp_quant_dual_matmul_pallas,
-                                        stamp_quant_grouped_matmul_pallas,
-                                        stamp_quant_matmul_pallas)
+                                        stamp_quant_matmul_pallas,
+                                        stamp_quant_ragged_matmul_pallas)
 from repro.kernels.wht import wht_pallas
 
 
@@ -150,30 +150,24 @@ def stamp_decode_matmul(x, qw, sw, zw, bias=None, *, out_dtype=None,
         out_dtype=out_dtype, interpret=interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("block_c", "block_f",
+@functools.partial(jax.jit, static_argnames=("block_m", "block_f",
                                              "out_dtype", "interpret"))
-def stamp_quant_grouped_matmul(qx, sx, zx, counts,
-                               qw_gate, sw_gate, zw_gate,
-                               qw_up, sw_up, zw_up,
-                               qw_down, sw_down, zw_down, *,
-                               block_c: int = 128, block_f: int = 512,
-                               out_dtype=jnp.float32,
-                               interpret: bool | None = None):
-    """Grouped MoE expert FFN over the quantized dispatch buffer (see
-    `stamp_matmul.py`).
-
-    qx/sx/zx: (b, E, C, d) int8 dispatch codes + per-token scale/shifted zp
-    — each token was transformed + mixed-precision quantized ONCE per
-    sequence span before dispatch; counts: (b, E) int32 occupancy
-    (scalar-prefetched); qw/sw/zw triplets: stacked (E, d, f) gate/up and
-    (E, f, d) down expert buffers from `prepare_linear`.  Returns the
-    (b, E, C, d) expert outputs for the combine einsum.
-    """
+def stamp_quant_ragged_matmul(table, qx, sx, zx,
+                              qw_gate, sw_gate, zw_gate,
+                              qw_up, sw_up, zw_up,
+                              qw_down, sw_down, zw_down, *,
+                              block_m: int = 32, block_f: int = 512,
+                              out_dtype=jnp.float32,
+                              interpret: bool | None = None):
+    """Grouped MoE expert FFN over ragged per-expert row groups (see
+    `stamp_matmul.stamp_quant_ragged_matmul_pallas`): ``table`` is the
+    (3·n_tiles,) scalar-prefetch tile table (fetch tile | expert | rows),
+    qx/sx/zx the (n_tiles·block_m, d) int8 row codes with their per-row
+    scale / shifted zp, grouped by expert at ``block_m``-aligned offsets.
+    Returns the (n_tiles·block_m, d) expert outputs."""
     if interpret is None:
         interpret = default_interpret()
-    return stamp_quant_grouped_matmul_pallas(
-        qx, sx, zx, counts,
-        qw_gate, sw_gate, zw_gate, qw_up, sw_up, zw_up,
-        qw_down, sw_down, zw_down,
-        block_c=block_c, block_f=block_f, out_dtype=out_dtype,
-        interpret=interpret)
+    return stamp_quant_ragged_matmul_pallas(
+        table, qx, sx, zx, qw_gate, sw_gate, zw_gate, qw_up, sw_up, zw_up,
+        qw_down, sw_down, zw_down, block_m=block_m, block_f=block_f,
+        out_dtype=out_dtype, interpret=interpret)

@@ -327,10 +327,12 @@ def paged_prefix_stats(entry: dict, q: jax.Array, lengths: jax.Array,
     return m[..., 0], l[..., 0], out
 
 
-def _scaled(q: jax.Array) -> jax.Array:
-    """Queries times the softmax scale, in f32, back in their own dtype."""
-    return (q.astype(jnp.float32) * (1.0 / np.sqrt(q.shape[-1]))
-            ).astype(q.dtype)
+def _scaled(q: jax.Array, scale: float | None) -> jax.Array:
+    """Queries times the softmax scale (the model's, or ``1/sqrt(hd)``
+    where it states none), in f32, back in their own dtype."""
+    if scale is None:
+        scale = 1.0 / np.sqrt(q.shape[-1])
+    return (q.astype(jnp.float32) * scale).astype(q.dtype)
 
 
 def _sink(entry: dict, hi_table: jax.Array, block_size: int, dtype) -> list:
@@ -342,10 +344,11 @@ def _sink(entry: dict, hi_table: jax.Array, block_size: int, dtype) -> list:
 
 def paged_decode_attention(entry: dict, q: jax.Array, lengths: jax.Array,
                            hi_table: jax.Array, lo_table: jax.Array,
-                           block_size: int,
+                           block_size: int, scale: float | None = None,
                            interpret: bool | None = None) -> jax.Array:
     """Decode attention over one layer's paged quantized pools: the kernel
-    over each slot's int4 pages, merged with its sink pages.
+    over each slot's int4 pages, merged with its sink pages.  ``scale``
+    is the score scale (None: ``1/sqrt(hd)``).
 
     ``entry``: pool dict (no periods axis) — k_hi (NH, bs, g, hd) int8,
     k_lo (NL, bs, g, hd/2) uint8, *_scale/zp (N?, bs, g) f16;
@@ -357,19 +360,20 @@ def paged_decode_attention(entry: dict, q: jax.Array, lengths: jax.Array,
     from repro.models.layers import decode_attention_segments
     s_slots, _, h, hd = q.shape
     g = entry["k_lo"].shape[-1] // (hd // 2)
-    qg = _scaled(q).reshape(s_slots, g, h // g, hd)
+    qg = _scaled(q, scale).reshape(s_slots, g, h // g, hd)
     stats = paged_prefix_stats(entry, qg, lengths, lo_table,
                                hi_table.shape[1] * block_size, block_size,
                                interpret=interpret)
     return decode_attention_segments(
         q, _sink(entry, hi_table, block_size, q.dtype), length=lengths,
-        parts=[stats])
+        parts=[stats], scale=scale)
 
 
 def paged_ragged_attention(entry: dict, q_pf: jax.Array, q_dec: jax.Array,
                            k_pf: jax.Array, v_pf: jax.Array,
                            lengths: jax.Array, hi_table: jax.Array,
                            lo_table: jax.Array, block_size: int,
+                           scale: float | None = None,
                            interpret: bool | None = None) -> tuple:
     """Attention of one **unified ragged step**: ``n_pf`` prefill chunk
     rows, then ``S`` decode slots, through span-ordered tables.
@@ -381,7 +385,8 @@ def paged_ragged_attention(entry: dict, q_pf: jax.Array, q_dec: jax.Array,
     pages — a chunk row its cached prefix (``start``; the pages just
     written for the chunk are not read), a decode slot its length with its
     own just-written token;
-    ``hi_table`` / ``lo_table``: (n_pf+S, ·) span-ordered block tables.
+    ``hi_table`` / ``lo_table``: (n_pf+S, ·) span-ordered block tables;
+    ``scale``: the score scale (None: ``1/sqrt(hd)``).
 
     Decode slots are `paged_decode_attention`.  Chunk rows take the
     kernel's statistics over their cached int4 pages, the sink pages below
@@ -396,7 +401,8 @@ def paged_ragged_attention(entry: dict, q_pf: jax.Array, q_dec: jax.Array,
     n_pf, c_len, h, hd = q_pf.shape
     out_dec = paged_decode_attention(entry, q_dec, lengths[n_pf:],
                                      hi_table[n_pf:], lo_table[n_pf:],
-                                     block_size, interpret=interpret)
+                                     block_size, scale=scale,
+                                     interpret=interpret)
     if n_pf == 0:
         return q_pf, out_dec
     g = entry["k_lo"].shape[-1] // (hd // 2)
@@ -404,7 +410,7 @@ def paged_ragged_attention(entry: dict, q_pf: jax.Array, q_dec: jax.Array,
     start = lengths[:n_pf]
     # rows (rep, C) per kv head: the layout chunked_prefill_attention's
     # statistics take, (n_pf, g, rep, C)
-    qg = _scaled(q_pf).reshape(n_pf, c_len, g, rep, hd).transpose(
+    qg = _scaled(q_pf, scale).reshape(n_pf, c_len, g, rep, hd).transpose(
         0, 2, 3, 1, 4).reshape(n_pf, g, rep * c_len, hd)
     m, l, acc = paged_prefix_stats(entry, qg, start, lo_table[:n_pf],
                                    hi_table.shape[1] * block_size,
@@ -413,5 +419,5 @@ def paged_ragged_attention(entry: dict, q_pf: jax.Array, q_dec: jax.Array,
              acc.reshape(n_pf, g, rep, c_len, hd))
     out_pf = chunked_prefill_attention(
         q_pf, _sink(entry, hi_table[:n_pf], block_size, q_pf.dtype),
-        k_pf, v_pf, start, parts=[stats])
+        k_pf, v_pf, start, parts=[stats], scale=scale)
     return out_pf, out_dec

@@ -186,40 +186,45 @@ def stamp_quant_dual_matmul_ref(x, qw_g, sw_g, zw_g, qw_u, sw_u, zw_u,
     return g.astype(out_dtype), u.astype(out_dtype)
 
 
-def stamp_quant_grouped_matmul_ref(qx, sx, zx, counts,
-                                   qw_gate, sw_gate, zw_gate,
-                                   qw_up, sw_up, zw_up,
-                                   qw_down, sw_down, zw_down, *,
-                                   block_f=512, out_dtype=jnp.float32):
-    """Unfused oracle for `stamp_quant_grouped_matmul`: dequantize the
-    gathered dispatch buffer and the stacked expert weights, run the
-    gate/up einsums + silu·mul, then the down-proj per ``block_f`` slab
-    with the same per-row 8-bit requantize the kernel applies in VMEM
-    (group-wise scales — one row scale per f tile).  Slots at or past each
-    expert bucket's kept-token count are zeroed, mirroring the reference
-    dispatch einsum's exact zeros."""
-    b, e, cap, d = qx.shape
+def stamp_quant_ragged_matmul_ref(table, qx, sx, zx,
+                                  qw_gate, sw_gate, zw_gate,
+                                  qw_up, sw_up, zw_up,
+                                  qw_down, sw_down, zw_down, *,
+                                  block_m=32, block_f=512,
+                                  out_dtype=jnp.float32):
+    """Unfused oracle for `stamp_quant_ragged_matmul`: dequantize the
+    grouped row codes and, for each row, the weights of its tile's expert
+    (``table``), run the gate/up einsums + silu·mul, then the down-proj
+    per ``block_f`` slab with the same per-row 8-bit requantize the kernel
+    applies in VMEM (group-wise scales — one row scale per f tile).  Rows
+    past their tile's count, and every row of an empty tile, are zero."""
+    r = qx.shape[0]
+    n = r // block_m
     f = qw_gate.shape[-1]
-    x = (qx.astype(jnp.float32) - zx) * sx                   # (b, E, C, d)
-    wg = (qw_gate.astype(jnp.float32) - zw_gate) * sw_gate   # (E, d, f)
-    wu = (qw_up.astype(jnp.float32) - zw_up) * sw_up
-    wd = (qw_down.astype(jnp.float32) - zw_down) * sw_down   # (E, f, d)
-    g = jnp.einsum("becd,edf->becf", x, wg)
-    u = jnp.einsum("becd,edf->becf", x, wu)
-    a = jax.nn.silu(g) * u
+    tile = jnp.arange(r) // block_m
+    expert = table[n:2 * n][tile]
+    live = (jnp.arange(r) % block_m) < table[2 * n:][tile]
+
+    def deq(q, sc, zp):                                      # per row
+        return ((q.astype(jnp.float32) - zp) * sc)[expert]
+
+    x = (qx.astype(jnp.float32) - zx) * sx                   # (R, d)
+    wg = deq(qw_gate, sw_gate, zw_gate)                      # (R, d, f)
+    wu = deq(qw_up, sw_up, zw_up)
+    wd = deq(qw_down, sw_down, zw_down)                      # (R, f, d)
+    a = (jax.nn.silu(jnp.einsum("rd,rdf->rf", x, wg))
+         * jnp.einsum("rd,rdf->rf", x, wu))
     bf = min(block_f, f)
     while f % bf:
         bf //= 2
-    out = jnp.zeros((b, e, cap, d), jnp.float32)
+    out = jnp.zeros((r, qx.shape[1]), jnp.float32)
     for j in range(f // bf):
-        blk = a[..., j * bf:(j + 1) * bf]
+        blk = a[:, j * bf:(j + 1) * bf]
         mn = jnp.min(blk, axis=-1, keepdims=True)
         mx = jnp.max(blk, axis=-1, keepdims=True)
         sa = jnp.maximum((mx - mn) / 255.0, 1e-8)
         za = jnp.round(-mn / sa)
         qa = jnp.clip(jnp.round(blk / sa) + za, 0.0, 255.0) - za
-        out = out + jnp.einsum("becf,efd->becd", qa * sa,
+        out = out + jnp.einsum("rf,rfd->rd", qa * sa,
                                wd[:, j * bf:(j + 1) * bf])
-    slot = jnp.arange(cap)[None, None, :, None]
-    out = jnp.where(slot < counts[:, :, None, None], out, 0.0)
-    return out.astype(out_dtype)
+    return jnp.where(live[:, None], out, 0.0).astype(out_dtype)

@@ -211,16 +211,21 @@ def _ex_stamp_segment():
 
 
 def _ex_stamp_grouped():
-    from repro.kernels.stamp_matmul import stamp_quant_grouped_matmul_pallas
+    from repro.kernels.stamp_matmul import stamp_quant_ragged_matmul_pallas
     r = _rng()
-    b, e, cap, d, f = 1, 4, 8, 32, 64
-    qx = r.integers(-128, 128, size=(b, e, cap, d), dtype=np.int8)
-    sx = r.uniform(1e-3, 1e-2, size=(b, e, cap, 1)).astype(np.float32)
-    zx = r.integers(-8, 8, size=(b, e, cap, 1)).astype(np.float32)
-    # occupancy prefetch table: full, partial and EMPTY buckets — the
-    # checker proves the clamped capacity-tile index maps in-bounds on
-    # exactly this table (KC001)
-    counts = np.array([[8, 5, 0, 8]], np.int32)
+    e, bm, d, f = 4, 8, 32, 64
+    # tile table: experts 0, 1 and 3 hold 8, 5 and 8 rows (expert 2
+    # none), then an EMPTY tail tile that re-fetches tile 2 — the checker
+    # proves the table-driven index maps in-bounds on exactly this table
+    # (KC001)
+    fetch = np.array([0, 1, 2, 2], np.int32)
+    expert = np.array([0, 1, 3, 3], np.int32)
+    rows = np.array([8, 5, 8, 0], np.int32)
+    table = np.concatenate([fetch, expert, rows])
+    n_rows = len(rows) * bm
+    qx = r.integers(-128, 128, size=(n_rows, d), dtype=np.int8)
+    sx = r.uniform(1e-3, 1e-2, size=(n_rows, 1)).astype(np.float32)
+    zx = r.integers(-8, 8, size=(n_rows, 1)).astype(np.float32)
 
     def expert_w(k, n):
         qw = r.integers(-128, 128, size=(e, k, n), dtype=np.int8)
@@ -231,9 +236,9 @@ def _ex_stamp_grouped():
     qg, sg, zg = expert_w(d, f)
     qu, su, zu = expert_w(d, f)
     qd, sd, zd = expert_w(f, d)
-    return stamp_quant_grouped_matmul_pallas, \
-        (qx, sx, zx, counts, qg, sg, zg, qu, su, zu, qd, sd, zd), \
-        dict(block_c=8, block_f=32)
+    return stamp_quant_ragged_matmul_pallas, \
+        (table, qx, sx, zx, qg, sg, zg, qu, su, zu, qd, sd, zd), \
+        dict(block_m=bm, block_f=32)
 
 
 def _ex_decode_matmul():

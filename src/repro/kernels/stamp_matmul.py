@@ -412,57 +412,55 @@ def _rowwise_quantize(a):
     return qa, sa, za - 128.0
 
 
-def _grouped_moe_kernel(counts_ref, qx_ref, sx_ref, zx_ref,
-                        qwg_ref, swg_ref, zwg_ref,
-                        qwu_ref, swu_ref, zwu_ref,
-                        qwd_ref, swd_ref, zwd_ref,
-                        o_ref, acc_ref, *,
-                        num_experts: int, block_c: int, block_f: int,
-                        nf: int, d: int):
-    """One (batch, expert, capacity-tile, f-tile) grid step of the grouped
-    MoE FFN: dual gate/up int8 GEMMs off the SHARED quantized dispatch
-    tile, silu·mul epilogue in VMEM, per-row requantize of the activation
-    slab, and the partial down-proj accumulated over the f axis into
-    scratch.  ``counts_ref`` is the scalar-prefetched per-(batch, expert)
-    occupancy table: rows at or past the expert's kept-token count are
-    zeroed on the final write (capacity-dropped / empty slots contribute
-    exactly zero, matching the reference dispatch einsum)."""
-    i, e, c, j = (pl.program_id(0), pl.program_id(1),
-                  pl.program_id(2), pl.program_id(3))
-    cnt = counts_ref[i * num_experts + e]
+def _ragged_moe_kernel(tab_ref, qx_ref, sx_ref, zx_ref,
+                       qwg_ref, swg_ref, zwg_ref,
+                       qwu_ref, swu_ref, zwu_ref,
+                       qwd_ref, swd_ref, zwd_ref,
+                       o_ref, acc_ref, *,
+                       n_tiles: int, block_m: int, block_f: int, nf: int,
+                       d: int):
+    """One (row tile, f tile) grid step of the grouped MoE FFN: dual
+    gate/up int8 GEMMs off the tile's quantized rows (all of one expert),
+    silu·mul epilogue in VMEM, per-row requantize of the activation slab,
+    and the partial down-proj accumulated over the f axis into scratch.
+    ``tab_ref`` is the scalar-prefetched tile table (see
+    `stamp_quant_ragged_matmul_pallas`): a tile with no rows does no work
+    and writes nothing; rows past a tile's count are written as zeros."""
+    t, j = pl.program_id(0), pl.program_id(1)
+    rows = tab_ref[2 * n_tiles + t]
 
-    @pl.when(j == 0)
-    def _zero():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    @pl.when(rows > 0)
+    def _tile():
+        @pl.when(j == 0)
+        def _zero():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    qx = qx_ref[0, 0]                                  # (bc, d) int8
-    sx = sx_ref[0, 0]                                  # (bc, 1) f32
-    zxs = zx_ref[0, 0]
-    g = _int_gemm(qx, sx, zxs, qwg_ref[0], swg_ref[0], zwg_ref[0],
-                  k_total=d)
-    u = _int_gemm(qx, sx, zxs, qwu_ref[0], swu_ref[0], zwu_ref[0],
-                  k_total=d)
-    a = jax.nn.silu(g) * u                             # (bc, bf) f32
-    # the down-proj consumes the activation slab as int8 too: per-row
-    # quantize within this f block (group-wise scales — each f tile gets
-    # its own row scale, so the partial products dequantize exactly)
-    qa, sa, zas = _rowwise_quantize(a)
-    acc_ref[...] += _int_gemm(qa, sa, zas, qwd_ref[0], swd_ref[0],
-                              zwd_ref[0], k_total=block_f)
+        qx, sx, zxs = qx_ref[...], sx_ref[...], zx_ref[...]   # (bm, ·)
+        g = _int_gemm(qx, sx, zxs, qwg_ref[0], swg_ref[0], zwg_ref[0],
+                      k_total=d)
+        u = _int_gemm(qx, sx, zxs, qwu_ref[0], swu_ref[0], zwu_ref[0],
+                      k_total=d)
+        a = jax.nn.silu(g) * u                             # (bm, bf) f32
+        # the down-proj consumes the activation slab as int8 too: per-row
+        # quantize within this f block (group-wise scales — each f tile
+        # gets its own row scale, so the partial products dequantize
+        # exactly; one block when bf = f)
+        qa, sa, zas = _rowwise_quantize(a)
+        acc_ref[...] += _int_gemm(qa, sa, zas, qwd_ref[0], swd_ref[0],
+                                  zwd_ref[0], k_total=block_f)
 
-    @pl.when(j == nf - 1)
-    def _write():
-        row = c * block_c + jax.lax.broadcasted_iota(
-            jnp.int32, (block_c, 1), 0)
-        o_ref[0, 0] = jnp.where(row < cnt, acc_ref[...],
-                                0.0).astype(o_ref.dtype)
+        @pl.when(j == nf - 1)
+        def _write():
+            row = jax.lax.broadcasted_iota(jnp.int32, (block_m, 1), 0)
+            o_ref[...] = jnp.where(row < rows, acc_ref[...],
+                                   0.0).astype(o_ref.dtype)
 
 
-def stamp_quant_grouped_matmul_pallas(
-    qx: jax.Array,           # (b, E, C, d) int8 gathered dispatch codes
-    sx: jax.Array,           # (b, E, C, 1) f32 per-token scale
-    zx: jax.Array,           # (b, E, C, 1) f32 per-token shifted zp
-    counts: jax.Array,       # (b, E) int32 kept tokens per expert bucket
+def stamp_quant_ragged_matmul_pallas(
+    table: jax.Array,        # (3 * n_tiles,) int32 tile table
+    qx: jax.Array,           # (n_tiles * bm, d) int8 row codes
+    sx: jax.Array,           # (n_tiles * bm, 1) f32 per-row scale
+    zx: jax.Array,           # (n_tiles * bm, 1) f32 per-row shifted zp
     qw_gate: jax.Array,      # (E, d, f) int8 stacked expert gate codes
     sw_gate: jax.Array,      # (E, 1, f) f32
     zw_gate: jax.Array,      # (E, 1, f) f32
@@ -473,88 +471,93 @@ def stamp_quant_grouped_matmul_pallas(
     sw_down: jax.Array,      # (E, 1, d) f32
     zw_down: jax.Array,
     *,
-    block_c: int = 128,
+    block_m: int = 32,
     block_f: int = 512,
     out_dtype=jnp.float32,
     interpret: bool | None = None,
 ) -> jax.Array:
-    """Grouped STaMP MoE FFN: the full expert stack in ONE kernel.
+    """Grouped STaMP MoE FFN over ragged per-expert row groups: the full
+    expert stack (gate/up, silu·mul, down) in ONE kernel.
 
-    The walk is ``(batch, E, C/block_c, f/block_f)`` over the
-    capacity-bucketed dispatch buffer — tokens were transformed +
-    mixed-precision quantized ONCE per sequence span *before* dispatch, so
-    each grid step streams int8 codes and int8 expert weights only.  Per
-    step: gate and up GEMMs share the one quantized dispatch tile, the
-    silu·mul epilogue runs in VMEM, and the grouped down-proj consumes the
-    requantized activation slab with its partial products accumulated in
-    f32 scratch across the f axis.  The per-(batch, expert) occupancy
-    ``counts`` rides as a scalar-prefetch table: index maps clamp the
-    capacity-tile fetch for empty bucket tails (no dead code streams), and
-    slots past the count write exact zeros.
+    Rows arrive grouped by expert, each expert's group starting at a
+    ``block_m``-aligned row offset, so every row tile belongs to one
+    expert.  The walk is ``(n_tiles, f / block_f)``; the scalar-prefetch
+    ``table`` holds three int32 vectors of length ``n_tiles``:
 
-    Returns the (b, E, C, d) expert outputs ready for the combine einsum.
+    * ``fetch[t]`` — the row tile whose codes (and output block) tile
+      ``t`` maps to: ``t`` itself for a tile with rows, the last tile
+      with rows before it for an empty one, so an empty tile copies
+      nothing and writes nothing (its index maps repeat the previous
+      step's blocks);
+    * ``expert[t]`` — the stacked-weight index of ``fetch[t]``'s group;
+    * ``rows[t]`` — the valid rows of tile ``t`` (0: empty).
+
+    Consecutive tiles of one expert keep the weight block index when
+    ``block_f`` covers ``f``, so an expert's weights stream from HBM once
+    per call however many tiles its rows fill.  Returns the ``(n_tiles *
+    block_m, d)`` expert outputs; rows past a tile's count are zero, and
+    the rows of empty tiles are undefined (no caller reads them).
     """
     if interpret is None:
         from repro.kernels.ops import default_interpret
         interpret = default_interpret()
-    b, e, cap, d = qx.shape
-    f = qw_gate.shape[-1]
-    bc = min(block_c, cap)
-    pad_c = -cap % bc
-    if pad_c:
-        padc = [(0, 0), (0, 0), (0, pad_c), (0, 0)]
-        qx = jnp.pad(qx, padc)
-        sx = jnp.pad(sx, padc, constant_values=1.0)
-        zx = jnp.pad(zx, padc)
+    r, d = qx.shape
+    e, _, f = qw_gate.shape
+    bm = block_m
+    if r % bm:
+        raise ValueError(f"{r} rows are not whole tiles of {bm}")
+    n_tiles = r // bm
+    if table.shape != (3 * n_tiles,):
+        raise ValueError(f"tile table {table.shape} does not match "
+                         f"{n_tiles} tiles")
     bf = _pick_block_n(block_f, f)
-    nc, nf = (cap + pad_c) // bc, f // bf
-    counts = counts.reshape(-1).astype(jnp.int32)
+    nf = f // bf
+    n = n_tiles
 
-    def occ_idx(i, eg, c, cnt):
-        # last capacity tile this expert bucket actually occupies; empty
-        # tail tiles re-fetch it (index unchanged between steps → no copy)
-        nblk = (cnt[i * e + eg] + bc - 1) // bc
-        return jnp.minimum(c, jnp.maximum(nblk - 1, 0))
-
-    x_spec = pl.BlockSpec((1, 1, bc, d),
-                          lambda i, eg, c, j, cnt:
-                          (i, eg, occ_idx(i, eg, c, cnt), 0))
-    s_spec = pl.BlockSpec((1, 1, bc, 1),
-                          lambda i, eg, c, j, cnt:
-                          (i, eg, occ_idx(i, eg, c, cnt), 0))
+    x_spec = pl.BlockSpec((bm, d), lambda t, j, tab: (tab[t], 0))
+    s_spec = pl.BlockSpec((bm, 1), lambda t, j, tab: (tab[t], 0))
     win_spec = pl.BlockSpec((1, d, bf),
-                            lambda i, eg, c, j, cnt: (eg, 0, j))
+                            lambda t, j, tab: (tab[n + t], 0, j))
     cin_spec = pl.BlockSpec((1, 1, bf),
-                            lambda i, eg, c, j, cnt: (eg, 0, j))
+                            lambda t, j, tab: (tab[n + t], 0, j))
     wdn_spec = pl.BlockSpec((1, bf, d),
-                            lambda i, eg, c, j, cnt: (eg, j, 0))
+                            lambda t, j, tab: (tab[n + t], j, 0))
     cdn_spec = pl.BlockSpec((1, 1, d),
-                            lambda i, eg, c, j, cnt: (eg, 0, 0))
+                            lambda t, j, tab: (tab[n + t], 0, 0))
     kernel = functools.partial(
-        _grouped_moe_kernel, num_experts=e, block_c=bc, block_f=bf,
-        nf=nf, d=d)
-    out = pl.pallas_call(
+        _ragged_moe_kernel, n_tiles=n_tiles, block_m=bm, block_f=bf, nf=nf,
+        d=d)
+    return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(b, e, nc, nf),
+            grid=(n_tiles, nf),
             in_specs=[
                 x_spec, s_spec, s_spec,
                 win_spec, cin_spec, cin_spec,
                 win_spec, cin_spec, cin_spec,
                 wdn_spec, cdn_spec, cdn_spec,
             ],
-            out_specs=pl.BlockSpec((1, 1, bc, d),
-                                   lambda i, eg, c, j, cnt: (i, eg, c, 0)),
+            out_specs=pl.BlockSpec((bm, d), lambda t, j, tab: (tab[t], 0)),
             scratch_shapes=[
-                pltpu.VMEM((bc, d), jnp.float32),   # down-proj accumulator
+                pltpu.VMEM((bm, d), jnp.float32),   # down-proj accumulator
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, e, cap + pad_c, d), out_dtype),
+        out_shape=jax.ShapeDtypeStruct((r, d), out_dtype),
         compiler_params=COMPILER_PARAMS,
         interpret=interpret,
-    )(counts, qx, sx, zx,
+        name="moe_grouped_matmul",
+    )(table.astype(jnp.int32), qx, sx, zx,
       qw_gate, sw_gate, zw_gate,
       qw_up, sw_up, zw_up,
       qw_down, sw_down, zw_down)
-    return out[:, :, :cap] if pad_c else out
+
+
+def tile_table(rows: jax.Array, expert: jax.Array) -> jax.Array:
+    """The ragged kernel's tile table from each tile's valid rows and its
+    group's expert index: an empty tile fetches (and names the expert of)
+    the last tile with rows before it, tile 0 where there is none."""
+    n = rows.shape[0]
+    t = jnp.arange(n, dtype=jnp.int32)
+    fetch = jax.lax.cummax(jnp.where(rows > 0, t, 0), axis=0)
+    return jnp.concatenate([fetch, expert[fetch], rows]).astype(jnp.int32)

@@ -20,7 +20,9 @@ from typing import Optional, Tuple
 @dataclasses.dataclass(frozen=True)
 class LayerSpec:
     mixer: str = "attn"        # attn | mamba | none
-    ffn: str = "mlp"           # mlp | moe | moe_dense (Arctic residual) | none
+    ffn: str = "mlp"           # mlp | moe | moe_dense (MoE + a dense MLP
+                               # beside it: Arctic's residual, Granite's
+                               # shared expert) | none
 
 
 @dataclasses.dataclass(frozen=True)
@@ -44,12 +46,15 @@ class ModelConfig:
     first_layer_dense: bool = False       # Kimi-K2: layer 0 is dense MLP
     capacity_factor: float = 1.25
     moe_group_size: int = 1024            # routing group (GShard-style)
+    num_local_experts: int = 0            # experts this chip holds (0: all)
+    expert_offset: int = 0                # first held expert's global index
     # --- hybrid / ssm ---
     attn_period: int = 0                  # Jamba: 1 attention per 8 layers
     ssm_state: int = 0
     ssm_head_dim: int = 64
     ssm_expand: int = 2
     conv_width: int = 4
+    conv_bias: bool = False               # Granite-4.0-H: depthwise conv bias
     # --- encoder-decoder ---
     encoder_layers: int = 0               # >0 => enc-dec (Seamless)
     # --- modality frontend stubs ---
@@ -58,8 +63,16 @@ class ModelConfig:
     frame_ratio: int = 4                  # audio frames = seq // frame_ratio
     # --- misc ---
     rope_theta: float = 1e6
+    use_rope: bool = True                 # False: NoPE attention (Granite)
+    # Granite's scalars (1.0 / None: the plain transformer's)
+    embedding_multiplier: float = 1.0     # x0 = embed(tokens) * m
+    residual_multiplier: float = 1.0      # x + m * block(x)
+    attention_multiplier: Optional[float] = None   # score scale; None:
+    # 1/sqrt(head_dim)
+    logits_scaling: float = 1.0           # logits = head(x) / s
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
+    embed_init_std: float = 0.02          # std of the random embedding
     schedule: str = "cosine"              # 'wsd' for MiniCPM
     sub_quadratic: bool = False           # True for ssm/hybrid (long_500k ok)
     source: str = ""
@@ -90,6 +103,12 @@ class ModelConfig:
         return self.moe_d_ff or self.d_ff
 
     @property
+    def held_experts(self) -> int:
+        """Experts whose weights this chip holds: ``[expert_offset,
+        expert_offset + held_experts)`` of the router's ``num_experts``."""
+        return self.num_local_experts or self.num_experts
+
+    @property
     def d_inner(self) -> int:
         return self.ssm_expand * self.d_model
 
@@ -109,8 +128,9 @@ class ModelConfig:
             for i in range(p):
                 mixer = "attn" if i == (p // 2) else "mamba"
                 # MoE every `moe_period`-th layer within the period
-                ffn = "moe" if (self.num_experts and i % self.moe_period ==
-                                (self.moe_period - 1)) else "mlp"
+                moe = "moe_dense" if self.dense_residual else "moe"
+                ffn = moe if (self.num_experts and i % self.moe_period ==
+                              (self.moe_period - 1)) else "mlp"
                 period.append(LayerSpec(mixer, ffn))
             if n % p:
                 raise ValueError(
@@ -133,12 +153,15 @@ class ModelConfig:
         mlp = 3 * d * self.d_ff
         moe = 0
         if self.num_experts:
-            moe = self.num_experts * 3 * d * self.expert_d_ff + d * self.num_experts
+            moe = (self.held_experts * 3 * d * self.expert_d_ff
+                   + d * self.num_experts)
         di, ns, nh = self.d_inner, self.ssm_state, self.ssm_heads
         groups_dim = 2 * ns  # B and C projections (single group)
         mamba = (d * (2 * di + groups_dim + nh)   # in_proj (x, z, B, C, dt)
                  + di * d                          # out_proj
                  + di * self.conv_width + nh * 2 + di)  # conv, A/dt bias, D
+        if self.conv_bias:
+            mamba += di + groups_dim
         total = 0
         pro, period, nper = self.layer_plan()
         for spec in pro + period * nper:
@@ -164,7 +187,7 @@ class ModelConfig:
         """Active (per-token) params, for MoE MODEL_FLOPS = 6·N_active·D."""
         if not self.num_experts:
             return self.param_count()
-        full_moe = self.num_experts * 3 * self.d_model * self.expert_d_ff
+        full_moe = self.held_experts * 3 * self.d_model * self.expert_d_ff
         active_moe = self.experts_per_token * 3 * self.d_model * self.expert_d_ff
         pro, period, nper = self.layer_plan()
         n_moe_layers = sum(1 for s in pro + period * nper

@@ -16,7 +16,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -109,6 +109,12 @@ def stamp_fused_dual_linear(x: Array, w_gate: dict, w_up: dict,
 # ---------------------------------------------------------------------------
 
 
+def _score_scale(scale: Optional[float], hd: int) -> float:
+    """The attention score scale: the model's own (Granite's
+    ``attention_multiplier``), else ``1/sqrt(head_dim)``."""
+    return 1.0 / np.sqrt(hd) if scale is None else scale
+
+
 @dataclasses.dataclass(frozen=True)
 class AttnChunks:
     q: int = 2048
@@ -122,6 +128,7 @@ def flash_attention(
     causal: bool = True,
     chunks: AttnChunks = AttnChunks(),
     q_offset: int = 0,
+    scale: Optional[float] = None,
 ) -> Array:
     """Memory-bounded attention: outer scan over query chunks, inner scan
     over KV chunks with running (max, sum, acc) — the standard online-softmax
@@ -129,7 +136,8 @@ def flash_attention(
     (no materialized KV repeat).  Causal masking is applied per
     (q-chunk, kv-chunk) pair; fully-masked pairs still lower (XLA cannot
     skip data-dependent work in a scan) — the wasted half of causal FLOPs is
-    accounted for in the roofline's MODEL_FLOPS/HLO ratio.
+    accounted for in the roofline's MODEL_FLOPS/HLO ratio.  ``scale``
+    multiplies the scores (None: ``1/sqrt(hd)``).
     """
     b, sq, h, hd = q.shape
     skv, g = k.shape[1], k.shape[2]
@@ -140,7 +148,7 @@ def flash_attention(
     nq, nkv = sq // cq, skv // ckv
     assert sq % cq == 0 and skv % ckv == 0, (sq, cq, skv, ckv)
 
-    scale = 1.0 / np.sqrt(hd)
+    scale = _score_scale(scale, hd)
     # (nq, b, g, rep, cq, hd) / (nkv, b, g, ckv, hd)
     qc = q.reshape(b, nq, cq, g, rep, hd).transpose(1, 0, 3, 4, 2, 5)
     kc = k.reshape(b, nkv, ckv, g, hd).transpose(1, 0, 3, 2, 4)
@@ -197,13 +205,14 @@ def decode_attention(
     k_cache: Array,          # (b, s, kv, hd)  — bf16 (already dequantized)
     v_cache: Array,
     length: Optional[Array] = None,
+    scale: Optional[float] = None,
 ) -> Array:
     """Single-token attention over the full cache, GQA-grouped.  When the
     cache's sequence axis is sharded over the 'model' mesh axis, GSPMD turns
     the softmax max/sum reductions into all-reduces — the TPU-native
     split-KV decode."""
     out = decode_attention_segments(q, [(k_cache, v_cache, 0)],
-                                    length=length)
+                                    length=length, scale=scale)
     return out
 
 
@@ -212,6 +221,7 @@ def decode_attention_segments(
     segments: list,                # [(k, v, position_offset), ...]
     length: Optional[Array] = None,
     parts: tuple = (),             # [(m, l, o)] computed elsewhere
+    scale: Optional[float] = None,
 ) -> Array:
     """Decode attention over disjoint cache segments with a score-level
     merge: the mixed-precision cache's hi (64-token int8) and lo (int4)
@@ -221,11 +231,12 @@ def decode_attention_segments(
     Matmuls keep bf16 operands with f32 accumulation (MXU-native).
     ``parts`` adds softmax statistics of further positions computed
     elsewhere (the paged kernel's int4 pages): ``m, l`` (b, g, rep), ``o``
-    (b, g, rep, hd), unnormalised."""
+    (b, g, rep, hd), unnormalised.  ``scale`` multiplies the scores
+    (None: ``1/sqrt(hd)``)."""
     b, _, h, hd = q.shape
     g = segments[0][0].shape[2] if segments else parts[0][0].shape[1]
     rep = h // g
-    scale = 1.0 / np.sqrt(hd)
+    scale = _score_scale(scale, hd)
     dtype = segments[0][0].dtype if segments else q.dtype
     qg = (q.reshape(b, g, rep, hd) * scale).astype(dtype)
 
@@ -276,6 +287,7 @@ def chunked_prefill_attention(
     v_self: Array,
     start: Array,                  # scalar or (b,) int32: tokens cached
     parts: tuple = (),             # [(m, l, o)] computed elsewhere
+    scale: Optional[float] = None,
 ) -> Array:
     """Attention for one continuous-batching prefill chunk: queries at
     global positions ``start + i`` attend to the **cached prefix** (the
@@ -291,11 +303,12 @@ def chunked_prefill_attention(
     rows, each with its own cached-prefix length).  ``parts`` adds softmax
     statistics of further cached positions computed elsewhere (the paged
     kernel's int4 pages): ``m, l`` (b, g, rep, c), ``o`` (b, g, rep, c,
-    hd), unnormalised."""
+    hd), unnormalised.  ``scale`` multiplies the scores (None:
+    ``1/sqrt(hd)``)."""
     b, c, h, hd = q.shape
     g = k_self.shape[2]
     rep = h // g
-    scale = 1.0 / np.sqrt(hd)
+    scale = _score_scale(scale, hd)
     qg = q.reshape(b, c, g, rep, hd).astype(jnp.float32) * scale
     start = jnp.broadcast_to(jnp.asarray(start, jnp.int32).reshape(-1), (b,))
     qpos = start[:, None] + jnp.arange(c)[None, :]           # (b, c)
@@ -362,15 +375,12 @@ def moe_route(
     capacity_factor: float,
     valid: Array,             # (b, s) f32 pad mask
 ) -> tuple[Array, Array, Array]:
-    """GShard capacity routing, shared VERBATIM by the reference and fused
-    MoE paths — both consume the same combine/dispatch tensors, so kept and
-    capacity-dropped token sets are bit-identical by construction.
+    """GShard capacity routing of the training MoE (`moe_ffn`).
 
     Returns ``(combine (b,s,E,C) in x.dtype, dispatch, counts (b,E)
     int32)``.  ``counts`` is each expert bucket's kept-token occupancy —
     kept slots form a prefix of ``[0, C)`` (the capacity cumsum assigns
-    positions in flat routing order), which is what lets the grouped
-    kernel's scalar-prefetch table clamp empty capacity tails.  When a
+    positions in flat routing order).  When a
     quant-telemetry scope is open, per-expert load / drop counters ride
     the same collection protocol as the site stats.
     """
@@ -417,12 +427,17 @@ def moe_ffn(
     gate_w: Array,            # (d, E)
     w_gate: Array,            # (E, d, f)
     w_up: Array,              # (E, d, f)
-    w_down: Array,            # (E, f, d)
+    w_down: Array,            # (E_h, f, d) the held experts
     experts_per_token: int,
     capacity_factor: float,
     group_size: int = 1024,
+    offset: int = 0,
 ) -> Array:
-    """GShard/Switch-style capacity-based top-k MoE (reference path).
+    """GShard/Switch-style capacity-based top-k MoE: the training path
+    (serving routes dropless, `moe_ffn_dropless_fused`).  Routing runs
+    over the router's ``E`` experts; only the buckets of the ``E_h`` held
+    ones, ``[offset, offset + E_h)``, are computed (all of them when every
+    expert is held).
 
     Tokens are routed in fixed groups of ``group_size`` (the batch axis is
     folded with sequence sub-blocks), so the dispatch/combine tensors are
@@ -441,6 +456,8 @@ def moe_ffn(
     x, valid, seq_p = _moe_fold(x, group_size)
     combine, dispatch, _ = moe_route(x, gate_w, experts_per_token,
                                      capacity_factor, valid)
+    held = slice(offset, offset + w_gate.shape[0])
+    combine, dispatch = combine[:, :, held], dispatch[:, :, held]
 
     xin = jnp.einsum("bsec,bsd->becd", dispatch, x)            # (b, E, C, d)
     g = jnp.einsum("becd,edf->becf", xin, w_gate.astype(x.dtype))
@@ -451,51 +468,190 @@ def moe_ffn(
     return y.reshape(bsz, seq_p, d)[:, :seq]
 
 
-def moe_ffn_fused(
-    x: Array,                 # (b, s, d) — the stamped round-trip activation
-    gate_w: Array,            # (d, E) full-precision router
-    w_gate: dict,             # {"iq": (E, d, f) int8, "isw", "izw"} prepared
-    w_up: dict,
-    w_down: dict,             # {"iq": (E, f, d) int8, ...}
-    experts_per_token: int,
-    capacity_factor: float,
-    group_size: int = 1024,
-) -> Array:
-    """Capacity MoE through the grouped STaMP kernel.
+class RaggedDispatch(NamedTuple):
+    """Dropless routing of ``T`` tokens over the experts this chip holds,
+    laid out for `stamp_quant_ragged_matmul`: each held expert's rows
+    grouped at a ``block_m``-aligned offset."""
 
-    Routing is `moe_route` on the SAME stamped activation the reference
-    path sees (bit-identical kept/dropped sets).  Then, instead of
-    dispatching bf16 activations into ``(b, E, C, d)`` and re-materializing
-    bf16 expert weights per call, each token is quantized ONCE
-    (`token_quantize` — however many of its top-k buckets it lands in), the
-    dispatch gather moves int8 codes, and `stamp_quant_grouped_matmul` runs
-    the gate/up/down expert stack as grouped int8 GEMMs in one kernel with
-    the per-bucket occupancy as its scalar-prefetch table.
-    """
+    table: Array       # (3·n_tiles,) int32 tile table: fetch | expert | rows
+    row_token: Array   # (n_tiles·block_m,) int32 token of each row
+    pair_row: Array    # (T, k) int32 row of each choice; n_tiles·block_m
+    #                    (a zero row) where the choice is not held here
+    gates: Array       # (T, k) f32 gate of each choice
+    counts: Array      # (4,) int32: rows routed, held, expert groups, dropped
+
+
+# what `RaggedDispatch.counts` holds, in order (the engine's counters)
+MOE_COUNTS = ("moe_rows_routed", "moe_rows_held", "moe_expert_groups",
+              "moe_rows_dropped")
+
+
+def moe_topk(x: Array, gate_w: Array, k: int) -> tuple[Array, Array]:
+    """Router of the dropless MoE: f32 logits over every expert, the top
+    ``k``, and their gates as the softmax over those ``k`` logits (equal
+    to softmax → top-k → renormalise).  ``x``: (T, d); returns (gates
+    (T, k) f32, expert ids (T, k) int32)."""
+    logits = jnp.dot(x.astype(jnp.float32), gate_w.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    top, idx = jax.lax.top_k(logits, k)
+    return jax.nn.softmax(top, axis=-1), idx.astype(jnp.int32)
+
+
+def _record_router(per_expert: Array, slots: int) -> None:
+    """Router load under the ``moe_router`` telemetry pseudo-site (as
+    `moe_route` records it): rows per held expert, none dropped, and
+    ``slots``, the static row capacity of the layer's expert walk."""
+    if QS.active():
+        QS.record_extra("moe_router", {
+            "expert_tokens": per_expert.astype(jnp.float32),
+            "dropped_tokens": jnp.zeros((), jnp.float32),
+            "capacity_slots": jnp.asarray(float(slots), jnp.float32),
+        })
+
+
+def _held(idx: Array, valid: Array, offset: int, held: int) -> Array:
+    """(T, k) bool: choices of valid tokens that land on a held expert."""
+    local = idx - offset
+    return (local >= 0) & (local < held) & valid[:, None]
+
+
+def moe_route_dropless(x: Array, gate_w: Array, k: int, valid: Array, *,
+                       offset: int, held: int, block_m: int
+                       ) -> RaggedDispatch:
+    """Route every valid token's top-``k`` choices (nothing is dropped)
+    and lay the choices that land on experts ``[offset, offset + held)``
+    out as ragged row groups: expert ``e``'s rows fill
+    ``ceil(count_e / block_m)`` tiles from its own tile offset, in token
+    order.  The tile count is static — enough for every valid token to
+    send all ``k`` choices here — so no shape depends on the routing.
+    ``valid`` (T,) bool masks pad tokens out of routing."""
+    t = x.shape[0]
+    gates, idx = moe_topk(x, gate_w, k)
+    is_held = _held(idx, valid, offset, held)
+    key = jnp.where(is_held, idx - offset, held).reshape(-1)     # (T·k,)
+    cnt = jnp.zeros((held + 1,), jnp.int32).at[key].add(1)[:held]
+    n_tiles = -(-t * min(k, held) // block_m) + min(held, t * k)
+    tiles_of = (cnt + block_m - 1) // block_m
+    tile_end = jnp.cumsum(tiles_of)
+    tile_start = tile_end - tiles_of
+    # pairs sorted by expert (stable: token order within an expert)
+    order = jnp.argsort(key, stable=True)
+    skey = key[order]
+    group0 = jnp.concatenate([jnp.cumsum(cnt) - cnt, jnp.zeros((1,),
+                                                               jnp.int32)])
+    rank = jnp.arange(t * k, dtype=jnp.int32) - group0[skey]
+    r_max = n_tiles * block_m
+    row = jnp.where(skey < held,
+                    jnp.concatenate([tile_start, jnp.zeros((1,), jnp.int32)]
+                                    )[skey] * block_m + rank, r_max)
+    pair_row = jnp.zeros((t * k,), jnp.int32).at[order].set(row)
+    row_token = jnp.zeros((r_max + 1,), jnp.int32).at[row].set(
+        (order // k).astype(jnp.int32))[:r_max]
+    # per tile: its expert (the group whose tile range holds it) and rows
+    tid = jnp.arange(n_tiles, dtype=jnp.int32)
+    expert = jnp.sum(tid[:, None] >= tile_end[None, :], axis=1)
+    expert = jnp.minimum(expert, held - 1).astype(jnp.int32)
+    rows = jnp.clip(cnt[expert] - (tid - tile_start[expert]) * block_m, 0,
+                    block_m)
+    rows = jnp.where(tid < tile_end[-1], rows, 0).astype(jnp.int32)
+    from repro.kernels.stamp_matmul import tile_table
+    n_held = jnp.sum(cnt)
+    _record_router(cnt, r_max)
+    counts = jnp.stack([jnp.sum(valid.astype(jnp.int32)) * k, n_held,
+                        jnp.sum((cnt > 0).astype(jnp.int32)),
+                        n_held - jnp.sum(rows)]).astype(jnp.int32)
+    return RaggedDispatch(tile_table(rows, expert), row_token,
+                          pair_row.reshape(t, k), gates, counts)
+
+
+def moe_block_f(d: int, f: int, budget: int = 12 * 2 ** 20) -> int:
+    """The grouped kernel's f tile: the whole expert width where one
+    expert's three int8 matrices fit ``budget`` bytes of VMEM (so an
+    expert's weights stream once per call), else halved until they do."""
+    bf = f
+    while 3 * d * bf > budget and bf % 2 == 0 and bf > 128:
+        bf //= 2
+    return bf
+
+
+def moe_ffn_dropless_fused(
+    x: Array,                 # (T, d) the activation the experts read
+    gate_w: Array,            # (d, E) router over every expert
+    w_gate: dict,             # {"iq": (E_h, d, f) int8, "isw", "izw"}
+    w_up: dict,
+    w_down: dict,             # {"iq": (E_h, f, d) int8, ...}
+    experts_per_token: int,
+    valid: Array,             # (T,) bool
+    *,
+    offset: int,
+    block_m: int = 32,
+) -> tuple[Array, Array]:
+    """Dropless MoE through the grouped int8 kernel, over the ``E_h``
+    experts this chip holds: route over all ``E`` experts
+    (`moe_route_dropless`, named scope ``moe.route``), quantize each token
+    once at 8 bits (per token, min-max), gather the held choices' codes
+    into their expert's row group, run gate/up, silu·mul and down in one
+    `stamp_quant_ragged_matmul` call (``moe.experts``), and sum each
+    token's held choices weighted by their gates (``moe.combine``).
+    Choices held elsewhere contribute zero here.  Returns the (T, d) f32
+    output and the (4,) int32 counts (`MOE_COUNTS`)."""
     from repro.core.stamp import token_quantize
     from repro.kernels import ops as kops
-    bsz, seq, d = x.shape
-    xg, valid, seq_p = _moe_fold(x, group_size)
-    combine, dispatch, counts = moe_route(xg, gate_w, experts_per_token,
-                                          capacity_factor, valid)
-    b, _, e, cap = combine.shape
-    qd, sd, zd = token_quantize(xg)
-    # slot c of expert e holds the c-th kept token in sequence order, so
-    # the argmax over the one-hot sequence axis IS the gather index;
-    # empty slots gather token 0 and are zeroed by the kernel's count mask
-    src = jnp.argmax(dispatch, axis=1)                         # (b, E, C)
-    idx = src.reshape(b, e * cap, 1)
+    t, d = x.shape
+    held, _, f = w_gate["iq"].shape
+    with jax.named_scope("moe.route"):
+        disp = moe_route_dropless(x, gate_w, experts_per_token, valid,
+                                  offset=offset, held=held, block_m=block_m)
+    with jax.named_scope("moe.experts"):
+        qd, sd, zd = token_quantize(x)
+        rt = disp.row_token
+        ye = kops.stamp_quant_ragged_matmul(
+            disp.table, qd[rt], sd[rt], zd[rt],
+            w_gate["iq"], w_gate["isw"], w_gate["izw"],
+            w_up["iq"], w_up["isw"], w_up["izw"],
+            w_down["iq"], w_down["isw"], w_down["izw"],
+            block_m=block_m, block_f=moe_block_f(d, f))
+    with jax.named_scope("moe.combine"):
+        ye = jnp.concatenate([ye, jnp.zeros((1, d), ye.dtype)])
+        y = jnp.einsum("tk,tkd->td", disp.gates, ye[disp.pair_row],
+                       precision=jax.lax.Precision.HIGHEST)
+    return y, disp.counts
 
-    def gather(t):
-        return jnp.take_along_axis(t, idx, axis=1).reshape(b, e, cap, -1)
 
-    ye = kops.stamp_quant_grouped_matmul(
-        gather(qd), gather(sd), gather(zd), counts,
-        w_gate["iq"], w_gate["isw"], w_gate["izw"],
-        w_up["iq"], w_up["isw"], w_up["izw"],
-        w_down["iq"], w_down["isw"], w_down["izw"])
-    y = jnp.einsum("bsec,becd->bsd", combine, ye.astype(x.dtype))
-    return y.reshape(bsz, seq_p, d)[:, :seq]
+def moe_ffn_dropless(
+    x: Array,                 # (T, d)
+    gate_w: Array,            # (d, E)
+    w_gate: Array,            # (E_h, d, f) held experts, float
+    w_up: Array,
+    w_down: Array,            # (E_h, f, d)
+    experts_per_token: int,
+    valid: Array,             # (T,) bool
+    *,
+    offset: int,
+) -> tuple[Array, Array]:
+    """Dropless MoE over the held experts with float weights, as a dense
+    loop: every held expert runs on every token and its output is
+    weighted by the token's gate for it (zero where the token did not
+    choose it).  The reference of `moe_ffn_dropless_fused`, and the path
+    of float or reference-execution trees.  Returns the (T, d) output and
+    the (4,) int32 counts (`MOE_COUNTS`; none is dropped here)."""
+    held = w_gate.shape[0]
+    gates, idx = moe_topk(x, gate_w, experts_per_token)
+    is_held = _held(idx, valid, offset, held)
+    onehot = jax.nn.one_hot(idx - offset, held, dtype=gates.dtype)
+    dense = jnp.einsum("tk,tke->te", gates * is_held, onehot)
+    g = jnp.einsum("td,edf->tef", x, w_gate.astype(x.dtype))
+    u = jnp.einsum("td,edf->tef", x, w_up.astype(x.dtype))
+    out = jnp.einsum("tef,efd->ted", jax.nn.silu(g) * u,
+                     w_down.astype(x.dtype))
+    per_expert = jnp.einsum("tk,tke->e", is_held.astype(gates.dtype),
+                            onehot)
+    _record_router(per_expert, x.shape[0] * held)
+    counts = jnp.stack([jnp.sum(valid.astype(jnp.int32)) * experts_per_token,
+                        jnp.sum(is_held.astype(jnp.int32)),
+                        jnp.sum((per_expert > 0).astype(jnp.int32)),
+                        jnp.zeros((), jnp.int32)]).astype(jnp.int32)
+    return jnp.einsum("te,ted->td", dense.astype(out.dtype), out), counts
 
 
 # ---------------------------------------------------------------------------
@@ -545,7 +701,9 @@ def ssd_chunked(
         # intra-chunk 'attention' matrix L_ij = exp(cum_i - cum_j) (i >= j)
         seg = cum[:, :, None, :] - cum[:, None, :, :]       # (b, c, c, h)
         tri = jnp.tril(jnp.ones((chunk, chunk), bool))
-        l = jnp.where(tri[None, :, :, None], jnp.exp(seg), 0.0)
+        # mask before the exp: above the diagonal seg > 0 can overflow,
+        # and an inf there turns the backward pass's zeros into NaN
+        l = jnp.exp(jnp.where(tri[None, :, :, None], seg, -jnp.inf))
         # scores: C_i · B_j weighted by decay and dt_j
         cb = jnp.einsum("bin,bjn->bij", ck, bk)             # (b, c, c)
         w = cb[..., None] * l * dtk[:, None, :, :]          # (b, c, c, h)
@@ -566,8 +724,10 @@ def ssd_chunked(
 
 
 def causal_conv1d(x: Array, w: Array, cache: Optional[Array] = None,
-                  lengths: Optional[Array] = None) -> tuple[Array, Array]:
-    """Depthwise causal conv along seq.  x: (b, s, d); w: (width, d).
+                  lengths: Optional[Array] = None,
+                  bias: Optional[Array] = None) -> tuple[Array, Array]:
+    """Depthwise causal conv along seq.  x: (b, s, d); w: (width, d);
+    ``bias`` (d,) is added before the SiLU.
     Returns (y, new_cache) where cache holds the last (width-1) inputs.
 
     ``lengths`` (b,) int32 marks each row's valid token count when ``x`` is
@@ -582,6 +742,8 @@ def causal_conv1d(x: Array, w: Array, cache: Optional[Array] = None,
         cache = jnp.zeros((x.shape[0], width - 1, x.shape[-1]), x.dtype)
     xp = jnp.concatenate([cache, x], axis=1)
     y = sum(xp[:, i:i + x.shape[1]] * w[i][None, None] for i in range(width))
+    if bias is not None:
+        y = y + bias.astype(y.dtype)
     if width <= 1:
         new_cache = cache
     elif lengths is None:

@@ -56,8 +56,8 @@ class ServeConfig:
     # (clip rate, hi-token coverage, scale range, saturation — see
     # repro/obs/quantstats.py) returned alongside the step outputs as
     # on-device scalar reductions in the SAME program: zero extra device
-    # dispatches per step.  Opt-in: changes the arity of prefill /
-    # paged_prefill_chunk / paged_unified_step returns
+    # dispatches per step.  Opt-in: adds an element to the returns of
+    # prefill / paged_prefill_chunk / paged_unified_step
 
 
 # ---------------------------------------------------------------------------
@@ -96,10 +96,17 @@ def init_layer_params(key, spec: LayerSpec, cfg: ModelConfig,
         conv_dim = di + 2 * n
         p["ln1"] = jnp.ones((d,), dtype)
         p["in_proj"] = _dense_init(next(keys), d, 2 * di + 2 * n + h, dtype)
-        p["conv_w"] = (jax.random.normal(next(keys), (cfg.conv_width, conv_dim),
-                                         jnp.float32) * 0.1).astype(dtype)
-        p["a_log"] = jnp.zeros((h,), jnp.float32)
-        p["dt_bias"] = jnp.full((h,), -2.0, jnp.float32)
+        # Mamba-2's published conv init (PyTorch's Conv1d default): the
+        # depthwise fan-in is the width, weight and bias U(±1/sqrt(width))
+        bound = 1.0 / np.sqrt(cfg.conv_width)
+        p["conv_w"] = jax.random.uniform(
+            next(keys), (cfg.conv_width, conv_dim), jnp.float32, -bound,
+            bound).astype(dtype)
+        if cfg.conv_bias:
+            p["conv_b"] = jax.random.uniform(
+                jax.random.fold_in(key, 102), (conv_dim,), jnp.float32,
+                -bound, bound).astype(dtype)
+        p["a_log"], p["dt_bias"] = _ssm_decay_init(key, h)
         p["d_skip"] = jnp.ones((h,), jnp.float32)
         p["ssm_norm"] = jnp.ones((di,), dtype)
         p["out_proj"] = _dense_init(next(keys), di, d, dtype)
@@ -113,14 +120,35 @@ def init_layer_params(key, spec: LayerSpec, cfg: ModelConfig,
         e, f = cfg.num_experts, cfg.expert_d_ff
         p["ln2"] = jnp.ones((d,), dtype)
         p["gate_w"] = _dense_init(next(keys), d, e, dtype)
-        std = 1.0 / np.sqrt(d)
-        p["we_gate"] = (jax.random.normal(next(keys), (e, d, f), jnp.float32)
-                        * std).astype(dtype)
-        p["we_up"] = (jax.random.normal(next(keys), (e, d, f), jnp.float32)
-                      * std).astype(dtype)
-        p["we_down"] = (jax.random.normal(next(keys), (e, f, d), jnp.float32)
-                        * (1.0 / np.sqrt(f))).astype(dtype)
+        # each held expert drawn from its GLOBAL index, so a chip's share
+        # holds exactly the uncut layer's experts [offset, offset + held)
+        held = cfg.expert_offset + jnp.arange(cfg.held_experts)
+
+        def experts(k, shape, std):
+            return jax.vmap(lambda i: jax.random.normal(
+                jax.random.fold_in(k, i), shape, jnp.float32))(held) * std
+
+        p["we_gate"] = experts(next(keys), (d, f), 1.0 / np.sqrt(d)
+                               ).astype(dtype)
+        p["we_up"] = experts(next(keys), (d, f), 1.0 / np.sqrt(d)
+                             ).astype(dtype)
+        p["we_down"] = experts(next(keys), (f, d), 1.0 / np.sqrt(f)
+                               ).astype(dtype)
     return p
+
+
+def _ssm_decay_init(key, h: int) -> tuple[Array, Array]:
+    """Mamba-2's published init of the per-head decay: ``A = -exp(a_log)``
+    with ``exp(a_log) ~ U[1, 16]``, and ``dt_bias`` the inverse softplus of
+    a step ``dt ~ exp(U[log 0.001, log 0.1])`` (floored at 1e-4), so heads
+    remember from a few to a few hundred tokens.  Drawn from keys folded
+    off the layer key, leaving the other parameters' draws as they were."""
+    a = jax.random.uniform(jax.random.fold_in(key, 100), (h,), jnp.float32,
+                           1.0, 16.0)
+    u = jax.random.uniform(jax.random.fold_in(key, 101), (h,), jnp.float32)
+    dt = jnp.maximum(jnp.exp(u * (np.log(0.1) - np.log(0.001))
+                             + np.log(0.001)), 1e-4)
+    return jnp.log(a), dt + jnp.log(-jnp.expm1(-dt))
 
 
 def init_params(key, cfg: ModelConfig, dtype=jnp.float32,
@@ -146,7 +174,7 @@ def init_params(key, cfg: ModelConfig, dtype=jnp.float32,
     params: dict = {
         "embed": finish((jax.random.normal(
             k_embed, (cfg.padded_vocab, cfg.d_model), jnp.float32)
-            * 0.02).astype(dtype)),
+            * cfg.embed_init_std).astype(dtype)),
         "final_norm": finish(jnp.ones((cfg.d_model,), dtype)),
     }
     if not cfg.tie_embeddings:
@@ -158,11 +186,12 @@ def init_params(key, cfg: ModelConfig, dtype=jnp.float32,
             finish(init_layer_params(k, s, cfg, dtype))
             for k, s in zip(pro_keys, pro))
     per_keys = jax.random.split(k_per, nper)
-    params["period"] = jax.lax.map(
-        lambda k: finish(tuple(
-            init_layer_params(kk, s, cfg, dtype)
-            for kk, s in zip(jax.random.split(k, len(period)), period))),
-        per_keys)
+    # one layer position at a time (a period's layers draw from the split
+    # of its period key): only one layer's float tree is ever live
+    params["period"] = tuple(
+        jax.lax.map(lambda k, j=j, s=s: finish(init_layer_params(
+            jax.random.split(k, len(period))[j], s, cfg, dtype)), per_keys)
+        for j, s in enumerate(period))
     if cfg.encoder_layers:
         enc_spec = LayerSpec("attn", "mlp")
         enc_cfg = dataclasses.replace(cfg, encoder_layers=0)  # no cross in enc
@@ -266,8 +295,9 @@ def quantize_weights_for_serving(params: Pytree, bits: int = 4) -> Pytree:
 #
 #   grouped — the stacked (E, din, dout) expert buffers prepare in one
 #            `prepare_linear` pass (per-output-channel scales per expert)
-#            and feed `stamp_quant_grouped_matmul`, which walks capacity
-#            buckets with the router occupancy scalar-prefetched.
+#            and feed `stamp_quant_ragged_matmul`, which walks each held
+#            expert's ragged row group with the tile table
+#            scalar-prefetched.
 #
 # Cross-attention projections (xw*) stay un-prepared: the paper applies no
 # sequence transform at pooled-conditioning sites (Table 4).
@@ -327,10 +357,9 @@ def fused_site_matrix(cfg: ModelConfig, stamp: Optional[StampConfig],
             add("gate_up", "stamp_quant_dual_matmul", "pair")
             add("wo_mlp", "stamp_quant_matmul", "single")
         if spec.ffn in ("moe", "moe_dense"):
-            # capacity-dispatched (b, E, C, d) expert tensors run through
-            # the grouped kernel: quantize-once dispatch + occupancy-
-            # prefetched int8 expert GEMMs (config-level eligibility only)
-            add("moe", "stamp_quant_grouped_matmul", "grouped_dispatch")
+            # dropless routing: ragged per-expert row groups of the held
+            # experts through the grouped kernel, prefill and decode alike
+            add("moe", "stamp_quant_ragged_matmul", "ragged_dispatch")
     if cfg.encoder_layers:
         # pooled-conditioning sites carry no sequence transform (Table 4)
         for _ in range(len(specs)):
@@ -401,7 +430,9 @@ def prepare_fused_weights(params: Pytree, stamp: StampConfig) -> Pytree:
                     # _encoder_forward): quantizing it is pure precision loss
                     out[k] = v
                 elif k == "period":
-                    out[k] = jax.lax.map(visit, v)
+                    # one layer position at a time: one layer's f32
+                    # dequant is live, not a whole period's
+                    out[k] = tuple(jax.lax.map(visit, layer) for layer in v)
                 elif k in FUSED_SITES and \
                         not (isinstance(v, dict) and "iq" in v):
                     out[k] = prep(v)
@@ -489,6 +520,29 @@ def _collect_telemetry(serve: ServeConfig) -> bool:
             and serve.stamp.enabled)
 
 
+def _residual(x: Array, y: Array, cfg: ModelConfig) -> Array:
+    """``x + m·y`` with the model's residual multiplier (Granite's 0.22);
+    at 1 exactly ``x + y``."""
+    m = cfg.residual_multiplier
+    return x + y if m == 1.0 else x + y * m
+
+
+# MoE counts (`layers.MOE_COUNTS`) the dropless layers record while a
+# stack traces: `run_stack` sums them over its layers (through the scan
+# as outputs) and leaves the total here for the entry point to return.
+_MOE_TALLY: list = []
+
+
+def _drain_tally() -> Array:
+    """Sum and clear the (4,) int32 MoE counts recorded at this trace
+    level (zeros where none were)."""
+    out = jnp.zeros((len(L.MOE_COUNTS),), jnp.int32)
+    for c in _MOE_TALLY:
+        out = out + c
+    _MOE_TALLY.clear()
+    return out
+
+
 def _maybe_stamp(x: Array, stamp: Optional[StampConfig],
                  site: Optional[str] = None) -> Array:
     if stamp is None or not stamp.enabled:
@@ -538,7 +592,7 @@ def _attn_qkv(p: dict, h: Array, cfg: ModelConfig,
 
 
 def _attn_out(p: dict, attn: Array, x: Array,
-              stamp: Optional[StampConfig]) -> Array:
+              stamp: Optional[StampConfig], cfg: ModelConfig) -> Array:
     """Out-projection (named scope ``stamp.out``) + residual (shared
     across paths)."""
     with jax.named_scope("stamp.out"):
@@ -552,7 +606,7 @@ def _attn_out(p: dict, attn: Array, x: Array,
         else:
             y = _linear(_maybe_stamp(_merge_heads(attn), stamp, site="wo"),
                         p["wo"])
-    return x + y
+    return _residual(x, y, cfg)
 
 
 def attn_block(
@@ -564,6 +618,7 @@ def attn_block(
     cache_capacity: Optional[int] = None, paged: Optional[dict] = None,
 ) -> tuple[Array, Optional[dict]]:
     hd, nh, kvh = cfg.resolved_head_dim, cfg.num_heads, cfg.num_kv_heads
+    scale = cfg.attention_multiplier
     h = L.rms_norm(x, p["ln1"].astype(x.dtype), cfg.norm_eps)
     q, k, v = _attn_qkv(p, h, cfg, stamp)
     q = apply_rope_heads(q, positions, cfg, nh, hd)
@@ -587,19 +642,20 @@ def attn_block(
                 attn = paged_decode_attention(new_entry, q, length,
                                               paged["hi_table"],
                                               paged["lo_table"],
-                                              pcfg.block_size)
+                                              pcfg.block_size, scale=scale)
         else:
             with jax.named_scope("attn.fallback"):
                 segs = PKV.gather_segments(new_entry, paged["hi_table"],
                                            paged["lo_table"], pcfg, x.dtype)
-                attn = L.decode_attention_segments(q, segs, length=length)
+                attn = L.decode_attention_segments(q, segs, length=length,
+                                                   scale=scale)
     elif mode == "decode":
         assert cache_entry is not None
         new_entry = KV.write_token(cache_entry, k, v, pos_scalar, kv_cfg)
         length = jnp.asarray(pos_scalar).reshape(-1) + 1
         if kv_cfg.quantized and kw_fused(kv_cfg):
             from repro.kernels.cache_attention import cache_decode_attention
-            attn = cache_decode_attention(new_entry, q, length)
+            attn = cache_decode_attention(new_entry, q, length, scale=scale)
         elif kv_cfg.quantized:
             (k_hi, v_hi), (k_lo, v_lo) = KV.dequantize_segments(
                 new_entry, kv_cfg, x.dtype)
@@ -609,14 +665,15 @@ def attn_block(
                 v_lo = policy.constraint(v_lo, spec)
             hi_len = k_hi.shape[1]
             attn = L.decode_attention_segments(
-                q, [(k_hi, v_hi, 0), (k_lo, v_lo, hi_len)], length=length)
+                q, [(k_hi, v_hi, 0), (k_lo, v_lo, hi_len)], length=length,
+                scale=scale)
         else:
             kf, vf = KV.dequantize_full(new_entry, kv_cfg, x.dtype)
             if policy is not None:
                 spec = policy.decode_kv_spec(kf.shape[0])
                 kf = policy.constraint(kf, spec)
                 vf = policy.constraint(vf, spec)
-            attn = L.decode_attention(q, kf, vf, length=length)
+            attn = L.decode_attention(q, kf, vf, length=length, scale=scale)
     elif mode == "prefill" and paged is not None:
         # chunked prefill into the paged cache: write this chunk's K/V
         # through the block table, attend to the cached prefix + the raw
@@ -631,12 +688,13 @@ def attn_block(
         # so the two-call and unified engines run row-identical math
         segs = PKV.gather_segments(new_entry, paged["hi_table"],
                                    paged["lo_table"], pcfg, x.dtype)
-        attn = L.chunked_prefill_attention(q, segs, k, v, paged["start"])
+        attn = L.chunked_prefill_attention(q, segs, k, v, paged["start"],
+                                           scale=scale)
     else:
-        attn = L.flash_attention(q, k, v, causal=causal)
+        attn = L.flash_attention(q, k, v, causal=causal, scale=scale)
         if mode == "prefill":
             new_entry = KV.quantize_full(k, v, kv_cfg, capacity=cache_capacity)
-    x = _attn_out(p, attn, x, stamp)
+    x = _attn_out(p, attn, x, stamp, cfg)
 
     if enc_out is not None and "xwq" in p:   # cross-attention (enc-dec)
         hx = L.rms_norm(x, p["lnx"].astype(x.dtype), cfg.norm_eps)
@@ -644,11 +702,11 @@ def attn_block(
         if mode == "decode" and cache_entry is not None and "xk" in cache_entry:
             kx = cache_entry["xk"].astype(x.dtype)
             vx = cache_entry["xv"].astype(x.dtype)
-            ax = L.decode_attention(qx, kx, vx)
+            ax = L.decode_attention(qx, kx, vx, scale=scale)
         else:
             kx = _split_heads(_linear(enc_out, p["xwk"]), kvh, hd)
             vx = _split_heads(_linear(enc_out, p["xwv"]), kvh, hd)
-            ax = L.flash_attention(qx, kx, vx, causal=False)
+            ax = L.flash_attention(qx, kx, vx, causal=False, scale=scale)
             if mode == "prefill":
                 new_entry = dict(new_entry or {})
                 new_entry["xk"] = kx.astype(jnp.bfloat16)
@@ -669,6 +727,10 @@ def attn_block(
 
 def apply_rope_heads(flat: Array, positions: Array, cfg: ModelConfig,
                      nh: int, hd: int) -> Array:
+    """Split heads and rotate them; a NoPE model (``use_rope=False``)
+    only splits."""
+    if not cfg.use_rope:
+        return _split_heads(flat, nh, hd)
     return L.apply_rope(_split_heads(flat, nh, hd), positions, cfg.rope_theta)
 
 
@@ -724,19 +786,21 @@ def attn_block_unified(
                                      paged["pages"], paged["offsets"],
                                      paged["is_hi"], pcfg)
 
+    scale = cfg.attention_multiplier
     if paged_kernel(pcfg, cfg, _FUSED_CACHE_ATTENTION):
         from repro.kernels.paged_attention import paged_ragged_attention
         with jax.named_scope("attn.kernel"):
             attn_pf, attn_dec = paged_ragged_attention(
                 new_entry, q_pf, q_dec, k_pf, v_pf, paged["span_cached"],
-                paged["span_ht"], paged["span_lt"], pcfg.block_size)
+                paged["span_ht"], paged["span_lt"], pcfg.block_size,
+                scale=scale)
     else:
         with jax.named_scope("attn.fallback"):
             segs_dec = PKV.gather_segments(new_entry, paged["dec_ht"],
                                            paged["dec_lt"], pcfg,
                                            x_dec.dtype)
             attn_dec = L.decode_attention_segments(
-                q_dec, segs_dec, length=paged["dec_lengths"])
+                q_dec, segs_dec, length=paged["dec_lengths"], scale=scale)
             # chunk rows: ONE branch covers first and continuation chunks.
             # A first row's empty cached prefix (pf_start = 0) masks every
             # segment and the online-softmax merge correction underflows
@@ -748,10 +812,11 @@ def attn_block_unified(
             segs_pf = PKV.gather_segments(new_entry, paged["pf_ht"],
                                           paged["pf_lt"], pcfg, x_pf.dtype)
             attn_pf = L.chunked_prefill_attention(q_pf, segs_pf, k_pf,
-                                                  v_pf, paged["pf_start"])
+                                                  v_pf, paged["pf_start"],
+                                                  scale=scale)
 
-    return (_attn_out(p, attn_pf, x_pf, stamp),
-            _attn_out(p, attn_dec, x_dec, None)), new_entry
+    return (_attn_out(p, attn_pf, x_pf, stamp, cfg),
+            _attn_out(p, attn_dec, x_dec, None, cfg)), new_entry
 
 
 def _mamba_in(p: dict, x: Array, cfg: ModelConfig,
@@ -760,13 +825,14 @@ def _mamba_in(p: dict, x: Array, cfg: ModelConfig,
     unified paths so their dispatch rules cannot diverge)."""
     di, n = cfg.d_inner, cfg.ssm_state
     h = L.rms_norm(x, p["ln1"].astype(x.dtype), cfg.norm_eps)
-    if _use_fused(stamp, p["in_proj"]):
-        # single-output fused kernel on the pre-mixer projection
-        proj = L.stamp_fused_linear(h, p["in_proj"], None, stamp,
-                                    site="in_proj")
-    else:
-        proj = _linear(_maybe_stamp(h, stamp, site="in_proj"),
-                       p["in_proj"])
+    with jax.named_scope("stamp.in_proj"):
+        if _use_fused(stamp, p["in_proj"]):
+            # single-output fused kernel on the pre-mixer projection
+            proj = L.stamp_fused_linear(h, p["in_proj"], None, stamp,
+                                        site="in_proj")
+        else:
+            proj = _linear(_maybe_stamp(h, stamp, site="in_proj"),
+                           p["in_proj"])
     z, xbc, dt_raw = jnp.split(proj, [di, 2 * di + 2 * n], axis=-1)
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + p["dt_bias"])
     return z, xbc, dt
@@ -781,11 +847,14 @@ def _mamba_out(p: dict, yh: Array, z: Array, x: Array, cfg: ModelConfig,
     # decode always passes stamp=None, so _use_fused is False there — the
     # same contract that keeps the in_proj dispatch above off the
     # sequence-transform kernel during decode
-    if _use_fused(stamp, p["out_proj"]):
-        return x + L.stamp_fused_linear(y, p["out_proj"], None, stamp,
-                                        site="out_proj")
-    y = _maybe_stamp(y, stamp, site="out_proj") if not decode else y
-    return x + _linear(y, p["out_proj"])
+    with jax.named_scope("stamp.out_proj"):
+        if _use_fused(stamp, p["out_proj"]):
+            y = L.stamp_fused_linear(y, p["out_proj"], None, stamp,
+                                     site="out_proj")
+        else:
+            y = _maybe_stamp(y, stamp, site="out_proj") if not decode else y
+            y = _linear(y, p["out_proj"])
+    return _residual(x, y, cfg)
 
 
 def _mamba_step(p: dict, xbc: Array, dt: Array, state: Array,
@@ -795,21 +864,26 @@ def _mamba_step(p: dict, xbc: Array, dt: Array, state: Array,
     ``state`` (b, h, p, n) f32, ``conv_cache`` (b, width-1, conv_dim).
     Returns (yh (b, 1, h, p) f32, new_state, new_conv)."""
     di, n, nh, pd = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads, cfg.ssm_head_dim
-    xp = jnp.concatenate([conv_cache.astype(dtype), xbc], axis=1)
-    w = p["conv_w"].astype(dtype)
-    y = sum(xp[:, i:i + 1] * w[i][None, None] for i in range(w.shape[0]))
-    xbc_c = jax.nn.silu(y)
-    new_conv = xp[:, 1:]
-    x_ssm, b_mat, c_mat = jnp.split(xbc_c, [di, di + n], axis=-1)
-    xh = x_ssm.reshape(*x_ssm.shape[:-1], nh, pd)
-    a = -jnp.exp(p["a_log"])
-    da = jnp.exp(dt[:, 0] * a[None])                          # (b, h)
-    upd = jnp.einsum("bhp,bn,bh->bhpn", xh[:, 0].astype(jnp.float32),
-                     b_mat[:, 0].astype(jnp.float32), dt[:, 0])
-    state = state * da[..., None, None] + upd
-    yh = jnp.einsum("bn,bhpn->bhp", c_mat[:, 0].astype(jnp.float32), state)
-    yh = yh[:, None] + p["d_skip"][None, None, :, None] * \
-        xh.astype(jnp.float32)
+    with jax.named_scope("ssm.conv"):
+        xp = jnp.concatenate([conv_cache.astype(dtype), xbc], axis=1)
+        w = p["conv_w"].astype(dtype)
+        y = sum(xp[:, i:i + 1] * w[i][None, None] for i in range(w.shape[0]))
+        if "conv_b" in p:
+            y = y + p["conv_b"].astype(dtype)
+        xbc_c = jax.nn.silu(y)
+        new_conv = xp[:, 1:]
+    with jax.named_scope("ssm.step"):
+        x_ssm, b_mat, c_mat = jnp.split(xbc_c, [di, di + n], axis=-1)
+        xh = x_ssm.reshape(*x_ssm.shape[:-1], nh, pd)
+        a = -jnp.exp(p["a_log"])
+        da = jnp.exp(dt[:, 0] * a[None])                      # (b, h)
+        upd = jnp.einsum("bhp,bn,bh->bhpn", xh[:, 0].astype(jnp.float32),
+                         b_mat[:, 0].astype(jnp.float32), dt[:, 0])
+        state = state * da[..., None, None] + upd
+        yh = jnp.einsum("bn,bhpn->bhp", c_mat[:, 0].astype(jnp.float32),
+                        state)
+        yh = yh[:, None] + p["d_skip"][None, None, :, None] * \
+            xh.astype(jnp.float32)
     return yh, state, new_conv
 
 
@@ -851,14 +925,17 @@ def _mamba_scan(p: dict, xbc: Array, dt: Array, cfg: ModelConfig, *,
     if lengths is not None:
         mask = jnp.arange(xbc.shape[1])[None, :] < lengths[:, None]
         dt = dt * mask[..., None].astype(dt.dtype)
-    xbc_c, conv_tail = L.causal_conv1d(xbc, p["conv_w"].astype(dtype),
-                                       cache=conv_cache, lengths=lengths)
-    x_ssm, b_mat, c_mat = jnp.split(xbc_c, [di, di + n], axis=-1)
-    xh = x_ssm.reshape(*x_ssm.shape[:-1], nh, pd)
-    yh, state = L.ssd_chunked(xh, dt, p["a_log"], b_mat, c_mat,
-                              init_state=init_state)
-    yh = yh.astype(jnp.float32) + p["d_skip"][None, None, :, None] * \
-        xh.astype(jnp.float32)
+    with jax.named_scope("ssm.conv"):
+        xbc_c, conv_tail = L.causal_conv1d(xbc, p["conv_w"].astype(dtype),
+                                           cache=conv_cache, lengths=lengths,
+                                           bias=p.get("conv_b"))
+    with jax.named_scope("ssm.scan"):
+        x_ssm, b_mat, c_mat = jnp.split(xbc_c, [di, di + n], axis=-1)
+        xh = x_ssm.reshape(*x_ssm.shape[:-1], nh, pd)
+        yh, state = L.ssd_chunked(xh, dt, p["a_log"], b_mat, c_mat,
+                                  init_state=init_state)
+        yh = yh.astype(jnp.float32) + p["d_skip"][None, None, :, None] * \
+            xh.astype(jnp.float32)
     return yh, state, conv_tail
 
 
@@ -881,11 +958,12 @@ def mamba_block(
             p, xbc, dt, state_all, conv_all, paged["dec_active"], cfg,
             x.dtype)
         s_slots = x.shape[0]
-        new_entry = {
-            "state": state_all.at[:s_slots].set(state_new),
-            "conv": conv_all.at[:s_slots].set(
-                conv_new.astype(conv_all.dtype)),
-        }
+        with jax.named_scope("ssm.state_write"):
+            new_entry = {
+                "state": state_all.at[:s_slots].set(state_new),
+                "conv": conv_all.at[:s_slots].set(
+                    conv_new.astype(conv_all.dtype)),
+            }
     elif mode == "decode":
         assert cache_entry is not None
         yh, state, new_conv = _mamba_step(p, xbc, dt, cache_entry["state"],
@@ -910,10 +988,12 @@ def mamba_block(
         yh, state_f, conv_tail = _mamba_scan(
             p, xbc, dt, cfg, conv_cache=conv0, init_state=state0,
             lengths=jnp.reshape(valid, (1,)), dtype=x.dtype)
-        new_entry = {
-            "state": state_all.at[slot].set(state_f[0]),
-            "conv": conv_all.at[slot].set(conv_tail[0].astype(conv_all.dtype)),
-        }
+        with jax.named_scope("ssm.state_write"):
+            new_entry = {
+                "state": state_all.at[slot].set(state_f[0]),
+                "conv": conv_all.at[slot].set(
+                    conv_tail[0].astype(conv_all.dtype)),
+            }
     else:
         yh, state, conv_tail = _mamba_scan(
             p, xbc, dt, cfg, conv_cache=None, init_state=None,
@@ -962,10 +1042,11 @@ def mamba_block_unified(
         p, xbc_dec, dt_dec, state_all, conv_all, paged["dec_active"], cfg,
         x_dec.dtype)
 
-    st = state_all.at[:s_slots].set(state_new)
-    st = st.at[pf_slots].set(state_f)
-    cv = conv_all.at[:s_slots].set(conv_new.astype(conv_all.dtype))
-    cv = cv.at[pf_slots].set(conv_tail.astype(conv_all.dtype))
+    with jax.named_scope("ssm.state_write"):
+        st = state_all.at[:s_slots].set(state_new)
+        st = st.at[pf_slots].set(state_f)
+        cv = conv_all.at[:s_slots].set(conv_new.astype(conv_all.dtype))
+        cv = cv.at[pf_slots].set(conv_tail.astype(conv_all.dtype))
     new_entry = {"state": st, "conv": cv}
 
     return (_mamba_out(p, yh_pf, z_pf, x_pf, cfg, stamp, decode=False),
@@ -974,7 +1055,14 @@ def mamba_block_unified(
 
 
 def ffn_block(p: dict, x: Array, spec: LayerSpec, cfg: ModelConfig, *,
-              stamp: Optional[StampConfig]) -> Array:
+              stamp: Optional[StampConfig],
+              valid: Optional[Array] = None, train: bool = False) -> Array:
+    """The layer's FFN on the normed input, added to the residual.
+    An MoE layer routes dropless when serving and with GShard capacity
+    (`L.moe_ffn`, static expert buckets) when ``train``.  ``valid``
+    (broadcastable to ``x``'s token axes) marks the real tokens: the
+    dropless MoE routes only those (pads and idle slots take no expert
+    rows)."""
     if spec.ffn == "none":
         return x
     h = L.rms_norm(x, p["ln2"].astype(x.dtype), cfg.norm_eps)
@@ -986,24 +1074,17 @@ def ffn_block(p: dict, x: Array, spec: LayerSpec, cfg: ModelConfig, *,
     if spec.ffn in ("moe", "moe_dense"):
         gate_w = (p["gate_w"] if not isinstance(p["gate_w"], dict)
                   else _dequant_packed(p["gate_w"], jnp.float32))
-        # both paths see the SAME stamped round trip (routing on it keeps
-        # kept/dropped token sets bit-identical fused vs reference)
+        # the fused and reference experts see the SAME stamped round trip
+        # (routing on it keeps the chosen experts bit-identical)
         hq = _maybe_stamp(h, stamp, site="moe")
-        if (_use_fused(stamp, p["we_gate"]) and _use_fused(stamp, p["we_up"])
-                and _use_fused(stamp, p["we_down"])):
-            # grouped kernel path: quantize each token once, dispatch int8
-            # codes, run the gate/up/down expert stack in ONE Pallas call
-            out = out + L.moe_ffn_fused(
-                hq, gate_w, p["we_gate"], p["we_up"], p["we_down"],
+        if train:
+            out = out + L.moe_ffn(
+                hq, gate_w, *(_expert_w(p[k], x.dtype)
+                              for k in ("we_gate", "we_up", "we_down")),
                 cfg.experts_per_token, cfg.capacity_factor,
-                group_size=cfg.moe_group_size)
+                group_size=cfg.moe_group_size, offset=cfg.expert_offset)
         else:
-            we_gate = _expert_w(p["we_gate"], x.dtype)
-            we_up = _expert_w(p["we_up"], x.dtype)
-            we_down = _expert_w(p["we_down"], x.dtype)
-            out = out + L.moe_ffn(hq, gate_w, we_gate, we_up, we_down,
-                                  cfg.experts_per_token, cfg.capacity_factor,
-                                  group_size=cfg.moe_group_size)
+            out = out + _moe_dropless(p, hq, gate_w, cfg, valid)
     if spec.ffn in ("mlp", "moe_dense"):
         prefix = "d" if spec.ffn == "moe_dense" else ""
         wg, wu = p[f"{prefix}wi_gate"], p[f"{prefix}wi_up"]
@@ -1026,7 +1107,30 @@ def ffn_block(p: dict, x: Array, spec: LayerSpec, cfg: ModelConfig, *,
                 y = _linear(_maybe_stamp(g, stamp, site="wo_mlp"),
                             p[f"{prefix}wo_mlp"])
         out = out + y
-    return x + out
+    return _residual(x, out, cfg)
+
+
+def _moe_dropless(p: dict, hq: Array, gate_w: Array, cfg: ModelConfig,
+                  valid: Optional[Array]) -> Array:
+    """The dropless MoE over this chip's experts on the (stamped) normed
+    tokens ``hq`` (..., d): prepared int8 experts through the grouped
+    kernel (`layers.moe_ffn_dropless_fused`) in prefill and decode alike,
+    float experts through the dense loop.  Records the layer's counts."""
+    lead, d = hq.shape[:-1], hq.shape[-1]
+    xt = hq.reshape(-1, d)
+    ok = jnp.ones(lead, bool) if valid is None else \
+        jnp.broadcast_to(valid, lead)
+    ws = [p["we_gate"], p["we_up"], p["we_down"]]
+    if all(isinstance(w, dict) and "iq" in w for w in ws):
+        y, counts = L.moe_ffn_dropless_fused(
+            xt, gate_w, *ws, cfg.experts_per_token, ok.reshape(-1),
+            offset=cfg.expert_offset)
+    else:
+        y, counts = L.moe_ffn_dropless(
+            xt, gate_w, *(_expert_w(w, hq.dtype) for w in ws),
+            cfg.experts_per_token, ok.reshape(-1), offset=cfg.expert_offset)
+    _MOE_TALLY.append(counts)
+    return y.reshape(hq.shape).astype(hq.dtype)
 
 
 def _expert_w(w, dtype):
@@ -1056,14 +1160,20 @@ def apply_block(spec: LayerSpec, p: dict, x: Array, cfg: ModelConfig, **kw
                                               cache_entry=kw["cache_entry"],
                                               paged=kw["paged"])
         elif spec.mixer == "mamba":
-            x, entry = mamba_block_unified(p, x, cfg, stamp=stamp,
-                                           cache_entry=kw["cache_entry"],
-                                           paged=kw["paged"])
+            with jax.named_scope("mamba"):
+                x, entry = mamba_block_unified(p, x, cfg, stamp=stamp,
+                                               cache_entry=kw["cache_entry"],
+                                               paged=kw["paged"])
         else:
             entry = None
+        paged = kw["paged"]
+        c_len = x[0].shape[1]
         with jax.named_scope("mlp"):
-            x_pf = ffn_block(p, x[0], spec, cfg, stamp=stamp)
-            x_dec = ffn_block(p, x[1], spec, cfg, stamp=None)
+            x_pf = ffn_block(p, x[0], spec, cfg, stamp=stamp,
+                             valid=jnp.arange(c_len)[None, :]
+                             < paged["pf_valid"][:, None])
+            x_dec = ffn_block(p, x[1], spec, cfg, stamp=None,
+                              valid=paged["dec_active"][:, None])
         return (x_pf, x_dec), entry
     if spec.mixer == "attn":
         with jax.named_scope("attn"):
@@ -1078,16 +1188,34 @@ def apply_block(spec: LayerSpec, p: dict, x: Array, cfg: ModelConfig, **kw
                                   cache_capacity=kw.get("cache_capacity"),
                                   paged=kw.get("paged"))
     elif spec.mixer == "mamba":
-        x, entry = mamba_block(p, x, cfg, mode=kw["mode"],
-                               policy=kw.get("policy"), stamp=stamp,
-                               cache_entry=kw.get("cache_entry"),
-                               paged=kw.get("paged"),
-                               seq_lengths=kw.get("seq_lengths"))
+        with jax.named_scope("mamba"):
+            x, entry = mamba_block(p, x, cfg, mode=kw["mode"],
+                                   policy=kw.get("policy"), stamp=stamp,
+                                   cache_entry=kw.get("cache_entry"),
+                                   paged=kw.get("paged"),
+                                   seq_lengths=kw.get("seq_lengths"))
     else:
         entry = None
     with jax.named_scope("mlp"):
-        x = ffn_block(p, x, spec, cfg, stamp=stamp)
+        x = ffn_block(p, x, spec, cfg, stamp=stamp,
+                      valid=_valid_tokens(x, kw),
+                      train=kw["mode"] == "train")
     return x, entry
+
+
+def _valid_tokens(x: Array, kw: dict) -> Optional[Array]:
+    """Real-token mask of a (b, s, d) block input outside the unified
+    step: active decode slots, a prefill chunk's valid prefix, or each
+    row's ``seq_lengths``; None where every token is real."""
+    paged, mode = kw.get("paged"), kw["mode"]
+    pos = jnp.arange(x.shape[1])[None, :]
+    if paged is not None and mode == "decode":
+        return paged["dec_active"][:, None]
+    if paged is not None and mode == "prefill":
+        return pos < paged["valid"]
+    if kw.get("seq_lengths") is not None:
+        return pos < kw["seq_lengths"][:, None]
+    return None
 
 
 # ---------------------------------------------------------------------------
@@ -1118,6 +1246,7 @@ def run_stack(
               causal=causal, cache_capacity=cache_capacity, paged=paged,
               seq_lengths=seq_lengths)
 
+    _MOE_TALLY.clear()
     new_pro_cache = {}
     for i, spec in enumerate(pro):
         entry = None if cache is None else cache.get(f"pro{i}")
@@ -1169,6 +1298,7 @@ def run_stack(
     # nper×.  The body drains its own records and returns them as extra
     # scan outputs; absorb() reduces the stacked period axis back out.
     pro_telem = QS.drain()
+    pro_tally = _drain_tally()
 
     def body(xc, xs):
         p_slice, c_slice = xs
@@ -1180,16 +1310,17 @@ def run_stack(
             if ne is not None:
                 new_entries[str(j)] = ne
         xc = constrain(xc, policy, lambda pol: pol.acts())
-        return xc, (new_entries, QS.drain())
+        return xc, (new_entries, QS.drain(), _drain_tally())
 
     if mode == "train" and remat:
         body = jax.checkpoint(body,
                               policy=jax.checkpoint_policies.nothing_saveable)
 
     xs = (params["period"], cache_per)
-    x, (period_cache, period_telem) = jax.lax.scan(body, x, xs)
+    x, (period_cache, period_telem, period_tally) = jax.lax.scan(body, x, xs)
     QS.absorb(period_telem)
     QS.merge_flat(pro_telem)
+    _MOE_TALLY.append(pro_tally + period_tally.sum(0))
     new_cache = None
     if mode in ("prefill", "decode", "unified"):
         new_cache = dict(period_cache)
@@ -1202,7 +1333,8 @@ def run_stack(
 # ---------------------------------------------------------------------------
 
 
-def chunked_xent(x: Array, head, labels: Array, chunk: int = 512) -> Array:
+def chunked_xent(x: Array, head, labels: Array, chunk: int = 512,
+                 logits_scaling: float = 1.0) -> Array:
     """Cross-entropy without materializing (b, s, vocab): scan over sequence
     chunks (each chunk's logits live only inside the scan body).  Labels < 0
     are ignored (VLM patch positions)."""
@@ -1217,7 +1349,7 @@ def chunked_xent(x: Array, head, labels: Array, chunk: int = 512) -> Array:
                        policy=jax.checkpoint_policies.nothing_saveable)
     def body(tot, inp):
         xc, lc = inp
-        logits = _linear(xc, head).astype(jnp.float32)
+        logits = _scale_logits(_linear(xc, head), logits_scaling)
         logz = jax.nn.logsumexp(logits, axis=-1)
         gold = jnp.take_along_axis(
             logits, jnp.maximum(lc, 0)[..., None], axis=-1)[..., 0]
@@ -1231,14 +1363,31 @@ def chunked_xent(x: Array, head, labels: Array, chunk: int = 512) -> Array:
     return loss / jnp.maximum(cnt, 1.0)
 
 
-def _embed(params, tokens: Array, dtype=jnp.bfloat16) -> Array:
-    return jnp.take(params["embed"], tokens, axis=0).astype(dtype)
+def _embed(params, tokens: Array, dtype=jnp.bfloat16,
+           scale: float = 1.0) -> Array:
+    """Embedding rows in ``dtype``, times the model's embedding
+    multiplier (Granite's 12) where it is not 1."""
+    x = jnp.take(params["embed"], tokens, axis=0).astype(dtype)
+    return x if scale == 1.0 else x * scale
 
 
 def _head_weight(params):
     if "head" in params:
         return params["head"]
     return params["embed"].T
+
+
+def _scale_logits(logits: Array, scaling: float) -> Array:
+    """Float32 logits divided by the model's logits scaling (Granite's
+    16) where it is not 1."""
+    logits = logits.astype(jnp.float32)
+    return logits if scaling == 1.0 else logits / scaling
+
+
+def _head_logits(x: Array, params, cfg: ModelConfig) -> Array:
+    """Float32 logits of normed hidden rows ``x``."""
+    return _scale_logits(_linear(x, _head_weight(params)),
+                         cfg.logits_scaling)
 
 
 def _encoder_forward(params, frames: Array, cfg: ModelConfig,
@@ -1271,7 +1420,10 @@ def model_hidden(params, batch: dict, cfg: ModelConfig, *,
                  cache_capacity: Optional[int] = None,
                  seq_lengths: Optional[Array] = None
                  ) -> tuple[Array, Optional[dict], Array]:
-    """Shared train/prefill forward.  Returns (hidden, cache, labels)."""
+    """Shared train/prefill forward.  Returns (hidden, cache, labels).
+    ``mode``: "train" (capacity-routed MoE, remat), "prefill" (fills the
+    cache), or "forward" — the serving layers over whole sequences with
+    no cache (calibration)."""
     # non-decode entry: clear the process-global decode-matmul flag so a
     # previous fused decode can't divert a length-1 forward off the STaMP
     # transform path (see set_fused_decode_matmul)
@@ -1282,13 +1434,16 @@ def model_hidden(params, batch: dict, cfg: ModelConfig, *,
     if cfg.frontend == "frames" or cfg.encoder_layers:
         enc_out = _encoder_forward(params, batch["frames"].astype(compute_dtype),
                                    cfg, policy, mode)
-        x = _embed(params, batch["tokens"], compute_dtype)
+        x = _embed(params, batch["tokens"], compute_dtype,
+                   cfg.embedding_multiplier)
     elif cfg.frontend == "patch":
-        tok = _embed(params, batch["tokens"], compute_dtype)
+        tok = _embed(params, batch["tokens"], compute_dtype,
+                     cfg.embedding_multiplier)
         x = jnp.concatenate([batch["patches"].astype(compute_dtype), tok],
                             axis=1)
     else:
-        x = _embed(params, batch["tokens"], compute_dtype)
+        x = _embed(params, batch["tokens"], compute_dtype,
+                   cfg.embedding_multiplier)
     x = constrain(x, policy, lambda pol: pol.acts())
     positions = jnp.arange(x.shape[1])[None, :]
     x, cache = run_stack(params, x, cfg, mode=mode, positions=positions,
@@ -1305,7 +1460,8 @@ def train_loss(params, batch: dict, cfg: ModelConfig,
                remat: bool = True) -> Array:
     x, _, labels = model_hidden(params, batch, cfg, mode="train",
                                 policy=policy, remat=remat)
-    return chunked_xent(x, _head_weight(params), labels)
+    return chunked_xent(x, _head_weight(params), labels,
+                        logits_scaling=cfg.logits_scaling)
 
 
 def prefill(params, batch: dict, cfg: ModelConfig,
@@ -1339,10 +1495,10 @@ def prefill(params, batch: dict, cfg: ModelConfig,
         x_last = x[:, -1:]
     else:
         x_last = jnp.take_along_axis(x, last_pos[:, None, None], axis=1)
-    logits = _linear(x_last, _head_weight(params))[:, 0]
+    logits = _head_logits(x_last, params, cfg)[:, 0]
     if collect:
-        return logits.astype(jnp.float32), cache, telem
-    return logits.astype(jnp.float32), cache
+        return logits, cache, telem
+    return logits, cache
 
 
 def decode_step(params, cache: dict, tokens: Array, pos: Array,
@@ -1355,7 +1511,8 @@ def decode_step(params, cache: dict, tokens: Array, pos: Array,
     set_fused_cache_attention(serve.fused_cache_attention)
     set_fused_decode_matmul(serve.fused_decode_matmul)
     compute_dtype = jnp.bfloat16
-    x = _embed(params, tokens[:, None], compute_dtype)
+    x = _embed(params, tokens[:, None], compute_dtype,
+               cfg.embedding_multiplier)
     pos = jnp.asarray(pos, jnp.int32)
     positions = pos[:, None] if pos.ndim == 1 else \
         jnp.full((1, 1), pos, jnp.int32)
@@ -1364,8 +1521,7 @@ def decode_step(params, cache: dict, tokens: Array, pos: Array,
                              stamp=None, kv_cfg=serve.kv, cache=cache,
                              pos_scalar=pos)
     x = L.rms_norm(x, params["final_norm"].astype(x.dtype), cfg.norm_eps)
-    logits = _linear(x[:, 0], _head_weight(params))
-    return logits.astype(jnp.float32), new_cache
+    return _head_logits(x[:, 0], params, cfg), new_cache
 
 
 def init_cache(cfg: ModelConfig, batch: int, seq: int,
@@ -1462,8 +1618,11 @@ def paged_prefill_chunk(params, pools: dict, tokens: Array, start: Array,
                         cfg: ModelConfig, serve: ServeConfig,
                         first: bool, slot: Optional[Array] = None,
                         policy: Optional[ShardingPolicy] = None
-                        ) -> tuple[Array, dict]:
-    """One prefill chunk of one request into the paged cache.
+                        ) -> tuple:
+    """One prefill chunk of one request into the paged cache.  Returns
+    ``(logits (1, V), new_pools[, telemetry], moe_counts)``; the last is
+    the (4,) int32 MoE counts (`layers.MOE_COUNTS`) summed over the
+    layers, zeros for a model without experts.
 
     **Two-call parity path**: the unified engine runs prefill and decode
     through one `paged_unified_step` program; this entry (and
@@ -1498,7 +1657,7 @@ def paged_prefill_chunk(params, pools: dict, tokens: Array, start: Array,
     # the (transform-free) decode matmul
     set_fused_decode_matmul(False)
     compute_dtype = jnp.bfloat16
-    x = _embed(params, tokens, compute_dtype)
+    x = _embed(params, tokens, compute_dtype, cfg.embedding_multiplier)
     x = constrain(x, policy, lambda pol: pol.acts())
     c = tokens.shape[1]
     positions = (start + jnp.arange(c))[None, :]
@@ -1519,12 +1678,13 @@ def paged_prefill_chunk(params, pools: dict, tokens: Array, start: Array,
                                  cache=pools, paged=paged, remat=False)
     finally:
         telem = QS.end() if collect else None
+    tally = _drain_tally()
     x = L.rms_norm(x, params["final_norm"].astype(x.dtype), cfg.norm_eps)
     x_last = jnp.take_along_axis(x, last_index[None, None, None], axis=1)
-    logits = _linear(x_last, _head_weight(params))[:, 0]
+    out = (_head_logits(x_last, params, cfg)[:, 0], new_pools)
     if collect:
-        return logits.astype(jnp.float32), new_pools, telem
-    return logits.astype(jnp.float32), new_pools
+        out += (telem,)
+    return out + (tally,)
 
 
 def paged_unified_step(params, pools: dict, pf_tokens: Array,
@@ -1535,7 +1695,7 @@ def paged_unified_step(params, pools: dict, pf_tokens: Array,
                        lo_table: Array, pages: Array, offsets: Array,
                        is_hi: Array, cfg: ModelConfig, serve: ServeConfig,
                        policy: Optional[ShardingPolicy] = None
-                       ) -> tuple[Array, Array, dict]:
+                       ) -> tuple:
     """ONE device program per engine step: every planned prefill chunk and
     the whole decode slot array run as a single ragged batch.
 
@@ -1578,7 +1738,8 @@ def paged_unified_step(params, pools: dict, pf_tokens: Array,
     ``pages / offsets / is_hi``: (n_pf·C + S,) write targets for the
     flattened token stream (pads and inactive slots → null page).
 
-    Returns ``(pf_logits (n_pf, V), dec_logits (S, V), new_pools)``.
+    Returns ``(pf_logits (n_pf, V), dec_logits (S, V), new_pools[,
+    telemetry], moe_counts)``, the counts as in `paged_prefill_chunk`.
     """
     n_pf, c_len = pf_tokens.shape
     collect = _collect_telemetry(serve)
@@ -1586,13 +1747,14 @@ def paged_unified_step(params, pools: dict, pf_tokens: Array,
         # all-decode fast case: decode runs transform-free (stamp=None),
         # so there is nothing to record — but the return arity must match
         # the collecting branch
-        dec_logits, new_pools = paged_decode_step(
+        dec_logits, new_pools, tally = paged_decode_step(
             params, pools, dec_tokens, dec_positions, hi_table, lo_table,
             pages, offsets, is_hi, cfg, serve, dec_active, policy)
         pf_logits = jnp.zeros((0, dec_logits.shape[-1]), jnp.float32)
+        out = (pf_logits, dec_logits, new_pools)
         if collect:
-            return pf_logits, dec_logits, new_pools, {}
-        return pf_logits, dec_logits, new_pools
+            out += ({},)
+        return out + (tally,)
     assert policy is None, "unified step is single-device for now"
     set_fused_cache_attention(serve.fused_cache_attention)
     # both regions live in ONE trace, so the decode-matmul dispatch relies
@@ -1605,8 +1767,10 @@ def paged_unified_step(params, pools: dict, pf_tokens: Array,
     # span-major from the start: embedding is per-token, so the (n_pf, C,
     # d) per-span view of the flattened batch is built directly
     with jax.named_scope("embed"):
-        x_pf = _embed(params, pf_tokens, compute_dtype)
-        x_dec = _embed(params, dec_tokens[:, None], compute_dtype)
+        x_pf = _embed(params, pf_tokens, compute_dtype,
+                      cfg.embedding_multiplier)
+        x_dec = _embed(params, dec_tokens[:, None], compute_dtype,
+                       cfg.embedding_multiplier)
     pos_pf = pf_start[:, None] + jnp.arange(c_len)[None, :]
     paged = {"cfg": serve.paged,
              "span_ht": hi_table, "span_lt": lo_table,
@@ -1632,20 +1796,21 @@ def paged_unified_step(params, pools: dict, pf_tokens: Array,
                                  paged=paged, remat=False)
     finally:
         telem = QS.end() if collect else None
+    tally = _drain_tally()
     x_pf, x_dec = x
-    head = _head_weight(params)
     with jax.named_scope("head"):
         x_pf = L.rms_norm(x_pf, params["final_norm"].astype(x_pf.dtype),
                           cfg.norm_eps)
         x_last = jnp.take_along_axis(x_pf, pf_last_index[:, None, None],
                                      axis=1)
-        pf_logits = _linear(x_last, head)[:, 0].astype(jnp.float32)
+        pf_logits = _head_logits(x_last, params, cfg)[:, 0]
         x_dec = L.rms_norm(x_dec, params["final_norm"].astype(x_dec.dtype),
                            cfg.norm_eps)
-        dec_logits = _linear(x_dec[:, 0], head).astype(jnp.float32)
+        dec_logits = _head_logits(x_dec[:, 0], params, cfg)
+    out = (pf_logits, dec_logits, new_pools)
     if collect:
-        return pf_logits, dec_logits, new_pools, telem
-    return pf_logits, dec_logits, new_pools
+        out += (telem,)
+    return out + (tally,)
 
 
 def paged_decode_step(params, pools: dict, tokens: Array, positions: Array,
@@ -1654,8 +1819,10 @@ def paged_decode_step(params, pools: dict, tokens: Array, positions: Array,
                       cfg: ModelConfig, serve: ServeConfig,
                       active: Optional[Array] = None,
                       policy: Optional[ShardingPolicy] = None
-                      ) -> tuple[Array, dict]:
+                      ) -> tuple[Array, dict, Array]:
     """One decode step for the whole slot array against the paged cache.
+    Returns ``(logits (S, V), new_pools, moe_counts)``, the counts as in
+    `paged_prefill_chunk`.
 
     **Two-call parity path** (see `paged_prefill_chunk`) — and the graph
     the unified step delegates to for its all-decode fast case (n_pf = 0),
@@ -1676,7 +1843,8 @@ def paged_decode_step(params, pools: dict, tokens: Array, positions: Array,
     set_fused_decode_matmul(serve.fused_decode_matmul)
     compute_dtype = jnp.bfloat16
     with jax.named_scope("embed"):
-        x = _embed(params, tokens[:, None], compute_dtype)
+        x = _embed(params, tokens[:, None], compute_dtype,
+                   cfg.embedding_multiplier)
     if active is None:
         active = jnp.ones(tokens.shape, bool)
     paged = {"cfg": serve.paged, "hi_table": hi_table, "lo_table": lo_table,
@@ -1686,7 +1854,8 @@ def paged_decode_step(params, pools: dict, tokens: Array, positions: Array,
                              positions=positions[:, None], policy=policy,
                              stamp=None, kv_cfg=serve.kv, cache=pools,
                              pos_scalar=positions, paged=paged)
+    tally = _drain_tally()
     with jax.named_scope("head"):
         x = L.rms_norm(x, params["final_norm"].astype(x.dtype), cfg.norm_eps)
-        logits = _linear(x[:, 0], _head_weight(params)).astype(jnp.float32)
-    return logits, new_pools
+        logits = _head_logits(x[:, 0], params, cfg)
+    return logits, new_pools, tally

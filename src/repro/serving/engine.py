@@ -156,7 +156,12 @@ class _EngineBase:
                  "watchdog_trips", "stalled_steps", "swap_corruptions",
                  "prefix_cache_queries", "prefix_cache_hits",
                  "prefix_tokens_reused", "cow_copies",
-                 "attn_pages_walked", "attn_pages_reserved")
+                 "attn_pages_walked", "attn_pages_reserved",
+                 # dropless MoE, summed over layer calls: token × choice
+                 # pairs routed over every expert, those landing on this
+                 # chip's experts, held experts with rows, rows lost (0)
+                 "moe_rows_routed", "moe_rows_held", "moe_expert_groups",
+                 "moe_rows_dropped")
 
     def __init__(self, params, cfg: ModelConfig, serve: lm.ServeConfig,
                  clock: Optional[Callable[[], float]] = None,
@@ -308,10 +313,10 @@ class _EngineBase:
                             clip_rate=s["clip_rate"], threshold=thresh)
 
     def _absorb_router_stats(self, router: dict) -> None:
-        """Publish the MoE router's load counters (recorded by `moe_route`
+        """Publish the MoE router's load counters (recorded by the router
         under the ``moe_router`` pseudo-site): per-expert load-balance
         gauges, the cumulative dropped-token counter, and the step's
-        capacity occupancy / drop rate."""
+        occupancy of the expert rows computed / drop rate."""
         expert_tokens = np.asarray(router.get("expert_tokens", []),
                                    np.float64).reshape(-1)
         dropped = float(np.asarray(router.get("dropped_tokens", 0.0)))
@@ -323,12 +328,12 @@ class _EngineBase:
                      "(last step, summed over layers)").set(float(n))
         self.metrics.counter(
             "moe_dropped_tokens",
-            help="MoE router: cumulative capacity-dropped tokens").inc(
+            help="MoE router: cumulative dropped tokens").inc(
             dropped)
         routed = float(expert_tokens.sum())
         self.metrics.gauge(
             "moe_capacity_occupancy",
-            help="MoE router: kept tokens / capacity slots (last step)"
+            help="MoE router: routed rows / row slots (last step)"
         ).set(routed / slots if slots > 0 else 0.0)
         total = routed + dropped
         self.metrics.gauge(
@@ -1051,6 +1056,14 @@ class PagedServingEngine(_EngineBase):
         self._inc("attn_pages_walked", walked)
         self._inc("attn_pages_reserved", reserved)
 
+    def _count_moe(self, counts: np.ndarray) -> None:
+        """Add one step's MoE counts (`layers.MOE_COUNTS` order, summed
+        over the step's layer calls; zeros without experts) to the engine
+        counters."""
+        from repro.models.layers import MOE_COUNTS
+        for name, n in zip(MOE_COUNTS, counts.tolist()):
+            self._inc(name, int(n))
+
     def _tables(self, sreqs: List[SchedRequest]) -> tuple:
         ht, lt = self._tables_np(sreqs)
         return jnp.asarray(ht), jnp.asarray(lt)
@@ -1187,7 +1200,7 @@ class PagedServingEngine(_EngineBase):
                     args)
                 self._inc("recompiles")
             with self._timer.phase("launch"):
-                out = self._unified(*args)
+                *out, moe = self._unified(*args)
             if self._collect:
                 pf_logits, dec_logits, self.pools, telem = out
             else:
@@ -1198,8 +1211,10 @@ class PagedServingEngine(_EngineBase):
             with self._timer.phase("wait"):
                 jax.block_until_ready((pf_logits, dec_logits))
             with self._timer.phase("fetch_logits"):
-                pf_logits = np.asarray(pf_logits)
-                dec_logits = np.asarray(dec_logits)
+                # the MoE counts ride the logits' copy to the host
+                pf_logits, dec_logits, moe = jax.device_get(
+                    (pf_logits, dec_logits, moe))
+                self._count_moe(moe)
         if telem is not None:
             self._absorb_telemetry(telem)
 
@@ -1265,7 +1280,7 @@ class PagedServingEngine(_EngineBase):
         fn = self._prefill_first if start == 0 else self._prefill_cont
         telem = None
         with self._timer.phase("dispatch"):
-            out = fn(
+            *out, moe = fn(
                 self.params, self.pools, jnp.asarray(chunk),
                 jnp.int32(start), ht, lt, jnp.asarray(pages),
                 jnp.asarray(offs), jnp.asarray(ishi),
@@ -1275,7 +1290,9 @@ class PagedServingEngine(_EngineBase):
             else:
                 logits, self.pools = out
             self._inc("device_dispatches")
-            logits = np.asarray(logits)
+            # the MoE counts ride the logits' copy to the host
+            logits, moe = jax.device_get((logits, moe))
+            self._count_moe(moe)
         if telem is not None:
             self._absorb_telemetry(telem)
         with self._timer.phase("post"):
@@ -1313,12 +1330,13 @@ class PagedServingEngine(_EngineBase):
                     self._write_target(sreq, sreq.pos)
         ht, lt = self._tables(running)
         with self._timer.phase("dispatch"):
-            logits, self.pools = self._decode(
+            logits, self.pools, moe = self._decode(
                 self.params, self.pools, jnp.asarray(tokens),
                 jnp.asarray(positions), ht, lt, jnp.asarray(pages),
                 jnp.asarray(offs), jnp.asarray(ishi), jnp.asarray(active))
             self._inc("device_dispatches")
-            logits = np.asarray(logits)
+            logits, moe = jax.device_get((logits, moe))
+            self._count_moe(moe)
         with self._timer.phase("post"):
             self._event("decode",
                         uids=tuple(sorted(r.uid for r in running)))

@@ -167,7 +167,7 @@ class TestMaskedDecode:
                 block_size=16, num_lo_blocks=4, num_hi_blocks=1,
                 max_blocks_per_seq=2,
                 quant=KV.KVCacheConfig(quantized=False)))
-        _, new_pools = lm.paged_decode_step(
+        _, new_pools, _ = lm.paged_decode_step(
             params, pools, z, z, ht, lt, z, z,
             jnp.zeros((s,), bool), SCFG, serve,
             active=jnp.asarray(active))

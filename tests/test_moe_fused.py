@@ -1,11 +1,12 @@
-"""Fused grouped-MoE path: `stamp_quant_grouped_matmul` kernel vs the
-unfused oracle (occupancy masking, empty buckets, capacity padding),
-`moe_ffn_fused` vs the reference `moe_ffn` (bit-identical routing, odd
-sequence lengths, capacity overflow, pad-tail groups), the call-counter
-proof that fused MoE prefill issues zero reference expert einsums, the
-router-stats telemetry ride-along, expert-parallel sharding of the
-prepared int8 buffers, and the single-branch chunk-attention regression
-(the XLA fallback must not evaluate flash AND chunked per row)."""
+"""Fused grouped-MoE path: the grouped expert kernel
+`stamp_quant_ragged_matmul` vs the unfused oracle (ragged groups, empty
+groups and tiles, rows past a tile's count), the dropless
+`moe_ffn_dropless_fused` vs the dense loop `moe_ffn_dropless` (odd token
+counts, a share of the experts, pad tokens), the call-counter proof that
+fused MoE prefill issues zero dense-loop expert einsums, the router-stats
+telemetry ride-along, expert-parallel sharding of the prepared int8
+buffers, and the single-branch chunk-attention regression (the XLA
+fallback must not evaluate flash AND chunked per row)."""
 
 import dataclasses
 
@@ -21,6 +22,7 @@ from jax.sharding import PartitionSpec as P
 from repro.core.stamp import (StampConfig, prepare_linear, stamp_fake_quant,
                               token_quantize)
 from repro.kernels import ops, ref
+from repro.kernels import stamp_matmul as SM
 from repro.models import layers as L
 from repro.models import lm
 from repro.obs import quantstats as QS
@@ -47,128 +49,146 @@ def make_expert_weight(e, k, n, seed=0):
     return p.qw, p.sw, p.zw, w
 
 
-def make_dispatch(b, e, cap, d, counts, seed=0):
-    """Quantized capacity buckets with the first ``counts[i, eg]`` rows
-    occupied (the contiguous-prefix layout `moe_route` guarantees)."""
-    x = rand((b, e, cap, d), seed=seed)
-    qx, sx, zx = token_quantize(x.reshape(b, e * cap, d))
-    return (qx.reshape(b, e, cap, d), sx.reshape(b, e, cap, 1),
-            zx.reshape(b, e, cap, 1), jnp.asarray(counts, jnp.int32))
+def make_groups(counts, bm, d, extra_tiles=0, seed=0):
+    """Row codes grouped by expert (``counts[e]`` rows from a
+    ``bm``-aligned offset, as `moe_route_dropless` lays them out) plus
+    ``extra_tiles`` empty tail tiles; returns the tile table, the codes
+    with their per-row scale / shifted zp, and the live-row mask."""
+    rows, expert = [], []
+    for e, c in enumerate(counts):
+        for t in range(-(-c // bm)):
+            rows.append(min(bm, c - t * bm))
+            expert.append(e)
+    rows += [0] * extra_tiles
+    expert += [expert[-1]] * extra_tiles
+    rows = jnp.asarray(rows, jnp.int32)
+    table = SM.tile_table(rows, jnp.asarray(expert, jnp.int32))
+    r = rows.shape[0] * bm
+    qx, sx, zx = token_quantize(rand((1, r, d), seed=seed))
+    live = (np.arange(r) % bm) < np.repeat(np.asarray(rows), bm)
+    return table, qx[0], sx[0], zx[0], live
 
 
 class TestGroupedKernel:
     """Pallas kernel (interpret mode) vs the pure-jnp oracle."""
 
     CASES = [
-        # b, e, cap, d, f, counts, block_c, block_f
-        (1, 4, 8, 32, 64, [[8, 5, 0, 8]], 8, 32),      # empty bucket
-        (2, 2, 16, 32, 64, [[16, 3], [0, 16]], 8, 64),
-        (1, 4, 10, 32, 96, [[10, 7, 1, 0]], 8, 96),    # C pads 10 -> 16
-        (1, 2, 8, 64, 128, [[8, 8]], 128, 512),        # bc clamps to cap
+        # counts per expert, block_m, d, f, block_f, empty tail tiles
+        ([8, 5, 0, 8], 8, 32, 64, 32, 1),      # empty group + empty tile
+        ([16, 3], 8, 32, 64, 64, 0),           # an expert over two tiles
+        ([10, 7, 1, 0], 8, 32, 96, 96, 2),     # partial tiles
+        ([8, 8], 8, 64, 128, 512, 0),          # block_f clamps to f
     ]
 
-    @pytest.mark.parametrize("b,e,cap,d,f,counts,bc,bf", CASES)
-    def test_matches_oracle(self, b, e, cap, d, f, counts, bc, bf):
-        qx, sx, zx, cnt = make_dispatch(b, e, cap, d, counts, seed=1)
+    @pytest.mark.parametrize("counts,bm,d,f,bf,extra", CASES)
+    def test_matches_oracle(self, counts, bm, d, f, bf, extra):
+        e = len(counts)
+        table, qx, sx, zx, live = make_groups(counts, bm, d, extra, seed=1)
         qg, sg, zg, _ = make_expert_weight(e, d, f, seed=2)
         qu, su, zu, _ = make_expert_weight(e, d, f, seed=3)
         qd, sd, zd, _ = make_expert_weight(e, f, d, seed=4)
-        args = (qx, sx, zx, cnt, qg, sg, zg, qu, su, zu, qd, sd, zd)
-        y = ops.stamp_quant_grouped_matmul(*args, block_c=bc, block_f=bf,
-                                           interpret=True)
-        yr = ref.stamp_quant_grouped_matmul_ref(*args, block_f=bf)
-        assert y.shape == (b, e, cap, d)
+        args = (table, qx, sx, zx, qg, sg, zg, qu, su, zu, qd, sd, zd)
+        y = ops.stamp_quant_ragged_matmul(*args, block_m=bm, block_f=bf,
+                                          interpret=True)
+        yr = ref.stamp_quant_ragged_matmul_ref(*args, block_m=bm,
+                                               block_f=bf)
+        assert y.shape == (qx.shape[0], d)
         # the oracle derives the silu-mul requantize codes from an f32
         # einsum while the kernel uses exact int32 GEMMs — .5-boundary
-        # code flips bound the gap, not kernel indexing
-        assert rel_err(y, yr) < 2e-3
+        # code flips bound the gap, not kernel indexing; rows of empty
+        # tiles are undefined
+        assert rel_err(np.asarray(y)[live], np.asarray(yr)[live]) < 2e-3
 
     def test_rows_past_count_exactly_zero(self):
-        qx, sx, zx, cnt = make_dispatch(1, 4, 8, 32, [[8, 5, 0, 2]], seed=5)
+        table, qx, sx, zx, live = make_groups([8, 5, 0, 2], 8, 32, seed=5)
         qg, sg, zg, _ = make_expert_weight(4, 32, 64, seed=6)
         qu, su, zu, _ = make_expert_weight(4, 32, 64, seed=7)
         qd, sd, zd, _ = make_expert_weight(4, 64, 32, seed=8)
-        y = ops.stamp_quant_grouped_matmul(
-            qx, sx, zx, cnt, qg, sg, zg, qu, su, zu, qd, sd, zd,
-            block_c=8, block_f=32, interpret=True)
-        slot = np.arange(8)[None, None, :]
-        empty = slot >= np.asarray(cnt)[:, :, None]
-        assert np.all(np.asarray(y)[empty] == 0.0)
-        assert np.all(np.asarray(y)[~empty] != 0.0)
+        y = np.asarray(ops.stamp_quant_ragged_matmul(
+            table, qx, sx, zx, qg, sg, zg, qu, su, zu, qd, sd, zd,
+            block_m=8, block_f=32, interpret=True))
+        assert np.all(y[~live] == 0.0)
+        assert np.all(y[live] != 0.0)
 
     def test_registered_in_contract_checker(self):
         """Satellite: the capture registry proves KC001–KC005 on the
-        concrete occupancy prefetch table (incl. an empty bucket)."""
+        concrete tile table (incl. an empty tile)."""
         from repro.kernels.specs import KERNEL_EXAMPLES, kernel_spec
         assert "stamp_matmul.grouped" in KERNEL_EXAMPLES
         ex = kernel_spec("stamp_matmul.grouped")
         cap = ex.captures[0]
         assert cap.num_scalar_prefetch == 1
         table = cap.prefetch[0]
-        assert 0 in table          # the checker sees the empty-bucket clamp
+        assert 0 in table          # the checker sees an empty tile
 
 
 class TestFusedMoEParity:
-    """`moe_ffn_fused` vs the reference `moe_ffn` running the SAME
-    prepared-int8 expert weights (dequantized for the reference) — the gap
-    is the token quantize + in-kernel requantize only."""
+    """`moe_ffn_dropless_fused` (grouped int8 kernel over ragged row
+    groups) vs the dense loop `moe_ffn_dropless` running the SAME
+    prepared-int8 expert weights (dequantized for the loop) — the gap is
+    the token quantize + in-kernel requantize only."""
 
-    def _setup(self, bsz, seq, d, f, e, seed=0):
-        x = rand((bsz, seq, d), seed=seed)
+    def _setup(self, t, d, f, e, held, seed=0):
+        x = rand((t, d), seed=seed)
         gate_w = rand((d, e), seed=seed + 1)
         prep, deq = {}, {}
         for name, (k, n, s) in {"g": (d, f, 2), "u": (d, f, 3),
                                 "d": (f, d, 4)}.items():
-            qw, sw, zw, _ = make_expert_weight(e, k, n, seed=seed + s)
+            qw, sw, zw, _ = make_expert_weight(held, k, n, seed=seed + s)
             prep[name] = {"iq": qw, "isw": sw, "izw": zw}
             deq[name] = (qw.astype(jnp.float32) - zw) * sw
         return x, gate_w, prep, deq
 
+    def _pair(self, x, gate_w, prep, deq, k, offset, valid=None):
+        valid = jnp.ones(x.shape[:1], bool) if valid is None else valid
+        y_ref, c_ref = L.moe_ffn_dropless(x, gate_w, deq["g"], deq["u"],
+                                          deq["d"], k, valid, offset=offset)
+        y_fused, c_fused = L.moe_ffn_dropless_fused(
+            x, gate_w, prep["g"], prep["u"], prep["d"], k, valid,
+            offset=offset)
+        np.testing.assert_array_equal(np.asarray(c_fused),
+                                      np.asarray(c_ref))
+        return y_ref, y_fused
+
     CASES = [
-        # bsz, seq, d, f, e, k, cf, group_size
-        (2, 37, 32, 64, 4, 2, 1.25, 16),    # odd seq, pad-tail group
-        (1, 64, 32, 64, 4, 2, 1.0, 64),
-        (2, 33, 32, 64, 8, 2, 2.0, 32),     # ample capacity
-        (1, 48, 64, 128, 4, 1, 1.25, 48),   # top-1
+        # t, d, f, e, k, held, offset
+        (74, 32, 64, 4, 2, 4, 0),       # odd token count, tail tile
+        (64, 32, 64, 4, 2, 2, 1),       # a share: experts 1-2 of 4
+        (66, 32, 64, 8, 2, 8, 0),       # many small groups
+        (48, 64, 128, 4, 1, 4, 0),      # top-1
     ]
 
-    @pytest.mark.parametrize("bsz,seq,d,f,e,k,cf,gs", CASES)
-    def test_fused_matches_reference(self, bsz, seq, d, f, e, k, cf, gs):
-        x, gate_w, prep, deq = self._setup(bsz, seq, d, f, e, seed=10)
-        y_ref = L.moe_ffn(x, gate_w, deq["g"], deq["u"], deq["d"],
-                          k, cf, group_size=gs)
-        y_fused = L.moe_ffn_fused(x, gate_w, prep["g"], prep["u"],
-                                  prep["d"], k, cf, group_size=gs)
-        assert y_fused.shape == y_ref.shape
+    @pytest.mark.parametrize("t,d,f,e,k,held,offset", CASES)
+    def test_fused_matches_reference(self, t, d, f, e, k, held, offset):
+        x, gate_w, prep, deq = self._setup(t, d, f, e, held, seed=10)
+        y_ref, y_fused = self._pair(x, gate_w, prep, deq, k, offset)
+        assert y_fused.shape == y_ref.shape == (t, d)
         assert rel_err(y_fused, y_ref) < 0.06
 
-    def test_capacity_overflow_drops_identically(self):
-        """Forced overflow (cf = 0.5): dropped tokens produce exact-zero
-        rows in BOTH paths, and the dropped sets are identical — routing
-        is bit-identical by construction (shared `moe_route`)."""
-        x, gate_w, prep, deq = self._setup(2, 32, 32, 64, 4, seed=20)
-        y_ref = L.moe_ffn(x, gate_w, deq["g"], deq["u"], deq["d"],
-                          2, 0.5, group_size=16)
-        y_fused = L.moe_ffn_fused(x, gate_w, prep["g"], prep["u"],
-                                  prep["d"], 2, 0.5, group_size=16)
-        zero_ref = np.all(np.asarray(y_ref) == 0.0, axis=-1)
-        zero_fused = np.all(np.asarray(y_fused) == 0.0, axis=-1)
-        assert zero_ref.sum() > 0, "workload never overflowed capacity"
-        np.testing.assert_array_equal(zero_ref, zero_fused)
-        kept = ~zero_ref
-        assert rel_err(np.asarray(y_fused)[kept],
-                       np.asarray(y_ref)[kept]) < 0.06
+    def test_pad_tokens_route_nowhere(self):
+        """Tokens masked invalid (pads, idle slots) take no expert row:
+        exact-zero outputs in BOTH paths, no count, and the real tokens'
+        outputs are those of the unmasked call."""
+        x, gate_w, prep, deq = self._setup(32, 32, 64, 4, 4, seed=20)
+        valid = jnp.arange(32) % 3 != 0
+        y_ref, y_fused = self._pair(x, gate_w, prep, deq, 2, 0, valid)
+        pad = ~np.asarray(valid)
+        assert np.all(np.asarray(y_ref)[pad] == 0.0)
+        assert np.all(np.asarray(y_fused)[pad] == 0.0)
+        _, c = L.moe_ffn_dropless(x, gate_w, deq["g"], deq["u"], deq["d"],
+                                  2, valid, offset=0)
+        assert int(c[0]) == 2 * int(valid.sum())
+        _, y_all = self._pair(x, gate_w, prep, deq, 2, 0)
+        assert rel_err(np.asarray(y_fused)[~pad],
+                       np.asarray(y_all)[~pad]) < 1e-6
 
     def test_num_hi_exceeds_seq(self):
         """The stamped round trip ahead of routing with num_hi >= seq:
         every token re-codes at hi_bits, both paths consume the same hq."""
-        x, gate_w, prep, deq = self._setup(1, 24, 32, 64, 4, seed=30)
+        x, gate_w, prep, deq = self._setup(24, 32, 64, 4, 4, seed=30)
         st = StampConfig(num_hi_tokens=512)
-        hq = stamp_fake_quant(x, st, site=None)
-        y_ref = L.moe_ffn(hq, gate_w, deq["g"], deq["u"], deq["d"],
-                          2, 1.25, group_size=24)
-        y_fused = L.moe_ffn_fused(hq, gate_w, prep["g"], prep["u"],
-                                  prep["d"], 2, 1.25, group_size=24)
+        hq = stamp_fake_quant(x[None], st, site=None)[0]
+        y_ref, y_fused = self._pair(hq, gate_w, prep, deq, 2, 0)
         assert rel_err(y_fused, y_ref) < 0.06
 
     def test_route_occupancy_is_contiguous_prefix(self):
@@ -185,7 +205,7 @@ class TestFusedMoEParity:
 
 
 class TestFusedMoEWiring:
-    """End-to-end: fused MoE prefill issues ZERO reference expert einsums
+    """End-to-end: fused MoE prefill issues ZERO dense-loop expert einsums
     and exactly one grouped-kernel call per traced MoE layer."""
 
     CFG = lm.ModelConfig(name="moe-count-test", family="moe", num_layers=2,
@@ -199,16 +219,17 @@ class TestFusedMoEWiring:
         stf = StampConfig(num_hi_tokens=8, execution="fused")
         pf = lm.prepare_fused_weights(params, stf)
         counts = {"grouped": 0}
-        real = kops.stamp_quant_grouped_matmul
+        real = kops.stamp_quant_ragged_matmul
 
         def grouped(*a, **k):
             counts["grouped"] += 1
             return real(*a, **k)
 
         def boom(*a, **k):
-            raise AssertionError("reference moe_ffn expert einsums ran")
+            raise AssertionError("dense-loop expert einsums ran")
 
-        monkeypatch.setattr(kops, "stamp_quant_grouped_matmul", grouped)
+        monkeypatch.setattr(kops, "stamp_quant_ragged_matmul", grouped)
+        monkeypatch.setattr(L, "moe_ffn_dropless", boom)
         monkeypatch.setattr(L, "moe_ffn", boom)
         toks = jnp.asarray(
             np.random.default_rng(1).integers(0, 128, (1, 48)), jnp.int32)
@@ -243,7 +264,7 @@ class TestFusedMoEWiring:
         stf = StampConfig(num_hi_tokens=8, execution="fused")
         m = lm.fused_site_matrix(self.CFG, stf)
         assert m["moe"]["status"] == "fused"
-        assert m["moe"]["kernel"] == "stamp_quant_grouped_matmul"
+        assert m["moe"]["kernel"] == "stamp_quant_ragged_matmul"
         assert m["moe"]["reasons"] == []
         # disabled stamp still demotes the cell with a reason (EL001)
         m_off = lm.fused_site_matrix(self.CFG, None)

@@ -167,9 +167,22 @@ def test_scopes_change_no_op(params, prompts, monkeypatch):
         eng.run()
         return eng.step_program().as_text()
 
-    scoped = compiled()
-    monkeypatch.setattr(jax, "named_scope",
-                        lambda name: contextlib.nullcontext())
-    plain = compiled()
+    # an earlier test in this process may have turned JAX's persistent
+    # compile cache on (the serving entry points do), and it keys a
+    # program without its scope metadata: the second compile would load
+    # the first one's text.  The cache settles whether it is in use once,
+    # so it is reset around the switch.
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    cache_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        scoped = compiled()
+        monkeypatch.setattr(jax, "named_scope",
+                            lambda name: contextlib.nullcontext())
+        plain = compiled()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_on)
+        cc.reset_cache()
     assert "stamp.qkv" in scoped and "stamp.qkv" not in plain
     assert _ops(scoped) == _ops(plain)

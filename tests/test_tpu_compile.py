@@ -1,6 +1,7 @@
 """Ahead-of-time compiles of the serving path's Pallas kernels for a
-described TPU v5e: the STaMP linears at Llama-3-8B widths, the paged
-attention at the benchmark cell's cache (Mistral-NeMo-12B) and at
+described TPU v5e: the STaMP linears at Llama-3-8B widths and at
+Granite-4.0-H-Small's Mamba projections, its grouped expert kernel, the
+paged attention at the benchmark cell's cache (Mistral-NeMo-12B) and at
 DeepSeek-7B's multi-head widths.
 
 Nothing runs: each test lowers one kernel for a chip that is described, not
@@ -103,6 +104,42 @@ def test_decode_matmul(one_chip, k, n):
         ((DECODE_ROWS, k), jnp.bfloat16), *_weights(k, n))
     assert "tpu_custom_call" in txt
 
+
+# Granite-4.0-H-Small (configs/granite_4_0_h_small.py): its Mamba-2
+# in_proj (4096 -> z | x | B | C | dt = 16768) and out_proj (8192 -> 4096)
+# through the single STaMP kernel, and the grouped expert kernel over the
+# ragged row tiles of its 18 held SwiGLU experts (4096 -> 768 -> 4096): 98
+# tiles of 32 rows for a prefill region of two 128-token chunk rows, 21
+# for the 8 decode slots, with an expert's whole width in one f tile
+G_D, G_IN, G_DI, G_F, G_HELD, G_BM = 4096, 16768, 8192, 768, 18, 32
+
+
+@pytest.mark.parametrize("k,n", [(G_D, G_IN), (G_DI, G_D)],
+                         ids=["in_proj", "out_proj"])
+def test_granite_mamba_projection(one_chip, k, n):
+    txt = _compile(
+        one_chip,
+        lambda x, *w: SM.stamp_quant_matmul_pallas(x, *w, **STAMP),
+        ((2, CHUNK, k), jnp.bfloat16), *_weights(k, n))
+    assert "tpu_custom_call" in txt
+
+
+def _expert_stack(k, n):
+    return [((G_HELD, k, n), jnp.int8)] + [((G_HELD, 1, n), jnp.float32)] * 2
+
+
+@pytest.mark.parametrize("tiles", [98, 21], ids=["prefill", "decode"])
+def test_grouped_expert_kernel(one_chip, tiles):
+    rows = tiles * G_BM
+    txt = _compile(
+        one_chip,
+        lambda t, x, sx, zx, *w: SM.stamp_quant_ragged_matmul_pallas(
+            t, x, sx, zx, *w, block_m=G_BM, block_f=G_F, interpret=False),
+        ((3 * tiles,), jnp.int32), ((rows, G_D), jnp.int8),
+        ((rows, 1), jnp.float32), ((rows, 1), jnp.float32),
+        *_expert_stack(G_D, G_F), *_expert_stack(G_D, G_F),
+        *_expert_stack(G_F, G_D))
+    assert "moe_grouped_matmul" in txt
 
 
 # the paged KV cache of mistral-nemo-12b-l20.long_prompt: pages of 4 tokens,
