@@ -48,7 +48,7 @@ def _block_bytes(buf) -> int:
     shape = buf.block_shape if buf.block_shape is not None else buf.shape
     n = 1
     for d in shape:
-        n *= int(d)
+        n *= int(d) if d is not None else 1     # None: a squeezed dim
     return n * _itemsize(buf.dtype)
 
 

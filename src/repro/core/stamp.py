@@ -183,20 +183,29 @@ def _blockwise_mixed(tx: Array, bits: Array, block_size: int) -> Array:
 
 
 @functools.partial(jax.tree_util.register_dataclass,
-                   data_fields=("qw", "sw", "zw", "bias"), meta_fields=())
+                   data_fields=("qw", "sw", "zw", "bias", "layer"),
+                   meta_fields=())
 @dataclasses.dataclass(frozen=True)
 class PreparedLinear:
     """Deployment weight buffers for the fused path: signed-int8 codes plus
     per-output-channel affine params, quantized **once** at preparation time
-    instead of re-materializing bf16 weights on every call."""
+    instead of re-materializing bf16 weights on every call.  With a
+    ``layer`` index the buffers are the layer scan's stacks and the kernels
+    read that layer in place."""
 
     qw: Array               # (din, dout) int8, codes shifted by -2^(b-1)
     sw: Array               # (1, dout) f32 scale
     zw: Array               # (1, dout) f32 zero point (same shift applied)
     bias: Optional[Array]   # (dout,) or None
+    layer: Optional[Array] = None   # () int32 index into (L, ·, ·) stacks
 
     def dequant(self, dtype=jnp.bfloat16) -> Array:
-        return ((self.qw.astype(jnp.float32) - self.zw) * self.sw).astype(dtype)
+        qw, sw, zw = self.qw, self.sw, self.zw
+        if self.layer is not None:
+            qw, sw, zw = (jax.lax.dynamic_index_in_dim(a, self.layer, 0,
+                                                       keepdims=False)
+                          for a in (qw, sw, zw))
+        return ((qw.astype(jnp.float32) - zw) * sw).astype(dtype)
 
 
 def prepare_linear(
@@ -312,7 +321,7 @@ def _fused_linear(x: Array, prep: PreparedLinear, cfg: StampConfig,
         *lead, s, d = x.shape
         xk = x.reshape(-1, s, d)
     y = kops.stamp_quant_matmul(
-        xk, prep.qw, prep.sw, prep.zw, prep.bias,
+        xk, prep.qw, prep.sw, prep.zw, prep.bias, layer=prep.layer,
         transform=cfg.seq_transform, levels=cfg.resolved_levels(s),
         skip_first=cfg.skip_first_token, num_hi=cfg.num_hi_tokens,
         hi_bits=cfg.hi_bits, lo_bits=cfg.lo_bits, out_dtype=x.dtype)
@@ -484,7 +493,7 @@ def stamp_dual_linear(
             prep_g.qw, prep_g.sw, prep_g.zw,
             prep_u.qw, prep_u.sw, prep_u.zw,
             prep_g.bias if b_gate is None else b_gate,
-            prep_u.bias if b_up is None else b_up,
+            prep_u.bias if b_up is None else b_up, layer=prep_g.layer,
             transform=cfg.seq_transform, levels=cfg.resolved_levels(s),
             skip_first=cfg.skip_first_token, num_hi=cfg.num_hi_tokens,
             hi_bits=cfg.hi_bits, lo_bits=cfg.lo_bits, epilogue=epilogue,

@@ -39,7 +39,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels.stamp_matmul import COMPILER_PARAMS
+from repro.kernels.stamp_matmul import (COMPILER_PARAMS, layer_grid,
+                                        weight_specs)
 
 
 def _kernel(x_ref, qw_ref, sw_ref, zw_ref, b_ref, o_ref,
@@ -79,38 +80,42 @@ def stamp_decode_matmul_pallas(
     bias: jax.Array,         # (1, N) f32
     *,
     block_n: int = 512,
+    layer: jax.Array | None = None,
     out_dtype=None,
     interpret: bool | None = None,
 ) -> jax.Array:
-    """Fused decode linear: ``Q8(x) · Wq_deq + bias`` in one kernel."""
+    """Fused decode linear: ``Q8(x) · Wq_deq + bias`` in one kernel.
+    ``qw``/``sw``/``zw`` may be ``(L, K, N)``/``(L, 1, N)`` stacks read in
+    place at ``layer`` (`stamp_matmul.weight_specs`)."""
     if interpret is None:
         from repro.kernels.ops import default_interpret
         interpret = default_interpret()
     b, k = x.shape
-    k2, n = qw.shape
+    k2, n = qw.shape[-2:]
     if k != k2:
         raise ValueError(f"activation K={k} does not match weight K={k2}")
     bn = min(block_n, n)
     while n % bn:
         bn //= 2
-    kernel = functools.partial(_kernel, k_total=k)
-    return pl.pallas_call(
-        kernel,
+    w_spec, c_spec = weight_specs(qw, layer, bn)
+    kernel, grid_kw, lead = layer_grid(
+        functools.partial(_kernel, k_total=k), layer,
         grid=(n // bn,),
         in_specs=[
-            pl.BlockSpec((b, k), lambda j: (0, 0)),
-            pl.BlockSpec((k, bn), lambda j: (0, j)),
-            pl.BlockSpec((1, bn), lambda j: (0, j)),
-            pl.BlockSpec((1, bn), lambda j: (0, j)),
-            pl.BlockSpec((1, bn), lambda j: (0, j)),
+            pl.BlockSpec((b, k), lambda j, *_: (0, 0)),
+            w_spec, c_spec, c_spec,
+            pl.BlockSpec((1, bn), lambda j, *_: (0, j)),
         ],
-        out_specs=pl.BlockSpec((b, bn), lambda j: (0, j)),
-        out_shape=jax.ShapeDtypeStruct((b, n), out_dtype or x.dtype),
+        out_specs=pl.BlockSpec((b, bn), lambda j, *_: (0, j)),
         scratch_shapes=[
             pltpu.VMEM((b, k), jnp.int8),      # quantized token codes
             pltpu.VMEM((b, 1), jnp.float32),   # per-token scale
             pltpu.VMEM((b, 1), jnp.float32),   # per-token (shifted) zp
-        ],
+        ])
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((b, n), out_dtype or x.dtype),
         compiler_params=COMPILER_PARAMS,
         interpret=interpret,
-    )(x, qw, sw, zw, bias)
+        **grid_kw,
+    )(*lead, x, qw, sw, zw, bias)

@@ -201,6 +201,37 @@ def _ex_stamp_dual():
         (x, qg, sg, zg, bg, qu, su, zu, bu), dict(num_hi=4)
 
 
+def _stacked(r, k, n, layers=3):
+    """A prepared weight as the layer scan hands it to a kernel: (layers,
+    k, n) codes and (layers, 1, n) scale / zero point, with the last layer
+    as the index (the checker proves the stack's index maps in bounds)."""
+    qw = r.integers(-128, 128, size=(layers, k, n), dtype=np.int8)
+    sw = r.uniform(1e-3, 1e-2, size=(layers, 1, n)).astype(np.float32)
+    zw = r.integers(-8, 8, size=(layers, 1, n)).astype(np.float32)
+    return qw, sw, zw, np.int32(layers - 1)
+
+
+def _ex_stamp_single_stacked():
+    from repro.kernels.stamp_matmul import stamp_quant_matmul_pallas
+    r = _rng()
+    x = r.standard_normal((2, 16, 32)).astype(np.float32)
+    qw, sw, zw, layer = _stacked(r, 32, 256)
+    bias = r.standard_normal((1, 256)).astype(np.float32)
+    return stamp_quant_matmul_pallas, (x, qw, sw, zw, bias), \
+        dict(num_hi=4, layer=layer)
+
+
+def _ex_stamp_dual_stacked():
+    from repro.kernels.stamp_matmul import stamp_quant_dual_matmul_pallas
+    r = _rng()
+    x = r.standard_normal((2, 16, 32)).astype(np.float32)
+    qg, sg, zg, layer = _stacked(r, 32, 256)
+    qu, su, zu, _ = _stacked(r, 32, 256)
+    bias = r.standard_normal((1, 256)).astype(np.float32)
+    return stamp_quant_dual_matmul_pallas, \
+        (x, qg, sg, zg, bias, qu, su, zu, bias), dict(num_hi=4, layer=layer)
+
+
 def _ex_stamp_segment():
     from repro.kernels.stamp_matmul import stamp_quant_segment_matmul_pallas
     r = _rng()
@@ -241,12 +272,29 @@ def _ex_stamp_grouped():
         dict(block_m=bm, block_f=32)
 
 
+def _ex_stamp_grouped_stacked():
+    fn, (table, qx, sx, zx, *w), kw = _ex_stamp_grouped()
+    # the same experts as three layers of a stack, read at the last one
+    w = [np.stack([a] * 3) for a in w]
+    return fn, (table, qx, sx, zx, *w), dict(kw, layer=np.int32(2))
+
+
 def _ex_decode_matmul():
     from repro.kernels.decode_matmul import stamp_decode_matmul_pallas
     r = _rng()
     x = r.standard_normal((4, 32)).astype(np.float32)
     qw, sw, zw, bias = _prepared_weight(r, 32, 512)
     return stamp_decode_matmul_pallas, (x, qw, sw, zw, bias), {}
+
+
+def _ex_decode_matmul_stacked():
+    from repro.kernels.decode_matmul import stamp_decode_matmul_pallas
+    r = _rng()
+    x = r.standard_normal((4, 32)).astype(np.float32)
+    qw, sw, zw, layer = _stacked(r, 32, 512)
+    bias = r.standard_normal((1, 512)).astype(np.float32)
+    return stamp_decode_matmul_pallas, (x, qw, sw, zw, bias), \
+        dict(layer=layer)
 
 
 def _ex_int8_matmul():
@@ -382,7 +430,11 @@ KERNEL_EXAMPLES: dict = {
     "stamp_matmul.dual": _ex_stamp_dual,
     "stamp_matmul.segment": _ex_stamp_segment,
     "stamp_matmul.grouped": _ex_stamp_grouped,
+    "stamp_matmul.single_stacked": _ex_stamp_single_stacked,
+    "stamp_matmul.dual_stacked": _ex_stamp_dual_stacked,
+    "stamp_matmul.grouped_stacked": _ex_stamp_grouped_stacked,
     "decode_matmul": _ex_decode_matmul,
+    "decode_matmul.stacked": _ex_decode_matmul_stacked,
     "int8_matmul": _ex_int8_matmul,
     "haar_dwt": _ex_haar_dwt,
     "wht.seq": _ex_wht_seq,

@@ -56,6 +56,13 @@ Three call-site variants share that structure:
   whole SwiGLU front half costs one activation read and one write.
 * `decode_matmul.stamp_decode_matmul_pallas` (sibling module) — the
   transform-free single-token variant for decode.
+
+Every variant also takes its weights as the layer scan's stacks: codes
+``(L, K, N)`` and scale / zero point ``(L, 1, N)`` with a ``layer`` index.
+The index is a scalar-prefetch operand that the weight block index maps
+read, so the kernel streams one layer's blocks straight out of the stack
+and no per-layer copy of the codes is ever written to HBM.  A rank-2
+weight (unstacked callers) takes the plain grid.
 """
 
 from __future__ import annotations
@@ -228,15 +235,48 @@ def _x_spec(x: jax.Array) -> tuple[pl.BlockSpec, int, int, int]:
     consumes the attention output without a merged HBM intermediate."""
     if x.ndim == 4:
         b, s, nh, hd = x.shape
-        return pl.BlockSpec((1, s, nh, hd), lambda i, j: (i, 0, 0, 0)), \
+        return pl.BlockSpec((1, s, nh, hd), lambda i, j, *_: (i, 0, 0, 0)), \
             b, s, nh * hd
     b, s, k = x.shape
-    return pl.BlockSpec((1, s, k), lambda i, j: (i, 0, 0)), b, s, k
+    return pl.BlockSpec((1, s, k), lambda i, j, *_: (i, 0, 0)), b, s, k
 
 
 def _m_spec(s: int) -> pl.BlockSpec:
     # constant block index: each transform matrix is fetched once per call
-    return pl.BlockSpec((s, s), lambda i, j: (0, 0))
+    return pl.BlockSpec((s, s), lambda i, j, *_: (0, 0))
+
+
+def weight_specs(qw: jax.Array, layer, bn: int
+                 ) -> tuple[pl.BlockSpec, pl.BlockSpec]:
+    """BlockSpecs of a weight's ``(K, bn)`` code block and ``(1, bn)``
+    scale / zero-point block at output block ``j`` (the grid's last
+    index).  A ``(L, K, N)`` stack with its ``layer`` index reads layer
+    ``l[0]``'s blocks in place (the index is the call's scalar-prefetch
+    operand); a ``(K, N)`` weight is read as it is."""
+    if (qw.ndim == 3) != (layer is not None):
+        raise ValueError(f"a {qw.ndim}-D weight with layer={layer!r}: a "
+                         f"stacked weight needs its layer index, and only "
+                         f"a stacked weight takes one")
+    k = qw.shape[-2]
+    if layer is None:
+        return (pl.BlockSpec((k, bn), lambda *g: (0, g[-1])),
+                pl.BlockSpec((1, bn), lambda *g: (0, g[-1])))
+    return (pl.BlockSpec((None, k, bn), lambda *g: (g[-1][0], 0, g[-2])),
+            pl.BlockSpec((None, 1, bn), lambda *g: (g[-1][0], 0, g[-2])))
+
+
+def layer_grid(kernel, layer, **grid_kw) -> tuple:
+    """``(kernel, pallas_call keywords, leading operands)`` for a grid of
+    ``grid_kw`` (``grid``, ``in_specs``, ``out_specs``, ``scratch_shapes``)
+    whose weights `weight_specs` placed.  With a ``layer`` index that index
+    is the one scalar-prefetch operand: only the index maps read it, so the
+    kernel body takes the same refs either way."""
+    if layer is None:
+        return kernel, grid_kw, ()
+    return ((lambda l_ref, *refs: kernel(*refs)),
+            {"grid_spec": pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, **grid_kw)},
+            (jnp.reshape(layer, (1,)).astype(jnp.int32),))
 
 
 def stamp_quant_matmul_pallas(
@@ -253,10 +293,13 @@ def stamp_quant_matmul_pallas(
     hi_bits: int = 8,
     lo_bits: int = 4,
     block_n: int = 256,
+    layer: jax.Array | None = None,
     out_dtype=None,
     interpret: bool | None = None,
 ) -> jax.Array:
-    """Fused STaMP linear: ``L⁻¹(Q(L·x) · Wq_deq) + bias`` in one kernel."""
+    """Fused STaMP linear: ``L⁻¹(Q(L·x) · Wq_deq) + bias`` in one kernel.
+    ``qw``/``sw``/``zw`` may be ``(L, K, N)``/``(L, 1, N)`` stacks read in
+    place at ``layer`` (see `weight_specs`)."""
     if interpret is None:
         from repro.kernels.ops import default_interpret
         interpret = default_interpret()
@@ -264,34 +307,34 @@ def stamp_quant_matmul_pallas(
         raise ValueError(f"transform {transform!r} is not fusable "
                          f"(expected one of {FUSABLE_TRANSFORMS})")
     x_spec, b, s, k = _x_spec(x)
-    k2, n = qw.shape
+    k2, n = qw.shape[-2:]
     if k != k2:
         raise ValueError(f"activation K={k} does not match weight K={k2}")
     bn = _pick_block_n(block_n, n)
     mats = _seq_matrices(transform, s, levels, skip_first)
-    kernel = functools.partial(
-        _stamp_kernel, has_mats=bool(mats), num_hi=num_hi, hi_bits=hi_bits,
-        lo_bits=lo_bits, k_total=k)
-    return pl.pallas_call(
-        kernel,
+    w_spec, c_spec = weight_specs(qw, layer, bn)
+    kernel, grid_kw, lead = layer_grid(
+        functools.partial(_stamp_kernel, has_mats=bool(mats), num_hi=num_hi,
+                          hi_bits=hi_bits, lo_bits=lo_bits, k_total=k),
+        layer,
         grid=(b, n // bn),
         in_specs=[_m_spec(s)] * len(mats) + [
-            x_spec,
-            pl.BlockSpec((k, bn), lambda i, j: (0, j)),
-            pl.BlockSpec((1, bn), lambda i, j: (0, j)),
-            pl.BlockSpec((1, bn), lambda i, j: (0, j)),
-            pl.BlockSpec((1, bn), lambda i, j: (0, j)),
+            x_spec, w_spec, c_spec, c_spec,
+            pl.BlockSpec((1, bn), lambda i, j, *_: (0, j)),
         ],
-        out_specs=pl.BlockSpec((1, s, bn), lambda i, j: (i, 0, j)),
-        out_shape=jax.ShapeDtypeStruct((b, s, n), out_dtype or x.dtype),
+        out_specs=pl.BlockSpec((1, s, bn), lambda i, j, *_: (i, 0, j)),
         scratch_shapes=[
             pltpu.VMEM((s, k), jnp.int8),      # quantized activation codes
             pltpu.VMEM((s, 1), jnp.float32),   # per-token scale
             pltpu.VMEM((s, 1), jnp.float32),   # per-token (shifted) zp
-        ],
+        ])
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((b, s, n), out_dtype or x.dtype),
         compiler_params=COMPILER_PARAMS,
         interpret=interpret,
-    )(*mats, x, qw, sw, zw, bias)
+        **grid_kw,
+    )(*lead, *mats, x, qw, sw, zw, bias)
 
 
 def stamp_quant_dual_matmul_pallas(
@@ -313,13 +356,15 @@ def stamp_quant_dual_matmul_pallas(
     lo_bits: int = 4,
     block_n: int = 256,
     epilogue: str = "silu_mul",   # "silu_mul" | "none"
+    layer: jax.Array | None = None,
     out_dtype=None,
     interpret: bool | None = None,
 ):
     """Fused STaMP gate/up pair: ONE transform+quantize of the shared input
     drives both integer GEMMs.  ``epilogue="silu_mul"`` returns
     ``silu(L⁻¹(Q·Wg)+bg) · (L⁻¹(Q·Wu)+bu)`` as a single array;
-    ``epilogue="none"`` returns the ``(gate, up)`` tuple."""
+    ``epilogue="none"`` returns the ``(gate, up)`` tuple.  Both weight
+    sets may be stacks read in place at ``layer`` (see `weight_specs`)."""
     if interpret is None:
         from repro.kernels.ops import default_interpret
         interpret = default_interpret()
@@ -329,7 +374,7 @@ def stamp_quant_dual_matmul_pallas(
     if epilogue not in ("silu_mul", "none"):
         raise ValueError(f"unknown epilogue {epilogue!r}")
     x_spec, b, s, k = _x_spec(x)
-    k2, n = qw_g.shape
+    k2, n = qw_g.shape[-2:]
     if k != k2:
         raise ValueError(f"activation K={k} does not match weight K={k2}")
     if qw_u.shape != qw_g.shape:
@@ -337,32 +382,34 @@ def stamp_quant_dual_matmul_pallas(
                          f"{qw_g.shape} vs {qw_u.shape}")
     bn = _pick_block_n(block_n, n)
     mats = _seq_matrices(transform, s, levels, skip_first)
-    kernel = functools.partial(
-        _stamp_dual_kernel, has_mats=bool(mats), num_hi=num_hi,
-        hi_bits=hi_bits, lo_bits=lo_bits, k_total=k, epilogue=epilogue)
-    w_spec = pl.BlockSpec((k, bn), lambda i, j: (0, j))
-    c_spec = pl.BlockSpec((1, bn), lambda i, j: (0, j))
-    o_spec = pl.BlockSpec((1, s, bn), lambda i, j: (i, 0, j))
+    w_spec, c_spec = weight_specs(qw_g, layer, bn)
+    b_spec = pl.BlockSpec((1, bn), lambda i, j, *_: (0, j))
+    o_spec = pl.BlockSpec((1, s, bn), lambda i, j, *_: (i, 0, j))
     o_shape = jax.ShapeDtypeStruct((b, s, n), out_dtype or x.dtype)
     single = epilogue == "silu_mul"
-    out = pl.pallas_call(
-        kernel,
+    kernel, grid_kw, lead = layer_grid(
+        functools.partial(_stamp_dual_kernel, has_mats=bool(mats),
+                          num_hi=num_hi, hi_bits=hi_bits, lo_bits=lo_bits,
+                          k_total=k, epilogue=epilogue),
+        layer,
         grid=(b, n // bn),
         in_specs=[_m_spec(s)] * len(mats) + [
             x_spec,
-            w_spec, c_spec, c_spec, c_spec,
-            w_spec, c_spec, c_spec, c_spec],
+            w_spec, c_spec, c_spec, b_spec,
+            w_spec, c_spec, c_spec, b_spec],
         out_specs=o_spec if single else (o_spec, o_spec),
-        out_shape=o_shape if single else (o_shape, o_shape),
         scratch_shapes=[
             pltpu.VMEM((s, k), jnp.int8),      # shared quantized codes
             pltpu.VMEM((s, 1), jnp.float32),   # per-token scale
             pltpu.VMEM((s, 1), jnp.float32),   # per-token (shifted) zp
-        ],
+        ])
+    return pl.pallas_call(
+        kernel,
+        out_shape=o_shape if single else (o_shape, o_shape),
         compiler_params=COMPILER_PARAMS,
         interpret=interpret,
-    )(*mats, x, qw_g, sw_g, zw_g, bias_g, qw_u, sw_u, zw_u, bias_u)
-    return out
+        **grid_kw,
+    )(*lead, *mats, x, qw_g, sw_g, zw_g, bias_g, qw_u, sw_u, zw_u, bias_u)
 
 
 def stamp_quant_segment_matmul_pallas(
@@ -473,6 +520,7 @@ def stamp_quant_ragged_matmul_pallas(
     *,
     block_m: int = 32,
     block_f: int = 512,
+    layer: jax.Array | None = None,
     out_dtype=jnp.float32,
     interpret: bool | None = None,
 ) -> jax.Array:
@@ -497,12 +545,16 @@ def stamp_quant_ragged_matmul_pallas(
     per call however many tiles its rows fill.  Returns the ``(n_tiles *
     block_m, d)`` expert outputs; rows past a tile's count are zero, and
     the rows of empty tiles are undefined (no caller reads them).
+
+    The weights may also be the layer scan's ``(L, E, ·, ·)`` stacks with
+    a ``layer`` index, which rides the table as one more entry: the
+    weight index maps read that layer's experts in place.
     """
     if interpret is None:
         from repro.kernels.ops import default_interpret
         interpret = default_interpret()
     r, d = qx.shape
-    e, _, f = qw_gate.shape
+    f = qw_gate.shape[-1]
     bm = block_m
     if r % bm:
         raise ValueError(f"{r} rows are not whole tiles of {bm}")
@@ -510,20 +562,31 @@ def stamp_quant_ragged_matmul_pallas(
     if table.shape != (3 * n_tiles,):
         raise ValueError(f"tile table {table.shape} does not match "
                          f"{n_tiles} tiles")
+    if (qw_gate.ndim == 4) != (layer is not None):
+        raise ValueError(f"{qw_gate.ndim}-D expert weights with "
+                         f"layer={layer!r}: a stack of layers needs its "
+                         f"layer index, and only a stack takes one")
     bf = _pick_block_n(block_f, f)
     nf = f // bf
     n = n_tiles
 
+    def w(block, at):
+        # tile t's expert tab[n + t]; in a stack, of layer tab[3n]
+        if layer is None:
+            return pl.BlockSpec((1, *block), lambda t, j, tab: (
+                tab[n + t], *at(j)))
+        return pl.BlockSpec((None, 1, *block), lambda t, j, tab: (
+            tab[3 * n], tab[n + t], *at(j)))
+
     x_spec = pl.BlockSpec((bm, d), lambda t, j, tab: (tab[t], 0))
     s_spec = pl.BlockSpec((bm, 1), lambda t, j, tab: (tab[t], 0))
-    win_spec = pl.BlockSpec((1, d, bf),
-                            lambda t, j, tab: (tab[n + t], 0, j))
-    cin_spec = pl.BlockSpec((1, 1, bf),
-                            lambda t, j, tab: (tab[n + t], 0, j))
-    wdn_spec = pl.BlockSpec((1, bf, d),
-                            lambda t, j, tab: (tab[n + t], j, 0))
-    cdn_spec = pl.BlockSpec((1, 1, d),
-                            lambda t, j, tab: (tab[n + t], 0, 0))
+    win_spec = w((d, bf), lambda j: (0, j))
+    cin_spec = w((1, bf), lambda j: (0, j))
+    wdn_spec = w((bf, d), lambda j: (j, 0))
+    cdn_spec = w((1, d), lambda j: (0, 0))
+    if layer is not None:
+        table = jnp.concatenate([table.astype(jnp.int32),
+                                 jnp.reshape(layer, (1,)).astype(jnp.int32)])
     kernel = functools.partial(
         _ragged_moe_kernel, n_tiles=n_tiles, block_m=bm, block_f=bf, nf=nf,
         d=d)

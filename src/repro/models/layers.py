@@ -63,6 +63,30 @@ def apply_rope(x: Array, positions: Array, theta: float) -> Array:
 # fused STaMP linear (integer deployment path)
 # ---------------------------------------------------------------------------
 
+# Trace-time record of the prepared-weight operands the integer kernels
+# read, one entry per operand: True where the kernel reads a layer stack in
+# place (a ``{"iq", "isw", "izw", "layer"}`` view, see `lm.run_stack`),
+# False where it reads a plain per-layer array.  `lm.run_stack` tallies it.
+WEIGHT_READS: list = []
+
+
+def kernel_weights(*ws: dict) -> Optional[Array]:
+    """Record the prepared weights one kernel call reads; returns their
+    layer index (None for unstacked weights)."""
+    WEIGHT_READS.extend("layer" in w for w in ws)
+    return ws[0].get("layer")
+
+
+def layer_weight(w: dict) -> dict:
+    """A prepared weight as its layer's own arrays, for readers outside the
+    kernels: a stacked view is indexed at its layer (XLA fuses that slice
+    into its consumer), any other prepared dict comes back as it is."""
+    if "layer" not in w:
+        return w
+    return {k: jax.lax.dynamic_index_in_dim(w[k], w["layer"], 0,
+                                            keepdims=False)
+            for k in ("iq", "isw", "izw")}
+
 
 def stamp_fused_linear(x: Array, w: dict, b: Optional[Array],
                        stamp_cfg, merge_heads: bool = False,
@@ -71,7 +95,8 @@ def stamp_fused_linear(x: Array, w: dict, b: Optional[Array],
 
     ``w`` is a prepared-weight dict ``{"iq": (din, dout) int8, "isw": (1,
     dout), "izw": (1, dout)}`` built by `repro.models.lm.prepare_fused_weights`
-    — the int8 buffers are reused across calls (no per-call dequant).  The
+    — the int8 buffers are reused across calls (no per-call dequant) — or
+    the layer scan's stacked view of them, read in place.  The
     kernel applies the sequence transform, mixed-precision quantization,
     integer GEMM and inverse transform in one VMEM residency, so the
     activation never materializes an intermediate in HBM.
@@ -82,7 +107,8 @@ def stamp_fused_linear(x: Array, w: dict, b: Optional[Array],
     activation first.
     """
     from repro.core.stamp import PreparedLinear, stamp_linear
-    prep = PreparedLinear(qw=w["iq"], sw=w["isw"], zw=w["izw"], bias=b)
+    prep = PreparedLinear(qw=w["iq"], sw=w["isw"], zw=w["izw"], bias=b,
+                          layer=kernel_weights(w))
     return stamp_linear(x, None, None, stamp_cfg, prepared=prep,
                         merge_heads=merge_heads, site=site)
 
@@ -95,10 +121,11 @@ def stamp_fused_dual_linear(x: Array, w_gate: dict, w_up: dict,
     silu·mul epilogue combines the pair in-VMEM, so the whole gate/up stage
     costs one HBM read of ``x`` and one write of the product."""
     from repro.core.stamp import PreparedLinear, stamp_dual_linear
+    layer = kernel_weights(w_gate, w_up)
     pg = PreparedLinear(qw=w_gate["iq"], sw=w_gate["isw"],
-                        zw=w_gate["izw"], bias=None)
+                        zw=w_gate["izw"], bias=None, layer=layer)
     pu = PreparedLinear(qw=w_up["iq"], sw=w_up["isw"],
-                        zw=w_up["izw"], bias=None)
+                        zw=w_up["izw"], bias=None, layer=layer)
     return stamp_dual_linear(x, None, None, stamp_cfg,
                              prepared_gate=pg, prepared_up=pu,
                              epilogue="silu_mul", site=site)
@@ -577,7 +604,8 @@ def moe_block_f(d: int, f: int, budget: int = 12 * 2 ** 20) -> int:
 def moe_ffn_dropless_fused(
     x: Array,                 # (T, d) the activation the experts read
     gate_w: Array,            # (d, E) router over every expert
-    w_gate: dict,             # {"iq": (E_h, d, f) int8, "isw", "izw"}
+    w_gate: dict,             # {"iq": (E_h, d, f) int8, "isw", "izw"[,
+                              # "layer"]} (a stacked view: (L, E_h, d, f))
     w_up: dict,
     w_down: dict,             # {"iq": (E_h, f, d) int8, ...}
     experts_per_token: int,
@@ -598,7 +626,7 @@ def moe_ffn_dropless_fused(
     from repro.core.stamp import token_quantize
     from repro.kernels import ops as kops
     t, d = x.shape
-    held, _, f = w_gate["iq"].shape
+    held, _, f = w_gate["iq"].shape[-3:]
     with jax.named_scope("moe.route"):
         disp = moe_route_dropless(x, gate_w, experts_per_token, valid,
                                   offset=offset, held=held, block_m=block_m)
@@ -610,6 +638,7 @@ def moe_ffn_dropless_fused(
             w_gate["iq"], w_gate["isw"], w_gate["izw"],
             w_up["iq"], w_up["isw"], w_up["izw"],
             w_down["iq"], w_down["isw"], w_down["izw"],
+            layer=kernel_weights(w_gate, w_up, w_down),
             block_m=block_m, block_f=moe_block_f(d, f))
     with jax.named_scope("moe.combine"):
         ye = jnp.concatenate([ye, jnp.zeros((1, d), ye.dtype)])

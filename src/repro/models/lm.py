@@ -226,12 +226,13 @@ def _linear(x: Array, w, b=None) -> Array:
             lead = x.shape[:-1]
             y = kops.stamp_decode_matmul(
                 x.reshape(-1, x.shape[-1]), w["iq"], w["isw"], w["izw"],
-                b, out_dtype=x.dtype)
+                b, layer=L.kernel_weights(w), out_dtype=x.dtype)
             return y.reshape(*lead, y.shape[-1])
         # target-dtype arithmetic for the same reason as _dequant_packed:
         # the dequant intermediate is what FSDP all-gathers, and the signed
         # codes / zero points are integers in [-128, 127] — exact in bf16
         # (prepare_linear anchors the quant range at zero to guarantee it)
+        w = L.layer_weight(w)
         wd = ((w["iq"].astype(x.dtype) - w["izw"].astype(x.dtype)) *
               w["isw"].astype(x.dtype))
     elif isinstance(w, dict):
@@ -379,12 +380,13 @@ def prepare_fused_weights(params: Pytree, stamp: StampConfig) -> Pytree:
     each gate/up pair stacks into one `prepare_linear` call.
 
     Runs once at engine/benchmark setup; stacked ``(nper, din, dout)`` period
-    weights prepare one period at a time (`lax.map`) into stacks that slice
-    cleanly under `lax.scan` — per-output-channel scales make that identical
-    to one whole-stack pass, and only one period's f32 dequant is ever
-    live.  Packed int4 dicts from :func:`quantize_weights_for_serving` are
-    dequantized first and re-coded at ``stamp.fused_weight_bits``.  No-op
-    when the config cannot run the fused kernel.
+    weights prepare one period at a time (`lax.map`) into stacks that the
+    layer scan's kernels read in place (`run_stack`) — per-output-channel
+    scales make that identical to one whole-stack pass, and only one
+    period's f32 dequant is ever live.  Packed int4 dicts from
+    :func:`quantize_weights_for_serving` are dequantized first and re-coded
+    at ``stamp.fused_weight_bits``.  No-op when the config cannot run the
+    fused kernel.
     """
     from repro.core.stamp import fused_eligible, prepare_linear
     if not fused_eligible(stamp):
@@ -1138,6 +1140,7 @@ def _expert_w(w, dtype):
         # prepared stacked (E, din, dout) int8 codes (decode / no-STaMP
         # call sites share the serving params): exact bf16 dequant — codes
         # and zero points are integers in [-128, 127]
+        w = L.layer_weight(w)
         return ((w["iq"].astype(dtype) - w["izw"].astype(dtype))
                 * w["isw"].astype(dtype))
     if isinstance(w, dict):
@@ -1223,6 +1226,25 @@ def _valid_tokens(x: Array, kw: dict) -> Optional[Array]:
 # ---------------------------------------------------------------------------
 
 
+def _prepared(v) -> bool:
+    """A fused site's prepared int8 weight (`prepare_fused_weights`)."""
+    return isinstance(v, dict) and "iq" in v
+
+
+# fused-weight kernel operands of the layer stack traced last, as (read in
+# place, read from a per-layer slice of a stack); a step program runs one
+# stack, and the engine tallies each compiled step's pair
+_STACK_READS: list = [None]
+
+
+def take_weight_reads() -> Optional[tuple]:
+    """The ``(in place, sliced)`` fused-weight kernel operands one run of
+    the stack traced last reads (None if no stack was traced since the last
+    call); clears the record."""
+    reads, _STACK_READS[0] = _STACK_READS[0], None
+    return reads
+
+
 def run_stack(
     params: dict, x: Array, cfg: ModelConfig, *,
     mode: str, positions: Array, policy: Optional[ShardingPolicy],
@@ -1247,6 +1269,7 @@ def run_stack(
               seq_lengths=seq_lengths)
 
     _MOE_TALLY.clear()
+    L.WEIGHT_READS.clear()
     new_pro_cache = {}
     for i, spec in enumerate(pro):
         entry = None if cache is None else cache.get(f"pro{i}")
@@ -1254,43 +1277,14 @@ def run_stack(
                             cache_entry=entry, **kw)
         if ne is not None:
             new_pro_cache[f"pro{i}"] = ne
+    # a prologue layer's weights are whole arrays: read in place
+    pro_reads = len(L.WEIGHT_READS)
 
     stateful = [j for j, s in enumerate(period) if s.mixer in ("attn", "mamba")]
     cache_per = None
     if cache is not None:
         cache_per = {str(j): cache[str(j)] for j in stateful
                      if str(j) in cache}
-
-    if mode == "decode" and cache_per is not None and False:
-        # DISABLED (§Perf decode iter 6): carrying the cache and updating at
-        # a dynamic layer index forces XLA to COPY the full stacked buffers
-        # every layer (read-before-write kills aliasing) — 4×0.67 GB/layer
-        # measured.  The xs/ys path below only moves per-layer slices, and
-        # with one-hot token writes it no longer triggers GSPMD gathers.
-        def body(carry, p_slice):
-            xc, cache_c, idx = carry
-            cache_next = dict(cache_c)
-            for j, spec in enumerate(period):
-                entry = None
-                if str(j) in cache_c:
-                    entry = jax.tree.map(
-                        lambda a: jax.lax.dynamic_index_in_dim(
-                            a, idx, 0, keepdims=False), cache_c[str(j)])
-                xc, ne = apply_block(spec, p_slice[j], xc, cfg,
-                                     cache_entry=entry, **kw)
-                if ne is not None:
-                    cache_next[str(j)] = jax.tree.map(
-                        lambda full, upd: jax.lax.dynamic_update_index_in_dim(
-                            full, upd, idx, 0), cache_next[str(j)], ne)
-            xc = constrain(xc, policy, lambda pol: pol.acts())
-            return (xc, cache_next, idx + 1), ()
-
-        (x, cache_out, _), _ = jax.lax.scan(
-            body, (x, cache_per, jnp.zeros((), jnp.int32)),
-            params["period"])
-        new_cache = dict(cache_out)
-        new_cache.update(new_pro_cache)
-        return x, new_cache
 
     # quant telemetry: records made by the prologue layers above live at
     # the outer trace level — drain them NOW so the scan body (traced
@@ -1300,24 +1294,44 @@ def run_stack(
     pro_telem = QS.drain()
     pro_tally = _drain_tally()
 
+    # The prepared fused-site weights stay out of the scanned inputs: the
+    # body closes over their whole (nper, ...) stacks, loop invariants of
+    # the scan, and hands each site a view of its stack at the scanned
+    # layer index, which the kernels read in place.  A scanned weight would
+    # be sliced into a fresh per-layer copy before every kernel call.
+    # Norms, biases, the router, the SSM's small leaves and the cache
+    # slices are scanned as before.
+    stacks = tuple({k: v for k, v in p.items() if _prepared(v)}
+                   for p in params["period"])
+    rest = tuple({k: v for k, v in p.items() if not _prepared(v)}
+                 for p in params["period"])
+    body_reads: list = []
+
     def body(xc, xs):
-        p_slice, c_slice = xs
+        layer, p_rest, c_slice = xs
+        L.WEIGHT_READS.clear()
         new_entries = {}
         for j, spec in enumerate(period):
+            p = dict(p_rest[j])
+            p.update((k, dict(v, layer=layer)) for k, v in stacks[j].items())
             entry = None if c_slice is None else c_slice.get(str(j))
-            xc, ne = apply_block(spec, p_slice[j], xc, cfg,
-                                 cache_entry=entry, **kw)
+            xc, ne = apply_block(spec, p, xc, cfg, cache_entry=entry, **kw)
             if ne is not None:
                 new_entries[str(j)] = ne
         xc = constrain(xc, policy, lambda pol: pol.acts())
+        body_reads[:] = L.WEIGHT_READS
         return xc, (new_entries, QS.drain(), _drain_tally())
 
     if mode == "train" and remat:
         body = jax.checkpoint(body,
                               policy=jax.checkpoint_policies.nothing_saveable)
 
-    xs = (params["period"], cache_per)
+    xs = (jnp.arange(nper, dtype=jnp.int32), rest, cache_per)
     x, (period_cache, period_telem, period_tally) = jax.lax.scan(body, x, xs)
+    L.WEIGHT_READS.clear()
+    in_place = sum(body_reads)
+    _STACK_READS[0] = (pro_reads + nper * in_place,
+                       nper * (len(body_reads) - in_place))
     QS.absorb(period_telem)
     QS.merge_flat(pro_telem)
     _MOE_TALLY.append(pro_tally + period_tally.sum(0))

@@ -161,7 +161,11 @@ class _EngineBase:
                  # pairs routed over every expert, those landing on this
                  # chip's experts, held experts with rows, rows lost (0)
                  "moe_rows_routed", "moe_rows_held", "moe_expert_groups",
-                 "moe_rows_dropped")
+                 "moe_rows_dropped",
+                 # prepared-weight operands of the integer kernels a step
+                 # runs: read from the layer stack in place, or from a
+                 # per-layer slice the layer scan copied out of it
+                 "weight_reads_in_place", "weight_reads_sliced")
 
     def __init__(self, params, cfg: ModelConfig, serve: lm.ServeConfig,
                  clock: Optional[Callable[[], float]] = None,
@@ -696,6 +700,7 @@ class PagedServingEngine(_EngineBase):
         (old compiled variants are dropped; the recompile counter starts
         over for the new config)."""
         self._compiled_keys = {}
+        self._weight_reads = {}
         unified = self.ecfg.step_mode == "unified"
         cfgm, serve_p = self.cfg, self.serve
         # static: whether the step fns return an extra quant-telemetry
@@ -1056,6 +1061,18 @@ class PagedServingEngine(_EngineBase):
         self._inc("attn_pages_walked", walked)
         self._inc("attn_pages_reserved", reserved)
 
+    def _count_weight_reads(self, key) -> None:
+        """Add one step's prepared-weight kernel operands (read from a
+        layer stack in place, read from a per-layer slice) to the engine
+        counters.  Tallied at trace time (`lm.take_weight_reads`), so once
+        per compiled step program ``key`` (a chunk-row bucket or a two-call
+        entry point), on its first call."""
+        if key not in self._weight_reads:
+            self._weight_reads[key] = lm.take_weight_reads() or (0, 0)
+        in_place, sliced = self._weight_reads[key]
+        self._inc("weight_reads_in_place", in_place)
+        self._inc("weight_reads_sliced", sliced)
+
     def _count_moe(self, counts: np.ndarray) -> None:
         """Add one step's MoE counts (`layers.MOE_COUNTS` order, summed
         over the step's layer calls; zeros without experts) to the engine
@@ -1201,6 +1218,7 @@ class PagedServingEngine(_EngineBase):
                 self._inc("recompiles")
             with self._timer.phase("launch"):
                 *out, moe = self._unified(*args)
+            self._count_weight_reads(n_pf)
             if self._collect:
                 pf_logits, dec_logits, self.pools, telem = out
             else:
@@ -1277,6 +1295,7 @@ class PagedServingEngine(_EngineBase):
         ht, lt = ht_all[slot_sel], lt_all[slot_sel]
         last_index = (sreq.prompt_len - 1) - start if end == sreq.prompt_len \
             else valid - 1
+        key = "first" if start == 0 else "cont"
         fn = self._prefill_first if start == 0 else self._prefill_cont
         telem = None
         with self._timer.phase("dispatch"):
@@ -1285,6 +1304,7 @@ class PagedServingEngine(_EngineBase):
                 jnp.int32(start), ht, lt, jnp.asarray(pages),
                 jnp.asarray(offs), jnp.asarray(ishi),
                 jnp.int32(last_index), jnp.int32(sreq.slot))
+            self._count_weight_reads(key)
             if self._collect:
                 logits, self.pools, telem = out
             else:
@@ -1334,6 +1354,7 @@ class PagedServingEngine(_EngineBase):
                 self.params, self.pools, jnp.asarray(tokens),
                 jnp.asarray(positions), ht, lt, jnp.asarray(pages),
                 jnp.asarray(offs), jnp.asarray(ishi), jnp.asarray(active))
+            self._count_weight_reads("decode")
             self._inc("device_dispatches")
             logits, moe = jax.device_get((logits, moe))
             self._count_moe(moe)

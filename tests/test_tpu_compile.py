@@ -2,7 +2,8 @@
 described TPU v5e: the STaMP linears at Llama-3-8B widths and at
 Granite-4.0-H-Small's Mamba projections, its grouped expert kernel, the
 paged attention at the benchmark cell's cache (Mistral-NeMo-12B) and at
-DeepSeek-7B's multi-head widths.
+DeepSeek-7B's multi-head widths, and whole serving steps at small widths,
+whose layer scan must hand the kernels its weight stacks, not copies.
 
 Nothing runs: each test lowers one kernel for a chip that is described, not
 attached, and compiles it with the TPU compiler, which refuses what the
@@ -14,13 +15,19 @@ import: only one process may load the TPU compiler's library at a time.
 
 from __future__ import annotations
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.stamp import StampConfig
 from repro.kernels import decode_matmul as DM, stamp_matmul as SM
+from repro.kernels import ops
 from repro.kernels import paged_attention as PA
+from repro.models import lm
+from repro.models.config import ModelConfig
 from repro.serving import paged_kvcache as PKV
 from repro.serving.kvcache import KVCacheConfig
 
@@ -229,3 +236,106 @@ def test_paged_attention_refused_page_sizes(one_chip, monkeypatch, page):
     monkeypatch.setattr(PA, "unsupported", lambda *a, **k: None)
     with pytest.raises(Exception, match="aligned to tiling"):
         _compile_paged(one_chip, 8, HEAD_DIM, 4, page, page, MAX_SEQ, 0)
+
+
+# Whole serving steps at small TPU-aligned widths, two layer periods each:
+# a dense llama, and a Granite-like hybrid (Mamba-2 then NoPE attention,
+# 4 of 8 experts held, a shared expert).  The layer scan must hand every
+# integer kernel its weight stack and the layer index: no kernel operand
+# may be a per-layer copy of one layer's codes cut out of the stack (the
+# `dynamic-slice` fusions the scan wrote for every layer), and no stack
+# may be copied whole.  KV-pool slices are not weights and not counted.
+STEP_CONFIGS = {
+    "dense": ModelConfig(
+        name="aot-dense", family="dense", num_layers=2, d_model=256,
+        num_heads=2, num_kv_heads=1, head_dim=128, d_ff=512,
+        vocab_size=256),
+    "hybrid": ModelConfig(
+        name="aot-hybrid", family="hybrid", num_layers=4, d_model=256,
+        num_heads=2, num_kv_heads=1, head_dim=128, d_ff=256, vocab_size=256,
+        num_experts=8, experts_per_token=2, moe_d_ff=128,
+        num_local_experts=4, dense_residual=True, moe_period=1,
+        attn_period=2, ssm_state=128, ssm_head_dim=4, ssm_expand=2,
+        conv_width=4, use_rope=False),
+}
+_INST = re.compile(r"\s*(?:ROOT\s+)?%(\S+)\s*=\s*(.*?)\s([a-z][a-z0-9-]*)\((.*)$")
+
+
+def _step_hlo(one_chip, cfg, program):
+    kv = KVCacheConfig(quantized=True, num_hi=16)
+    slots, chunk, rows, max_seq, page = 4, 128, 2, 512, 16
+    lo_per, hi_per = -(-(max_seq - kv.num_hi) // page), kv.num_hi // page
+    pcfg = PKV.PagedCacheConfig(
+        block_size=page, num_lo_blocks=slots * lo_per + 1,
+        num_hi_blocks=slots * hi_per + 1, max_blocks_per_seq=lo_per,
+        quant=kv)
+    stamp = StampConfig(num_hi_tokens=4, execution="fused")
+    serve = lm.ServeConfig(stamp=stamp, kv=kv, paged=pcfg,
+                           fused_decode_matmul=True)
+    params = jax.eval_shape(lambda k: lm.prepare_fused_weights(
+        lm.init_params(k, cfg), stamp), jax.random.PRNGKey(0))
+    pools = jax.eval_shape(lambda: lm.init_paged_cache(cfg, pcfg,
+                                                       num_slots=slots))
+
+    def on_chip(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    state = jax.tree.map(lambda a: on_chip(a.dtype, *a.shape),
+                         (params, pools))
+    i32, b1 = jnp.int32, jnp.bool_
+    if program == "unified":
+        n = rows * chunk + slots
+        args = (on_chip(i32, rows, chunk), *[on_chip(i32, rows)] * 2,
+                on_chip(b1, rows), *[on_chip(i32, rows)] * 2,
+                *[on_chip(i32, slots)] * 2, on_chip(b1, slots),
+                on_chip(i32, rows + slots, hi_per),
+                on_chip(i32, rows + slots, lo_per),
+                *[on_chip(i32, n)] * 2, on_chip(b1, n))
+        fn = lambda p, po, *a: lm.paged_unified_step(p, po, *a, cfg, serve)
+    else:
+        args = (*[on_chip(i32, slots)] * 2, on_chip(i32, slots, hi_per),
+                on_chip(i32, slots, lo_per), *[on_chip(i32, slots)] * 2,
+                on_chip(b1, slots), on_chip(b1, slots))
+        fn = lambda p, po, *a: lm.paged_decode_step(
+            p, po, *a[:-1], cfg, serve, active=a[-1])
+    txt = jax.jit(fn).lower(*state, *args).compile().as_text()
+    codes = [v["iq"].shape for layer in params["period"]
+             for v in layer.values() if isinstance(v, dict) and "iq" in v]
+    return txt, codes
+
+
+@pytest.mark.parametrize("program", ["unified", "decode"])
+@pytest.mark.parametrize("config", sorted(STEP_CONFIGS))
+def test_step_reads_weight_stacks_in_place(one_chip, monkeypatch, config,
+                                           program):
+    monkeypatch.setattr(ops, "default_interpret", lambda: False)
+    txt, codes = _step_hlo(one_chip, STEP_CONFIGS[config], program)
+    assert min(shape[0] for shape in codes) >= 2      # two periods or more
+    layer_codes = {f"s8[{','.join(map(str, s[1:]))}]" for s in codes}
+    stacks = {f"s8[{','.join(map(str, s))}]" for s in codes}
+
+    def is_one(typ, shapes):
+        return any(typ == s or typ.startswith(s + "{") for s in shapes)
+
+    defs, operands = {}, []
+    for line in txt.splitlines():
+        m = _INST.match(line)
+        if m:
+            name, typ, op, rest = m.groups()
+            args = re.findall(r"%([\w.-]+)", rest.split(")")[0])
+            defs[name] = (typ, op, args)
+            if op == "custom-call" and "tpu_custom_call" in rest:
+                operands += [(name, a) for a in args]
+    assert any(c.startswith("stamp_decode_matmul") for c, _ in operands)
+    sliced = []
+    for call, arg in operands:
+        while defs[arg][1] == "bitcast":
+            arg = defs[arg][2][0]
+        typ, op, _ = defs[arg]
+        if op in ("fusion", "dynamic-slice", "copy") and \
+                is_one(typ, layer_codes):
+            sliced.append((call, arg, typ))
+    assert not sliced, sliced
+    copied = [(n, t) for n, (t, op, _) in defs.items()
+              if op in ("copy", "copy-start") and is_one(t, stacks)]
+    assert not copied, copied
